@@ -7,7 +7,8 @@
 # build args below; bump them TOGETHER and only to combinations published
 # on the jax release matrix:
 #
-#   JAX_VERSION    the jax/jaxlib release (e.g. 0.4.38)
+#   JAX_VERSION    the jax/jaxlib release (0.9.0 pairs with libtpu 0.0.34 — the
+#                  installation chip_smoke.py was brought up on)
 #   JAX_EXTRAS     ""      → CPU-only image (CI builds this: hermetic,
 #                            no TPU wheel downloads)
 #                  "[tpu]" → pulls the matching libtpu via the release
@@ -23,7 +24,7 @@
 
 FROM python:3.12-slim
 
-ARG JAX_VERSION=0.4.38
+ARG JAX_VERSION=0.9.0
 ARG JAX_EXTRAS=""
 # the libtpu release index the [tpu] extra resolves against; pinned so an
 # image rebuild months later still gets the SAME libtpu for this jaxlib

@@ -7,30 +7,36 @@ import sys as _sys
 
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))))
 
-import jax.numpy as jnp
-
 from gofr_tpu import App
 from gofr_tpu.config import EnvConfig
 from gofr_tpu.models import LlamaConfig, ModelSpec
 
 
-def build_app(config=None, *, preset: str = "tiny") -> App:
+def build_app(config=None, *, model_config: LlamaConfig | None = None, **engine_kw) -> App:
+    """``model_config`` replaces the demo-sized LlamaConfig — and the demo's
+    byte tokenizer with it, so prompts, results and streams are token ids
+    (a deployment names its own tokenizer in the ModelSpec). ``engine_kw``
+    go through to ``serve_model`` (kv_layout, slots, max_len, page_size,
+    ...) on top of the demo-sized defaults."""
     import os
 
     folder = os.path.join(os.path.dirname(os.path.abspath(__file__)), "configs")
     app = App(config=config or EnvConfig(folder=folder))
 
-    from gofr_tpu.utils import ByteTokenizer
+    if model_config is None:
+        from gofr_tpu.utils import ByteTokenizer
 
-    # vocab must cover the byte tokenizer's 259 ids; prompts can be raw
-    # token-id lists OR strings (encoded through the tokenizer), and results
-    # carry decoded text alongside ids. EOS is disabled here because random
-    # weights emit any token — a real checkpoint would keep the tokenizer's
-    # eos_token_id (build_engine wires it automatically).
-    cfg = LlamaConfig.tiny(vocab_size=300) if preset == "tiny" else LlamaConfig.one_b()
-    dtype = jnp.float32 if preset == "tiny" else jnp.bfloat16
-    spec = ModelSpec("llama", cfg, task="generate", dtype=dtype, tokenizer=ByteTokenizer())
-    app.serve_model("lm", spec, slots=4, max_len=64, eos_token_id=-1)
+        # vocab must cover the byte tokenizer's 259 ids; prompts can be raw
+        # token-id lists OR strings (encoded through the tokenizer), and
+        # results carry decoded text alongside ids.
+        cfg, tokenizer = LlamaConfig.tiny(vocab_size=300), ByteTokenizer()
+    else:
+        cfg, tokenizer = model_config, None
+    spec = ModelSpec("llama", cfg, task="generate", dtype=cfg.dtype, tokenizer=tokenizer)
+    # EOS is disabled here because random weights emit any token — a real
+    # checkpoint would keep the tokenizer's eos_token_id (build_engine wires
+    # it automatically).
+    app.serve_model("lm", spec, **{"slots": 4, "max_len": 64, "eos_token_id": -1, **engine_kw})
 
     async def generate(ctx):
         # async handler + agenerate: awaits the engine future on the event

@@ -984,13 +984,12 @@ class App:
                 except (TypeError, ValueError):
                     needs_example = True
                 if not needs_example:
-                    try:
-                        n = engine.warmup()
-                        self.logger.infof("model engine %s warmed (%d programs)", name, n)
-                    except Exception as e:  # noqa: BLE001 - warmup is an
-                        # optimization: surface the failure loudly but let the
-                        # engine serve (first traffic compiles lazily)
-                        self.logger.log_exception(e, f"engine {name} warmup failed")
+                    # a warmup that raises stops the boot: the operator
+                    # asked for every program compiled before traffic, and
+                    # a program that fails to compile here would fail (or
+                    # crash-loop the device thread) inside a request
+                    n = engine.warmup()
+                    self.logger.infof("model engine %s warmed (%d programs)", name, n)
             if hasattr(engine, "start"):
                 engine.start()
                 self.logger.infof("model engine %s started", name)

@@ -702,6 +702,10 @@ class Container:
         for engine in self._engines.values():
             if hasattr(engine, "stop"):
                 engine.stop()
+        # a closed container lets go of its engines, so their weights and KV
+        # cache leave device memory even while something still holds the App
+        # (aiohttp keeps a process-wide LRU of handlers, hence of their App)
+        self._engines.clear()
         for ds in (self.sql, self.redis, self.pubsub, self.kv, self.mongo, self.cassandra, self.clickhouse):
             if ds is not None and hasattr(ds, "close"):
                 try:

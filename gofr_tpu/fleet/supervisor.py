@@ -1,4 +1,10 @@
-"""Process supervision for fleet members: the VERDICT #4 runbook as code.
+"""Process supervision for fleet members: the restart runbook as code.
+
+One process per chip: the supervising parent MUST NOT have initialised a
+JAX backend. A process that has touched JAX holds its chips, and a spawned
+member that needs them then fails or hangs — so run the supervisor from a
+process that only imports this (import-light) module, and let each member
+be the first and only process to touch its devices.
 
 A fleet process dies in one of three recognizable ways:
 

@@ -1,4 +1,4 @@
-"""BERT encoder for embedding serving (BASELINE.md config #1).
+"""BERT encoder for embedding serving (BASELINE.json configs[1]).
 
 Post-LayerNorm transformer encoder matching HF ``BertModel`` numerics
 (oracle test in tests/test_models.py). Functional, stacked layers, scanned.

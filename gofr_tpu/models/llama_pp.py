@@ -3,7 +3,7 @@
 ``build_engine`` wraps the llama family with :class:`PPLlamaFamily` when the
 container's mesh has a ``pp`` axis of size > 1: block params AND the slot KV
 cache shard over ``pp`` on the layer dim — the 70B weight-fit story
-(BASELINE.md row 4) — and every engine device call runs a GPipe-style
+(BASELINE.json configs[4]) — and every engine device call runs a GPipe-style
 schedule (``parallel.pipeline.spmd_pipeline_stateful``) where microbatches
 of slots stream through the stage ring. Composes with ``tp``: head/mlp dims
 of the stage weights and the cache's kv-head dim stay tp-sharded inside the

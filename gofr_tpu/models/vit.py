@@ -1,4 +1,4 @@
-"""Vision Transformer classifier (BASELINE.md config #3: pubsub → ViT).
+"""Vision Transformer classifier (BASELINE.json configs[3]: pubsub → ViT).
 
 Pre-LayerNorm encoder matching HF ``ViTModel``/``ViTForImageClassification``
 numerics. Patch embedding is an unfold + matmul (not a conv): identical

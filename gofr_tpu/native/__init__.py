@@ -1,11 +1,16 @@
 """Native (C++) host-runtime core: prefill planner + token data loader.
 
 The shared library is compiled from ``src/gofr_native.cc`` on first use
-(g++, cached next to the source) and bound via ctypes — no pybind11, no
-build step for users. Every entry point has a pure-Python fallback with
-IDENTICAL semantics (tested against each other), so the framework degrades
-gracefully where a toolchain is missing; ``GOFR_NATIVE=0`` forces the
-fallback.
+(g++) and bound via ctypes — no pybind11, no build step for users. The
+built file is named by a hash of the source's CONTENTS
+(``src/libgofr_native.<hash>.so``, gitignored), so what gets loaded was
+built from exactly the tracked source: a stale or copied ``.so`` is never
+picked up, whatever its mtime. Every entry point has a pure-Python twin
+with IDENTICAL semantics (tested against each other) for hosts without a
+toolchain; ``GOFR_NATIVE=0`` forces it. :func:`planner_in_use` says which
+of the two serves (engines log it at construction); a build that FAILS
+where ``g++`` exists is logged at error level with the compiler's output
+and kept in :func:`build_error`.
 
 Reference capability map: GoFr's runtime is Go (SURVEY.md §2) — the TPU
 build keeps Python as the orchestration layer and moves the schedule/IO
@@ -16,6 +21,8 @@ runtime rather than an interpreter.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -24,27 +31,41 @@ from dataclasses import dataclass
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "src", "gofr_native.cc")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "src", "libgofr_native.so")
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _lib_failed = False
+_build_error: str | None = None
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"libgofr_native.{digest}.so")
 
 
 def _build() -> str | None:
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC):
-        return _LIB_PATH
+    global _build_error
+    lib_path = _lib_path()
+    if os.path.exists(lib_path):
+        return lib_path
     # compile to a private temp path and publish atomically so a concurrent
     # process can never dlopen a half-written .so
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
              "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
-        os.replace(tmp, _LIB_PATH)
-        return _LIB_PATH
-    except (OSError, subprocess.SubprocessError):
+        os.replace(tmp, lib_path)
+        return lib_path
+    except FileNotFoundError:
+        return None  # no toolchain on this host: the Python twin serves
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", b"") or b""
+        _build_error = f"{e}: {stderr.decode(errors='replace')[-2000:]}"
+        _log.error("native build failed, Python planner in use: %s", _build_error)
         return None
     finally:
         if os.path.exists(tmp):
@@ -56,7 +77,7 @@ def _build() -> str | None:
 
 def load_native() -> ctypes.CDLL | None:
     """The shared library, building it if needed; None when unavailable."""
-    global _lib, _lib_failed
+    global _lib, _lib_failed, _build_error
     if os.environ.get("GOFR_NATIVE", "") == "0":
         return None
     if _lib is not None or _lib_failed:
@@ -70,8 +91,10 @@ def load_native() -> ctypes.CDLL | None:
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
             _lib_failed = True
+            _build_error = f"dlopen {path}: {e}"
+            _log.error("native library unusable, Python planner in use: %s", _build_error)
             return None
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -97,6 +120,17 @@ def load_native() -> ctypes.CDLL | None:
 
 def native_available() -> bool:
     return load_native() is not None
+
+
+def planner_in_use() -> str:
+    """'native' or 'python' — which prefill planner ``plan_prefill`` runs."""
+    return "native" if native_available() else "python"
+
+
+def build_error() -> str | None:
+    """Why the native build or load failed on a host that has ``g++``;
+    None when it succeeded, was never tried, or there is no toolchain."""
+    return _build_error
 
 
 # ---------------------------------------------------------------------------

@@ -25,11 +25,12 @@ def resolve_backend(backend: str, op: str | None = None) -> str:
     GOFR_PALLAS env value (0/1 — the operator override), then a pinned
     warmup-autotune decision for ``op`` (ops.autotune.decision_scope;
     engines pin measured winners for the decode ops around every trace
-    they drive), then the legacy static default (XLA on hardware, Pallas
-    under the interpreter — ops/pallas/__init__.flash_attention_available).
-    An explicit 'pallas' is honored whenever the platform can lower
-    kernels at all, degrading to 'xla' only off-TPU so one model code path
-    serves the CPU test mesh and real chips."""
+    they drive), then the static default (XLA on hardware, Pallas
+    under the interpreter — ops/pallas/__init__.flash_attention_available);
+    'auto' picks XLA wherever no kernel can lower, so one model code path
+    serves the CPU test mesh and real chips. An explicit 'pallas' is a
+    request for the kernel by name: where it cannot lower it RAISES
+    (ops.pallas.require_kernel_platform) rather than running XLA."""
     if backend == "auto":
         import os
 
@@ -43,9 +44,10 @@ def resolve_backend(backend: str, op: str | None = None) -> str:
                 return "pallas" if pinned == "pallas" and kernel_platform() else "xla"
         return "pallas" if flash_attention_available() else "xla"
     if backend == "pallas":
-        from gofr_tpu.ops.pallas import kernel_platform
+        from gofr_tpu.ops.pallas import require_kernel_platform
 
-        return "pallas" if kernel_platform() else "xla"
+        require_kernel_platform("backend='pallas'")
+        return backend
     if backend != "xla":
         raise ValueError(f"unknown attention backend {backend!r}; use 'auto', 'xla' or 'pallas'")
     return backend
@@ -166,6 +168,18 @@ def _softmax(scores: jnp.ndarray) -> jnp.ndarray:
     return unnorm / jnp.maximum(denom, 1e-20)
 
 
+def slot_decode_kernel_ok(smax: int) -> bool:
+    """Can the slot decode kernel tile a cache of length ``smax``? An
+    awkward Smax (e.g. prime) would degrade the kernel's kv block to a
+    sliver and serialize the grid; the block must also be a multiple of 8
+    (f32 sublane tile) — only 128-aligned caches, which the engine builds
+    whenever the model allows, are implicitly safe (ADVICE.md)."""
+    from gofr_tpu.ops.pallas.decode_attention import _pick_block
+
+    bkv = _pick_block(smax, 512)
+    return bkv >= min(smax, 128) and bkv % 8 == 0
+
+
 def decode_attention(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,
@@ -179,18 +193,11 @@ def decode_attention(
     [B, Hkv, Smax, D], attending to positions < lengths[b]. Returns
     [B, Hq, D]."""
     if resolve_backend(backend, op="decode") == "pallas":
-        from gofr_tpu.ops.pallas import interpret_mode
-        from gofr_tpu.ops.pallas.decode_attention import _pick_block
-        from gofr_tpu.ops.pallas.decode_attention import decode_attention as pallas_decode
-
         smax = k_cache.shape[2]
-        # An awkward Smax (e.g. prime) would degrade the kernel's kv block to
-        # a sliver and serialize the grid; the XLA path is faster then. The
-        # block must also be a multiple of 8 (f32 sublane tile) — Mosaic can
-        # reject or degrade odd second-minor block dims on hardware, and only
-        # the engine's 128-aligned caches are implicitly safe (ADVICE.md).
-        bkv = _pick_block(smax, 512)
-        if bkv >= min(smax, 128) and bkv % 8 == 0:
+        if slot_decode_kernel_ok(smax):
+            from gofr_tpu.ops.pallas import interpret_mode
+            from gofr_tpu.ops.pallas.decode_attention import decode_attention as pallas_decode
+
             return pallas_decode(
                 q, k_cache, v_cache, lengths, scale=scale, interpret=interpret_mode()
             )
@@ -199,10 +206,9 @@ def decode_attention(
             # kernel cannot satisfy must not be ignored (ADVICE.md round 2;
             # paged_decode_attention already raises for its analog).
             raise ValueError(
-                f"backend='pallas' requested but cache Smax {smax} yields kv "
-                f"block {bkv} (need a block >= min(Smax, 128) that divides "
-                f"Smax and is a multiple of 8); use a 128-aligned cache "
-                f"length or backend='auto'"
+                f"backend='pallas' requested but cache Smax {smax} has no kv "
+                f"block >= min(Smax, 128) that divides Smax and is a multiple "
+                f"of 8; use a 128-aligned cache length or backend='auto'"
             )
     b, hq, d = q.shape
     _, hkv, smax, _ = k_cache.shape
@@ -276,7 +282,6 @@ def _shard_paged_call(impl, ctx, q, pools, table, lengths):
     """Run ``impl(q, *pools, table, lengths)`` per-shard: q and every pool
     plane split on their head axis (dim 1), table/lengths replicated, output
     head-sharded (no reduce — see module note above)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     ax = ctx.axis
@@ -284,12 +289,12 @@ def _shard_paged_call(impl, ctx, q, pools, table, lengths):
         P(None, ax, None, None) if p.ndim == 4 else P(None, ax, None)
         for p in pools
     )
-    return shard_map(
+    return jax.shard_map(
         impl,
         mesh=ctx.mesh,
         in_specs=(P(None, ax, None),) + pool_specs + (P(), P()),
         out_specs=P(None, ax, None),
-        check_rep=False,
+        check_vma=False,
     )(q, *pools, table, lengths)
 
 

@@ -44,6 +44,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import json
+import logging
 import os
 import time
 from typing import Any, Callable
@@ -200,9 +201,13 @@ class Autotuner:
                 candidates: dict[str, Callable[[], Any]]) -> str:
         """Pin a backend for ``op``: cache hit > timed winner > the single
         candidate (no timing when there is nothing to compare — the CPU
-        fallback path costs zero device work). A candidate that raises
-        (e.g. Mosaic rejects the shape) loses by disqualification; if every
-        candidate fails, 'xla' — the everywhere-correct path — is pinned."""
+        path costs zero device work). A candidate that raises (e.g. the
+        kernel compiler rejects the shape) is disqualified LOUDLY: the
+        error is logged at error level and kept under ``errors`` in the
+        decision record, which ``engine.autotune_report()`` and
+        ``/debug/engine`` serve — a kernel that does not compile is a
+        defect to repair, not a race it quietly lost. If every candidate
+        fails, 'xla' — the everywhere-correct path — is pinned."""
         key = entry_key(self.device_kind, op, shape, kv_dtype, self.role,
                         self.sharding)
         cached = self._cache.get(key)
@@ -222,8 +227,11 @@ class Autotuner:
             for name, fn in candidates.items():
                 try:
                     timings[name] = round(self.timer(fn) * 1000.0, 4)
-                except Exception as e:  # noqa: BLE001 - a failing candidate loses
-                    errors[name] = str(e)[:200]
+                except Exception as e:  # noqa: BLE001 - recorded and logged below
+                    errors[name] = f"{type(e).__name__}: {e}"[:2000]
+                    (self.logger or logging.getLogger(__name__)).error(
+                        f"autotune: {op} candidate {name!r} failed at shape "
+                        f"{shape} ({kv_dtype}): {errors[name]}")
             if timings:
                 backend = min(timings, key=lambda n: timings[n])
             else:
@@ -265,6 +273,10 @@ class Autotuner:
     def report(self) -> dict[str, Any]:
         out: dict[str, Any] = {"device_kind": self.device_kind,
                                "decisions": dict(self.decisions)}
+        errors = {op: rec["errors"] for op, rec in self.decisions.items()
+                  if rec.get("errors")}
+        if errors:
+            out["errors"] = errors  # {op: {backend: message}}; absent = clean
         if self.role:
             out["role"] = self.role
         if self.sharding:

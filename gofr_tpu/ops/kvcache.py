@@ -213,25 +213,24 @@ def append_tokens(
     Two lowerings, chosen by ``GOFR_KV_WRITE`` (read at TRACE time; jit
     caches traces process-globally, so A/B across processes):
 
-    - ``select`` (default): masked full-buffer select — beat XLA's scatter
-      ~1.4-2x on v5e round 3 (6429 scatter vs 8893 select tok/s at
-      Smax=256, 2123 vs 4074 at Smax=1024) but still rewrites the whole
+    - ``select`` (default): masked full-buffer select — rewrites the whole
       layer buffer every step: O(N*Hkv*Smax*D) HBM traffic.
     - ``pallas``: in-place tile-patch kernel (ops/pallas/kv_append) —
       O(N*Hkv*block*D) traffic via input/output aliasing; requires a TPU
-      (or the Pallas interpreter), falls back to select elsewhere."""
+      (or the Pallas interpreter) and raises elsewhere.
+
+    Which is faster on the chip is not measured (PERF.md; ROADMAP S3)."""
     import os
 
     if os.environ.get("GOFR_KV_WRITE", "select") == "pallas":
-        from gofr_tpu.ops.pallas import interpret_mode, kernel_platform
+        from gofr_tpu.ops.pallas import interpret_mode, require_kernel_platform
+        from gofr_tpu.ops.pallas.kv_append import append_tokens_inplace
 
-        if kernel_platform():
-            from gofr_tpu.ops.pallas.kv_append import append_tokens_inplace
-
-            return append_tokens_inplace(
-                k_layer, v_layer, positions, k_new, v_new,
-                interpret=interpret_mode(),
-            )
+        require_kernel_platform("GOFR_KV_WRITE=pallas")
+        return append_tokens_inplace(
+            k_layer, v_layer, positions, k_new, v_new,
+            interpret=interpret_mode(),
+        )
     smax = k_layer.shape[2]
     mask = (positions[:, None] == jnp.arange(smax)[None, :])[:, None, :, None]
     k_layer = jnp.where(mask, k_new.astype(k_layer.dtype)[:, :, None, :], k_layer)

@@ -547,15 +547,14 @@ def append_tokens_paged(
 
     mode = resolve_write_mode()
     if mode == "pallas":
-        from gofr_tpu.ops.pallas import interpret_mode, kernel_platform
+        from gofr_tpu.ops.pallas import interpret_mode, require_kernel_platform
+        from gofr_tpu.ops.pallas.kv_append import append_tokens_paged_inplace
 
-        if kernel_platform():
-            from gofr_tpu.ops.pallas.kv_append import append_tokens_paged_inplace
-
-            return append_tokens_paged_inplace(
-                k_layer, v_layer, table, positions, k_new, v_new,
-                interpret=interpret_mode(),
-            )
+        require_kernel_platform("GOFR_PAGED_KV_WRITE=pallas")
+        return append_tokens_paged_inplace(
+            k_layer, v_layer, table, positions, k_new, v_new,
+            interpret=interpret_mode(),
+        )
 
     pp, off = _locate(table, positions[:, None], page)
     pp, off = pp[:, 0], off[:, 0]  # [N]

@@ -11,13 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as _pltpu
 
 NEG_INF = -1e30
-
-# jax renamed pltpu.TPUCompilerParams → CompilerParams (~0.5); same fields
-# either way. One shim here so every kernel works across the pin range.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
 
 
 def init_softmax_scratch(ki, acc_ref, m_ref, l_ref) -> None:
@@ -36,7 +31,7 @@ def softmax_block_update(s, v, acc_ref, m_ref, l_ref, v_scale=None) -> None:
     running (acc, m, l) scratch. Fully-masked-so-far rows keep l == 0 so the
     final divide yields zeros, not NaN.
 
-    ``v_scale`` [block_kv] is the int8-KV dequant fold: per-position value
+    ``v_scale`` [1, block_kv] (f32) is the int8-KV dequant fold: per-position value
     scales ride the probabilities before the PV contraction — the same
     place the XLA path folds ``vs`` (ops.attention.decode_attention_q) —
     so a quantized ``v`` stays int8 in HBM/VMEM and converts only at the
@@ -56,7 +51,7 @@ def softmax_block_update(s, v, acc_ref, m_ref, l_ref, v_scale=None) -> None:
     if v_scale is None:
         p_in, v_in = p.astype(v.dtype), v
     else:
-        p_in = p * v_scale.astype(jnp.float32)[None, :]
+        p_in = p * v_scale
         v_in = v.astype(jnp.float32)
     pv = jax.lax.dot_general(
         p_in, v_in, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -64,6 +59,20 @@ def softmax_block_update(s, v, acc_ref, m_ref, l_ref, v_scale=None) -> None:
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
+
+
+def select_head_row(block: jnp.ndarray, head) -> jnp.ndarray:
+    """Row ``head`` of a [Hkv, block_kv] scale block as [1, block_kv] f32.
+
+    The scale planes are [P, Hkv, page]: a one-head block (1, 1, page) has a
+    second-minor block dim of 1 over an array dim of Hkv, which the TPU
+    lowering refuses (neither a multiple of 8 nor the full dim). So the
+    kernels fetch all Hkv rows of the page and pick theirs with an iota
+    mask — no dynamic sublane index on a packed bf16 tile, no relayout of
+    the plane in HBM."""
+    x = block.astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(row == head, x, 0.0), axis=0, keepdims=True)
 
 
 def softmax_finish(ki, n_kvb, acc_ref, l_ref, write) -> None:

@@ -25,7 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gofr_tpu.ops.pallas.common import (
     NEG_INF,
-    CompilerParams,
     init_softmax_scratch,
     softmax_block_update,
     softmax_finish,
@@ -124,7 +123,7 @@ def decode_attention(
             pltpu.VMEM((group, 128), jnp.float32),
             pltpu.VMEM((group, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

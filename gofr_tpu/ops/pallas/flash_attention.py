@@ -31,7 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gofr_tpu.ops.pallas.common import (
     NEG_INF,
-    CompilerParams,
     init_softmax_scratch,
     softmax_block_update,
     softmax_finish,
@@ -173,7 +172,7 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

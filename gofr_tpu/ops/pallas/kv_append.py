@@ -3,17 +3,19 @@
 The XLA lowerings of the per-step KV append both pay O(cache) HBM traffic:
 the masked-select path rewrites the ENTIRE layer buffer every decode step
 (read + write of [N, Hkv, Smax, D]), and the scatter path materializes a
-non-aliased copy (BASELINE.md round-3 select-vs-scatter notes). But the
-append itself only CHANGES one [Hkv, D] row per slot. This kernel writes in
-place via ``input_output_aliases``: the grid walks slots, scalar-prefetched
-positions pick the [block_s, D] tile containing each slot's write row
-(data-dependent BlockSpec index_map), and the kernel copies that one tile
-through with the new row patched in. Per-step traffic drops from
+non-aliased copy. But the append itself only CHANGES one [Hkv, D] row per
+slot. This kernel writes in place via ``input_output_aliases``: the grid
+walks slots, scalar-prefetched positions pick the [block_s, D] tile
+containing each slot's write row (data-dependent BlockSpec index_map), and
+the kernel copies that one tile through with the new row patched in by a
+masked select over the tile (a single-row store at a dynamic sublane offset
+is refused on packed bf16 tiles: "cannot statically prove that index in
+dimension 2 is a multiple of 8"). Per-step traffic drops from
 O(N·Hkv·Smax·D) to O(N·Hkv·block_s·D) — a (Smax/block_s)× reduction on the
 axis long-context decode is bound by.
 
 Out-of-bounds convention (engine padding/bubble rows): positions >= Smax
-clamp to the last tile in the index_map and the row store is skipped, so
+clamp to the last tile in the index_map and the select mask is empty, so
 the tile is copied through unchanged — the same dropped-write semantics as
 the XLA paths (ops/kvcache.append_tokens, ops/paged.append_tokens_paged).
 
@@ -30,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gofr_tpu.ops.pallas.common import CompilerParams
-
 
 def _pick_block(total: int, desired: int) -> int:
     if total <= desired:
@@ -42,20 +42,23 @@ def _pick_block(total: int, desired: int) -> int:
     return total
 
 
+def _patch_tile(off, new_ref, in_ref, out_ref) -> None:
+    """out tile = in tile with row ``off`` (second-minor axis) replaced by
+    the new row; ``off`` < 0 patches nothing. ``new_ref`` blocks are
+    [1, Hkv, 1, D] — the wrapper inserts the unit row axis, because an
+    in-kernel [Hkv, D] -> [Hkv, 1, D] reshape is an "unsupported shape
+    cast" on bf16."""
+    _, hkv, rows, d = out_ref.shape
+    hit = jax.lax.broadcasted_iota(jnp.int32, (hkv, rows, d), 1) == off
+    out_ref[0] = jnp.where(hit, new_ref[0].astype(out_ref.dtype), in_ref[0])
+
+
 def _append_kernel(pos_ref, knew_ref, vnew_ref, k_ref, v_ref, ko_ref, vo_ref,
                    *, block_s: int, smax: int):
-    n = pl.program_id(0)
-    pos = pos_ref[n]
-    # copy the resident tile through (aliased output: same HBM buffer, but
-    # the VMEM out block must be fully defined)
-    ko_ref[0] = k_ref[0]
-    vo_ref[0] = v_ref[0]
-
-    @pl.when(pos < smax)
-    def _():
-        off = pos % block_s
-        ko_ref[0, :, pl.ds(off, 1), :] = knew_ref[0][:, None, :].astype(ko_ref.dtype)
-        vo_ref[0, :, pl.ds(off, 1), :] = vnew_ref[0][:, None, :].astype(vo_ref.dtype)
+    pos = pos_ref[pl.program_id(0)]
+    off = jnp.where(pos < smax, pos % block_s, -1)
+    _patch_tile(off, knew_ref, k_ref, ko_ref)
+    _patch_tile(off, vnew_ref, v_ref, vo_ref)
 
 
 def append_tokens_inplace(
@@ -83,8 +86,8 @@ def append_tokens_inplace(
             num_scalar_prefetch=1,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, hkv, d), lambda bi, p: (bi, 0, 0)),
-                pl.BlockSpec((1, hkv, d), lambda bi, p: (bi, 0, 0)),
+                pl.BlockSpec((1, hkv, 1, d), lambda bi, p: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, hkv, 1, d), lambda bi, p: (bi, 0, 0, 0)),
                 pl.BlockSpec((1, hkv, bs, d), cache_map),
                 pl.BlockSpec((1, hkv, bs, d), cache_map),
             ],
@@ -100,11 +103,11 @@ def append_tokens_inplace(
         # inputs 3/4 are (k_layer, v_layer) AFTER the prefetch operand;
         # aliasing makes the untouched tiles true no-ops in HBM
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pos, k_new, v_new, k_layer, v_layer)
+    )(pos, k_new[:, :, None, :], v_new[:, :, None, :], k_layer, v_layer)
 
 
 def append_tokens_paged_inplace(
@@ -120,7 +123,7 @@ def append_tokens_paged_inplace(
     """Paged-pool append writing only the page holding each slot's row.
 
     OOB rows (table entry == P) redirect their tile fetch to page 0 and
-    skip the row store. Page 0 is RESERVED as a never-allocated sink by
+    patch nothing. Page 0 is RESERVED as a never-allocated sink by
     the engine whenever this lowering is enabled (GOFR_PAGED_KV_WRITE=
     pallas), so an OOB copy-through can never revisit a tile that a real
     row writes in the same call — under Mosaic's double-buffered block
@@ -145,17 +148,12 @@ def append_tokens_paged_inplace(
         i = pl.program_id(0)
         p = pos_ref[i]
         logical = p // page
-        valid = (logical < maxp) & (p >= 0)
         # OOB pages (table entry == pool size) must drop the write
         entry = table_ref[i, jnp.minimum(logical, maxp - 1)]
-        ko_ref[0] = k_ref[0]
-        vo_ref[0] = v_ref[0]
-
-        @pl.when(valid & (entry < pool))
-        def _():
-            off = p % page
-            ko_ref[0, :, pl.ds(off, 1), :] = knew_ref[0][:, None, :].astype(ko_ref.dtype)
-            vo_ref[0, :, pl.ds(off, 1), :] = vnew_ref[0][:, None, :].astype(vo_ref.dtype)
+        valid = (logical < maxp) & (p >= 0) & (entry < pool)
+        off = jnp.where(valid, p % page, -1)
+        _patch_tile(off, knew_ref, k_ref, ko_ref)
+        _patch_tile(off, vnew_ref, v_ref, vo_ref)
 
     return pl.pallas_call(
         _kernel,
@@ -163,8 +161,8 @@ def append_tokens_paged_inplace(
             num_scalar_prefetch=2,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, hkv, d), lambda bi, p, t: (bi, 0, 0)),
-                pl.BlockSpec((1, hkv, d), lambda bi, p, t: (bi, 0, 0)),
+                pl.BlockSpec((1, hkv, 1, d), lambda bi, p, t: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, hkv, 1, d), lambda bi, p, t: (bi, 0, 0, 0)),
                 pl.BlockSpec((1, hkv, page, d), pool_map),
                 pl.BlockSpec((1, hkv, page, d), pool_map),
             ],
@@ -178,8 +176,8 @@ def append_tokens_paged_inplace(
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         input_output_aliases={4: 0, 5: 1},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pos, tbl, k_new, v_new, k_pool, v_pool)
+    )(pos, tbl, k_new[:, :, None, :], v_new[:, :, None, :], k_pool, v_pool)

@@ -44,8 +44,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gofr_tpu.ops.pallas.common import (
     NEG_INF,
-    CompilerParams,
     init_softmax_scratch,
+    select_head_row,
     softmax_block_update,
     softmax_finish,
 )
@@ -137,7 +137,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((n, hkv, group, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -151,8 +151,8 @@ def _paged_decode_q_kernel(
     q_ref,     # VMEM [1, 1, G, d]
     k_ref,     # VMEM int8 [1, 1, page, d] — the physical page from index_map
     v_ref,     # VMEM int8 [1, 1, page, d]
-    ks_ref,    # VMEM [1, 1, page] per-position K scales (same page pick)
-    vs_ref,    # VMEM [1, 1, page]
+    ks_ref,    # VMEM [1, Hkv, page] per-position K scales (same page pick)
+    vs_ref,    # VMEM [1, Hkv, page]
     o_ref,     # VMEM [1, 1, G, d]
     acc_ref,   # scratch f32 [G, d]
     m_ref,     # scratch f32 [G, 128]
@@ -164,6 +164,7 @@ def _paged_decode_q_kernel(
     group: int,
 ):
     bi = pl.program_id(0)
+    hi = pl.program_id(1)
     pi = pl.program_id(2)
     init_softmax_scratch(pi, acc_ref, m_ref, l_ref)
 
@@ -175,7 +176,7 @@ def _paged_decode_q_kernel(
     # K-scale fold: constant along the d reduction, so it multiplies the
     # finished scores per key position (decode_attention_q order: scale
     # before the mask, where a masked position's value is irrelevant).
-    s = s * ks_ref[0, 0].astype(jnp.float32)[None, :]
+    s = s * select_head_row(ks_ref[0], hi)
 
     kv_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
     s = jnp.where(kv_pos < ln_ref[bi], s, NEG_INF)
@@ -183,7 +184,7 @@ def _paged_decode_q_kernel(
     # V-scale fold happens inside the recurrence (common.py): probabilities
     # pick up vs before the PV matmul, v converts from int8 at the input.
     softmax_block_update(s, v_ref[0, 0], acc_ref, m_ref, l_ref,
-                         v_scale=vs_ref[0, 0])
+                         v_scale=select_head_row(vs_ref[0], hi))
 
     def write(out):
         o_ref[0, 0] = out.astype(o_ref.dtype)
@@ -224,7 +225,9 @@ def paged_decode_attention_q(
         return (table_ref[bi, pi], hi, 0, 0)
 
     def sc_map(bi, hi, pi, ln_ref, table_ref):
-        return (table_ref[bi, pi], hi, 0)
+        # all Hkv scale rows of the page; the kernel picks row hi
+        # (common.select_head_row says why)
+        return (table_ref[bi, pi], 0, 0)
 
     kernel = functools.partial(
         _paged_decode_q_kernel, scale=scale, page=page, n_pages=maxp, group=group
@@ -238,8 +241,8 @@ def paged_decode_attention_q(
                 pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
                 pl.BlockSpec((1, 1, page, d), kv_map),
                 pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page), sc_map),
-                pl.BlockSpec((1, 1, page), sc_map),
+                pl.BlockSpec((1, hkv, page), sc_map),
+                pl.BlockSpec((1, hkv, page), sc_map),
             ],
             out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
             scratch_shapes=[
@@ -249,7 +252,7 @@ def paged_decode_attention_q(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((n, hkv, group, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -263,8 +266,8 @@ def _paged_decode_q4_kernel(
     q_ref,     # VMEM [1, 1, G, d]
     k_ref,     # VMEM uint8 [1, 1, page, d//2] packed nibbles (index_map page)
     v_ref,     # VMEM uint8 [1, 1, page, d//2]
-    ks_ref,    # VMEM [1, 1, page] per-position K scales (same page pick)
-    vs_ref,    # VMEM [1, 1, page]
+    ks_ref,    # VMEM [1, Hkv, page] per-position K scales (same page pick)
+    vs_ref,    # VMEM [1, Hkv, page]
     o_ref,     # VMEM [1, 1, G, d]
     acc_ref,   # scratch f32 [G, d]
     m_ref,     # scratch f32 [G, 128]
@@ -276,6 +279,7 @@ def _paged_decode_q4_kernel(
     group: int,
 ):
     bi = pl.program_id(0)
+    hi = pl.program_id(1)
     pi = pl.program_id(2)
     init_softmax_scratch(pi, acc_ref, m_ref, l_ref)
 
@@ -288,13 +292,16 @@ def _paged_decode_q4_kernel(
         return jnp.concatenate([(bi32 & 0xF) - 8, ((bi32 >> 4) & 0xF) - 8], axis=-1)
 
     q = q_ref[0, 0]                              # [G, d]
-    k = unpack(k_ref[0, 0]).astype(q.dtype)      # packed → [page, d] nibbles
+    # packed → [page, d] nibbles; int32 → compute dtype goes through f32
+    # (exact for [-8, 7]; the direct int32 → bf16 convert is not one the
+    # v5e kernel compiler is known to take)
+    k = unpack(k_ref[0, 0]).astype(jnp.float32).astype(q.dtype)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, page]
     # K-scale fold: identical order to the int8 kernel — constant along the
     # d reduction, multiplies the finished scores per key position.
-    s = s * ks_ref[0, 0].astype(jnp.float32)[None, :]
+    s = s * select_head_row(ks_ref[0], hi)
 
     kv_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
     s = jnp.where(kv_pos < ln_ref[bi], s, NEG_INF)
@@ -302,7 +309,7 @@ def _paged_decode_q4_kernel(
     # V-scale fold inside the recurrence (common.py), with V unpacked from
     # nibbles in-register — the PV matmul input converts to f32 there.
     softmax_block_update(s, unpack(v_ref[0, 0]), acc_ref, m_ref, l_ref,
-                         v_scale=vs_ref[0, 0])
+                         v_scale=select_head_row(vs_ref[0], hi))
 
     def write(out):
         o_ref[0, 0] = out.astype(o_ref.dtype)
@@ -347,7 +354,9 @@ def paged_decode_attention_q4(
         return (table_ref[bi, pi], hi, 0, 0)
 
     def sc_map(bi, hi, pi, ln_ref, table_ref):
-        return (table_ref[bi, pi], hi, 0)
+        # all Hkv scale rows of the page; the kernel picks row hi
+        # (common.select_head_row says why)
+        return (table_ref[bi, pi], 0, 0)
 
     kernel = functools.partial(
         _paged_decode_q4_kernel, scale=scale, page=page, n_pages=maxp, group=group
@@ -361,8 +370,8 @@ def paged_decode_attention_q4(
                 pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
                 pl.BlockSpec((1, 1, page, d2), kv_map),
                 pl.BlockSpec((1, 1, page, d2), kv_map),
-                pl.BlockSpec((1, 1, page), sc_map),
-                pl.BlockSpec((1, 1, page), sc_map),
+                pl.BlockSpec((1, hkv, page), sc_map),
+                pl.BlockSpec((1, hkv, page), sc_map),
             ],
             out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
             scratch_shapes=[
@@ -372,7 +381,7 @@ def paged_decode_attention_q4(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((n, hkv, group, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

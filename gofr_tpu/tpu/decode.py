@@ -184,7 +184,7 @@ def dispatch_spec_paged(eng) -> bool:
     eng._announce(TAG_SPEC, packed.shape[0], 1, packed)  # b=1: live, carry applies
     carry = eng._spec_carry
     if carry is None:
-        carry = (jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32))
+        carry = (eng._zero_carry(), eng._zero_carry())
     toks_dev, accs_dev, eng.cache, eng._spec_carry = eng._spec_chunk_fn(
         eng.params, eng._base_key, eng.cache, k, jnp.asarray(packed), carry,
         *((eng._adapter_args(),) if ae else ()))
@@ -251,7 +251,7 @@ def dispatch_spec(eng) -> bool:
     eng._announce(TAG_SPEC, packed.shape[0], 1, packed)  # b=1: live, carry applies
     carry = eng._spec_carry
     if carry is None:
-        carry = (jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32))
+        carry = (eng._zero_carry(), eng._zero_carry())
     toks_dev, accs_dev, eng.cache, eng._spec_carry = eng._spec_chunk_fn(
         eng.params, eng._base_key, eng.cache, k, jnp.asarray(packed), carry,
         *((eng._adapter_args(),) if ae else ()))
@@ -345,7 +345,7 @@ def dispatch_decode(eng) -> bool:
     eng._announce(TAG_DECODE, 1, 0, packed)  # a=1: live, carry applies
     prev = eng._prev_last
     if prev is None:
-        prev = jnp.zeros((n,), jnp.int32)
+        prev = eng._zero_carry()
     chunk_dev, last_dev, eng.cache = eng._decode_chunk(
         eng.params, eng._base_key, eng.cache, k, jnp.asarray(packed), prev,
         *((eng._adapter_args(),) if ae else ())

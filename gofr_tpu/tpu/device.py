@@ -20,12 +20,37 @@ stats are best-effort because the CPU PJRT client doesn't report them.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Any
 
 import jax
 
 from gofr_tpu.parallel import ShardingRules, mesh_from_config
+
+# <checkout>/.cache/jax: the directory that holds the gofr_tpu package, so
+# the path is the same for every process started from one checkout. The
+# path is part of the cache key — a directory that moves never hits.
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "jax")
+
+
+def ensure_compile_cache() -> str:
+    """The ONE place that decides where JAX's persistent compilation cache
+    lives; returns the directory in use. Called by ``TPUDevices`` (so every
+    App and engine gets it), tests/conftest.py and bench.py.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets no other directory in code. Otherwise the cache goes to the fixed
+    ``<checkout>/.cache/jax`` (gitignored) — never /tmp, a pid or a
+    timestamp — so a second process from the same checkout starts warm."""
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    if jax.config.jax_compilation_cache_dir != _CHECKOUT_CACHE:
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    return _CHECKOUT_CACHE
 
 
 def _maybe_init_distributed(config, logger) -> bool:
@@ -62,6 +87,7 @@ class TPUDevices:
         self.metrics = metrics
         self._lock = threading.Lock()
 
+        self.compile_cache_dir = ensure_compile_cache()
         self.distributed = _maybe_init_distributed(config, logger)
         limit = config.get_int("TPU_DEVICES", 0)
         # multi-host: the mesh MUST span the global device set so pjit
@@ -77,9 +103,10 @@ class TPUDevices:
         metrics.set_gauge("app_tpu_device_count", len(self.devices))
         self._push_memory_gauges()
         logger.infof(
-            "TPU datasource: %d %s device(s), mesh %s",
+            "TPU datasource: %d %s device(s), mesh %s, compile cache %s",
             len(self.devices), self.platform,
             dict(zip(self.mesh.axis_names, self.mesh.devices.shape)),
+            self.compile_cache_dir,
         )
 
     # -- stats -----------------------------------------------------------------

@@ -323,7 +323,7 @@ def warmup_compile(eng, lbs: list[int], bbs: list[int]) -> int:
         eng._announce(TAG_DECODE, 0, 0, packed)  # a=0: warmup, no carry
         out, _, eng.cache = eng._decode_chunk(
             eng.params, eng._base_key, eng.cache, k, jnp.asarray(packed),
-            jnp.zeros((n,), jnp.int32), *ad
+            eng._zero_carry(), *ad
         )
         jax.block_until_ready(out)
         eng._compiled.add(("decode", n, k))
@@ -346,7 +346,7 @@ def warmup_compile(eng, lbs: list[int], bbs: list[int]) -> int:
             spec_packed[1, :] = eng._cache_len + 1
             spec_packed[2, :] = 1
         eng._announce(TAG_SPEC, spec_packed.shape[0], 0, spec_packed)
-        carry = (jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32))
+        carry = (eng._zero_carry(), eng._zero_carry())
         toks, _, eng.cache, _warm_carry = eng._spec_chunk_fn(
             eng.params, eng._base_key, eng.cache, k,
             jnp.asarray(spec_packed), carry, *ad)

@@ -304,7 +304,7 @@ class LockstepFollower:
                         packed = self._recv((5 + wt, n))
                         prev = eng._prev_last if live else None
                         if prev is None:
-                            prev = jnp.zeros((n,), jnp.int32)
+                            prev = eng._zero_carry()
                         out, last, eng.cache = eng._decode_chunk(
                             eng.params, eng._base_key, eng.cache, k,
                             jnp.asarray(packed), prev)
@@ -329,8 +329,7 @@ class LockstepFollower:
                         packed = self._recv((a, n))
                         carry = eng._spec_carry if live else None
                         if carry is None:
-                            carry = (jnp.zeros((n,), jnp.int32),
-                                     jnp.zeros((n,), jnp.int32))
+                            carry = (eng._zero_carry(), eng._zero_carry())
                         toks, accs, eng.cache, carry_out = eng._spec_chunk_fn(
                             eng.params, eng._base_key, eng.cache, k,
                             jnp.asarray(packed), carry)

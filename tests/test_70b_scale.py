@@ -10,8 +10,6 @@ import subprocess
 import sys
 import textwrap
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from jaxpin import child_env  # noqa: E402
 import pytest
 
 # integration tier (CI `integration` job): multi-minute engine/process
@@ -141,7 +139,7 @@ _WORKER = textwrap.dedent("""
 
 def test_llama70b_sharded_programs_lower_on_v5e64_mesh():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _WORKER.replace("@REPO@", repo)],

@@ -182,15 +182,27 @@ def test_autotuner_pins_winner():
     assert tuner.pins() == {"paged_decode_q": "pallas"}
 
 
-def test_autotuner_failing_candidate_disqualified():
+def test_autotuner_failing_candidate_disqualified_loudly(mock_logger):
+    """A candidate the compiler refuses loses — and says so: error-level log
+    line, ``errors`` in the decision record AND at the top of the report
+    (what engine.autotune_report() / /debug/engine serve)."""
     def dies():
         raise RuntimeError("Mosaic rejected the shape")
 
-    tuner = autotune.Autotuner(device_kind="v5e", timer=autotune._default_timer)
+    tuner = autotune.Autotuner(device_kind="v5e", timer=autotune._default_timer,
+                               logger=mock_logger)
     backend = tuner.measure("decode", "4x97", "float32",
                             {"xla": lambda: jnp.zeros(()), "pallas": dies})
     assert backend == "xla"
-    assert "pallas" in tuner.decisions["decode"]["errors"]
+    assert "Mosaic rejected" in tuner.decisions["decode"]["errors"]["pallas"]
+    assert "Mosaic rejected" in tuner.report()["errors"]["decode"]["pallas"]
+    logged = [r for r in mock_logger.records if r.get("level") == "ERROR"]
+    assert any("decode candidate 'pallas' failed" in str(r.get("message")) for r in logged), \
+        mock_logger.lines
+    # a clean tuner's report carries no errors key at all
+    clean = autotune.Autotuner(device_kind="v5e", timer=lambda fn: 1.0)
+    clean.measure("decode", "4x128", "float32", {"xla": lambda: None, "pallas": lambda: None})
+    assert "errors" not in clean.report()
 
 
 def test_pinned_decision_drives_auto_resolution(monkeypatch):

@@ -213,13 +213,12 @@ def test_publisher_subscriber_examples_two_process(tmp_path):
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from jaxpin import child_env
     from tests.test_http_server import _free_port
 
     from gofr_tpu.config import DictConfig
 
     sub_port = _free_port()
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.update({
         "HTTP_PORT": str(sub_port), "METRICS_PORT": str(_free_port()),
         "PUBSUB_BACKEND": "file", "PUBSUB_DIR": str(tmp_path),

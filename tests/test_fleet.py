@@ -24,10 +24,7 @@ import time
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from jaxpin import child_env  # noqa: E402
-
-from gofr_tpu.fleet import (  # noqa: E402
+from gofr_tpu.fleet import (
     ChannelClosed,
     FleetFollowerChannel,
     FleetLeaderChannel,
@@ -36,8 +33,8 @@ from gofr_tpu.fleet import (  # noqa: E402
     Supervisor,
     chaos,
 )
-from gofr_tpu.logging import MockLogger  # noqa: E402
-from gofr_tpu.tpu.lockstep import TAG_EPOCH, TAG_PREFILL  # noqa: E402
+from gofr_tpu.logging import MockLogger
+from gofr_tpu.tpu.lockstep import TAG_EPOCH, TAG_PREFILL
 
 
 def _free_port() -> int:
@@ -465,7 +462,7 @@ _FLEET_WORKER = textwrap.dedent("""
 
 def _run_workers(src: str, roles: list[str], tmp_path, timeout: float,
                  extra_env: dict | None = None):
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.pop("XLA_FLAGS", None)
     env.pop("GOFR_CHAOS", None)
     logs = [open(tmp_path / f"{role}{i}.log", "w+") for i, role in enumerate(roles)]

@@ -14,9 +14,6 @@ import textwrap
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from jaxpin import child_env  # noqa: E402
-
 _WORKER = textwrap.dedent("""
     import faulthandler, os, sys
     faulthandler.dump_traceback_later(560, exit=True)  # post-mortem on hang
@@ -80,7 +77,7 @@ def test_two_process_lockstep_serving(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     port = _free_port()
     src = _WORKER.format(repo=repo, port=port)
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.pop("XLA_FLAGS", None)
 
     logs = [open(tmp_path / f"worker{pid}.log", "w+") for pid in (0, 1)]
@@ -165,7 +162,7 @@ def test_killed_leader_releases_follower(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     port = _free_port()
     src = _KILL_WORKER.format(repo=repo, port=port)
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.pop("XLA_FLAGS", None)
 
     logs = [open(tmp_path / f"kill{pid}.log", "w+") for pid in (0, 1)]

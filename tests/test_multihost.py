@@ -16,9 +16,6 @@ import textwrap
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from jaxpin import child_env  # noqa: E402
-
 # integration tier (CI `integration` job): multi-minute engine/process
 # runs — excluded from the tier-1 gate via -m 'not slow' (docs/testing.md)
 pytestmark = pytest.mark.slow
@@ -81,7 +78,7 @@ def test_two_process_global_mesh():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     port = _free_port()
     src = _WORKER.format(repo=repo, port=port)
-    env = child_env()
+    env = dict(os.environ)  # JAX_PLATFORMS=cpu (conftest pin) is inherited
     env.pop("XLA_FLAGS", None)  # workers pin their own device count
 
     procs = [
