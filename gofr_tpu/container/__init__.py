@@ -94,8 +94,16 @@ class Container:
         m.new_gauge("app_tpu_device_count", "visible TPU devices")
         m.new_gauge("app_tpu_hbm_used_bytes", "per-device HBM in use")
         m.new_gauge("app_tpu_hbm_limit_bytes", "per-device HBM capacity")
-        m.new_counter("app_tpu_compile_total", "XLA compilations triggered")
-        m.new_counter("app_tpu_compile_cache_hits", "batch steps served from compile cache")
+        # fed by JAX's own compile events (tpu/device.py), not by the engines
+        m.new_counter("app_tpu_compile_total",
+                      "backend compile requests: executables built or fetched from the persistent cache")
+        m.new_counter("app_tpu_compile_seconds_total", "seconds spent in backend compile requests")
+        m.new_counter("app_tpu_compile_cache_hits",
+                      "compile requests the persistent compilation cache answered")
+        # the device loop's host time by phase (tracing.LoopPhases; self time,
+        # nested phases subtracted), flushed from the engines on every scrape
+        m.new_counter("app_tpu_loop_phase_seconds_total", "device-loop host self time by phase (s)")
+        m.new_counter("app_tpu_loop_phase_total", "device-loop phase entries by phase")
         m.new_histogram("app_tpu_batch_occupancy", "occupied fraction of each device batch",
                         buckets=[0.1, 0.25, 0.5, 0.75, 0.9, 1.0])
         m.new_histogram("app_tpu_step_seconds", "device step wall time (s)")
@@ -336,6 +344,10 @@ class Container:
         self.metrics.set_gauge(
             "app_tpu_inflight_requests",
             sum(getattr(e, "_inflight_requests", 0) for e in self._engines.values()))
+        for e in self._engines.values():
+            phases = getattr(e, "_phases", None)
+            if phases is not None:
+                phases.flush(self.metrics)
         # spec-decode acceptance, divided at scrape time from raw
         # per-adapter (accepted, proposed) numerators summed across engines
         # — never an average of per-engine ratios

@@ -58,6 +58,7 @@ from gofr_tpu.ops.paged import (
 )
 from gofr_tpu.ops.quant import fake_quant_row_int4
 from gofr_tpu.ops.lora import lora_logits_delta
+from gofr_tpu.tracing import scoped
 
 # Serving entry points accept a per-lane LoRA pool (``adapters`` kwarg:
 # (sel, a, b, scale); ops/lora.py) — build_programs keys on this flag.
@@ -183,6 +184,18 @@ def _rope(cfg: LlamaConfig):
 # -- block ---------------------------------------------------------------------
 
 
+# The phases every layer body below is made of carry their tracing.SCOPES name
+# HERE (and inside the ops they call: apply_rope, the KV writes and gathers,
+# the attention ops), not at the eight call sites: a device trace then names
+# an operation by its phase whichever entry point ran it.
+
+
+@scoped("embed")
+def _embed(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
+    return params["embed"][tokens].astype(cfg.dtype)
+
+
+@scoped("qkv_rope")
 def _qkv(cfg: LlamaConfig, lp: dict, x: jnp.ndarray):
     """x [B,S,E] → q [B,S,Hq,D], k/v [B,S,Hkv,D] (post-norm, pre-rope)."""
     b, s, _ = x.shape
@@ -193,10 +206,32 @@ def _qkv(cfg: LlamaConfig, lp: dict, x: jnp.ndarray):
     return q, k, v
 
 
+@scoped("o_proj")
+def _o_proj(lp: dict, x: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    """Residual + output projection of the attention heads ([..., Hq, D])."""
+    return x + qdot(attn.reshape(*x.shape[:-1], -1), lp["wo"])
+
+
+@scoped("mlp")
 def _mlp(cfg: LlamaConfig, lp: dict, x: jnp.ndarray) -> jnp.ndarray:
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     gated = jax.nn.silu(qdot(h, lp["w_gate"])) * qdot(h, lp["w_up"])
     return qdot(gated, lp["w_down"])
+
+
+@scoped("lm_head")
+def _lm_head(cfg: LlamaConfig, params: dict, x: jnp.ndarray, adapters=None,
+             last=None, head_fn: Any = None) -> jnp.ndarray:
+    """Final norm → (``last``: pick one position per row) → vocabulary
+    projection in f32, plus the per-lane LoRA delta when ``adapters``."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if last is not None:
+        x = x[last]
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = (head_fn(x, head) if head_fn is not None else qdot(x, head)).astype(jnp.float32)
+    if adapters is not None:
+        logits = logits + lora_logits_delta(x, adapters)
+    return logits
 
 
 # -- entry points --------------------------------------------------------------
@@ -218,7 +253,7 @@ def forward(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     teacher-forced sequences with the exact adapter math serving used."""
     attn = attn_fn or mha_attention
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     b, s = tokens.shape
     positions = jnp.arange(s)[None]
 
@@ -227,15 +262,12 @@ def forward(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         a = attn(q, k, v, causal=True, kv_lengths=lengths)
-        x = x + qdot(a.reshape(b, s, -1), lp["wo"])
+        x = _o_proj(lp, x, a)
         x = x + _mlp(cfg, lp, x)
         return x, None
 
     x, _ = lax.scan(body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = head_fn(x, head) if head_fn is not None else qdot(x, head)
-    return logits.astype(jnp.float32)
+    return _lm_head(cfg, params, x, head_fn=head_fn)
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5))
@@ -255,7 +287,7 @@ def forward_pipelined(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     from gofr_tpu.parallel.sharding import ShardingRules
 
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     s = tokens.shape[1]
     positions = jnp.arange(s)[None]
     d = cfg.head_size
@@ -295,9 +327,7 @@ def forward_pipelined(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
         mesh, microbatches=microbatches, param_specs=block_specs
     )
     x = pp_forward(stage, params["blocks"], x, lengths)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return qdot(x, head).astype(jnp.float32)
+    return _lm_head(cfg, params, x)
 
 
 @partial(jax.jit, static_argnums=0, static_argnames=("attn_fn",), donate_argnums=4)
@@ -324,7 +354,7 @@ def prefill(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, lengths: jnp.nd
     if attn_fn is not None and offsets is not None:
         raise ValueError("attn_fn applies to whole-prompt prefill only (offsets=None)")
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     b, s = tokens.shape
     chunked = offsets is not None
     positions = (offsets[:, None] if chunked else 0) + jnp.arange(s)[None]
@@ -365,7 +395,7 @@ def prefill(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, lengths: jnp.nd
                 causal=True, kv_lengths=lengths)
         else:
             attn = (attn_fn or mha_attention)(q, k, v, causal=True, kv_lengths=lengths)
-        x = x + qdot(attn.reshape(b, s, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -376,13 +406,8 @@ def prefill(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, lengths: jnp.nd
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = SlotKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = x[row, lengths - 1]  # [B,E]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(last, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(last, adapters)
-    return logits, out_cache
+    # last live position per row → [B,E] → logits
+    return _lm_head(cfg, params, x, adapters, last=(row, lengths - 1)), out_cache
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=4)
@@ -403,7 +428,7 @@ def verify_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     Out-of-bounds positions (inactive lanes) drop their writes — the same
     convention as prefill padding rows."""
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     n, t = tokens.shape
     pos2d = positions[:, None] + jnp.arange(t)[None]
     total = positions + t
@@ -430,7 +455,7 @@ def verify_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
             q, k_view.swapaxes(1, 2), v_view.swapaxes(1, 2),
             causal=True, q_offset=positions, kv_lengths=total,
         )
-        x = x + qdot(attn.reshape(n, t, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -441,12 +466,7 @@ def verify_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = SlotKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(x, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(x, adapters)
-    return logits, out_cache
+    return _lm_head(cfg, params, x, adapters), out_cache
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=4)
@@ -462,7 +482,7 @@ def decode_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, positions: 
     uniform work keeps the step a single fixed XLA program.
     """
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)  # [N,E]
+    x = _embed(cfg, params, tokens)  # [N,E]
     n = tokens.shape[0]
     pos1 = positions[:, None]  # [N,1]
     quant = isinstance(cache, QSlotKVCache)
@@ -483,7 +503,7 @@ def decode_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, positions: 
         else:
             k_layer, v_layer = append_tokens(k_layer, v_layer, positions, k, v)
             attn = decode_attention(q, k_layer, v_layer, positions + 1)
-        x = x + qdot(attn.reshape(n, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -494,12 +514,7 @@ def decode_step(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray, positions: 
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = SlotKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(x, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(x, adapters)
-    return logits, out_cache
+    return _lm_head(cfg, params, x, adapters), out_cache
 
 
 def make_cache(cfg: LlamaConfig, slots: int, max_len: int | None = None) -> SlotKVCache:
@@ -533,7 +548,7 @@ def verify_step_paged(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     decode_step_paged — the quantized layouts share plane names, so only
     the write/gather helpers differ)."""
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     n, t = tokens.shape
     pos2d = positions[:, None] + jnp.arange(t)[None]
     total = positions + t
@@ -565,7 +580,7 @@ def verify_step_paged(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
             q, k_view.swapaxes(1, 2), v_view.swapaxes(1, 2),
             causal=True, q_offset=positions, kv_lengths=total,
         )
-        x = x + qdot(attn.reshape(n, t, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -576,12 +591,7 @@ def verify_step_paged(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = PagedKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(x, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(x, adapters)
-    return logits, out_cache
+    return _lm_head(cfg, params, x, adapters), out_cache
 
 
 def make_paged_cache(cfg: LlamaConfig, pages: int, page_size: int = 128,
@@ -632,7 +642,7 @@ def prefill_paged(
     if attn_fn is not None and offsets is not None:
         raise ValueError("attn_fn applies to whole-prompt prefill only (offsets=None)")
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, tokens)
     b, s = tokens.shape
     page = cache.page_size
     off = jnp.zeros((b,), jnp.int32) if offsets is None else offsets
@@ -686,7 +696,7 @@ def prefill_paged(
             else:
                 k_layer, v_layer = write_prompts_paged(k_layer, v_layer, pages, k, v)
                 attn = (attn_fn or mha_attention)(q, k, v, causal=True, kv_lengths=lengths)
-        x = x + qdot(attn.reshape(b, s, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -697,13 +707,8 @@ def prefill_paged(
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = PagedKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = x[row, lengths - 1]  # [B,E]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(last, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(last, adapters)
-    return logits, out_cache
+    # last live position per row → [B,E] → logits
+    return _lm_head(cfg, params, x, adapters, last=(row, lengths - 1)), out_cache
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=4)
@@ -714,7 +719,7 @@ def decode_step_paged(
     """One decode step over every slot, K/V appended through the block
     table. Contract matches ``decode_step`` with ``table`` [N, MaxP]."""
     cos, sin = _rope(cfg)
-    x = params["embed"][tokens].astype(cfg.dtype)  # [N,E]
+    x = _embed(cfg, params, tokens)  # [N,E]
     n = tokens.shape[0]
     pos1 = positions[:, None]
     q4c = isinstance(cache, Q4PagedKVCache)
@@ -740,7 +745,7 @@ def decode_step_paged(
         else:
             k_layer, v_layer = append_tokens_paged(k_layer, v_layer, table, positions, k, v)
             attn = paged_decode_attention(q, k_layer, v_layer, table, positions + 1)
-        x = x + qdot(attn.reshape(n, -1), lp["wo"])
+        x = _o_proj(lp, x, attn)
         x = x + _mlp(cfg, lp, x)
         return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
 
@@ -751,9 +756,4 @@ def decode_step_paged(
     else:
         x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
         out_cache = PagedKVCache(k=new_k, v=new_v)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = qdot(x, head).astype(jnp.float32)
-    if adapters is not None:
-        logits = logits + lora_logits_delta(x, adapters)
-    return logits, out_cache
+    return _lm_head(cfg, params, x, adapters), out_cache

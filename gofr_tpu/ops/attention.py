@@ -17,6 +17,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from gofr_tpu.tracing import scoped
+
 NEG_INF = -1e30
 
 
@@ -61,6 +63,7 @@ def _group_query_heads(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
     return q.reshape(b, s, num_kv_heads, hq // num_kv_heads, d)
 
 
+@scoped("attention")
 def mha_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -180,6 +183,7 @@ def slot_decode_kernel_ok(smax: int) -> bool:
     return bkv >= min(smax, 128) and bkv % 8 == 0
 
 
+@scoped("attention")
 def decode_attention(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,
@@ -222,6 +226,7 @@ def decode_attention(
     return out.reshape(b, hq, d)
 
 
+@scoped("attention")
 def decode_attention_q(
     q: jnp.ndarray,        # [B, Hq, D]
     k_cache: jnp.ndarray,  # int8 [B, Hkv, Smax, D]
@@ -298,6 +303,7 @@ def _shard_paged_call(impl, ctx, q, pools, table, lengths):
     )(q, *pools, table, lengths)
 
 
+@scoped("attention")
 def paged_decode_attention_q(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # int8 [P, Hkv, page, D]
@@ -368,6 +374,7 @@ def _paged_decode_attention_q_local(
     return decode_attention_q(q, gkq, gvq, gks, gvs, lengths, scale=scale)
 
 
+@scoped("attention")
 def paged_decode_attention_q4(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed nibbles
@@ -442,6 +449,7 @@ def _paged_decode_attention_q4_local(
     return decode_attention_q(q, gkq, gvq, gks, gvs, lengths, scale=scale)
 
 
+@scoped("attention")
 def paged_decode_attention(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,

@@ -24,6 +24,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from gofr_tpu.tracing import scoped
+
 
 @jax.tree_util.register_dataclass
 @dataclass
@@ -109,6 +111,7 @@ def quantize_row(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return q, s
 
 
+@scoped("kv_append")
 def write_prompts_q(
     cache_q: jnp.ndarray,   # int8 [Slots, Hkv, Smax, D] (one of k/v)
     cache_s: jnp.ndarray,   # [Slots, Hkv, Smax] scales
@@ -129,6 +132,7 @@ def write_prompts_q(
     return cache_q, cache_s
 
 
+@scoped("kv_append")
 def append_tokens_q(
     cache_q: jnp.ndarray,   # int8 [B, Hkv, Smax, D]
     cache_s: jnp.ndarray,   # [B, Hkv, Smax]
@@ -165,6 +169,7 @@ def dequantize_view(cache_q: jnp.ndarray, cache_s: jnp.ndarray, dtype) -> jnp.nd
     return cache_q.astype(dtype) * cache_s[..., None].astype(dtype)
 
 
+@scoped("kv_append")
 def write_prompts(
     k_layer: jnp.ndarray,
     v_layer: jnp.ndarray,
@@ -200,6 +205,7 @@ def write_prompt(
     return write_prompts(k_layer, v_layer, slot, k_new[None], v_new[None])
 
 
+@scoped("kv_append")
 def append_tokens(
     k_layer: jnp.ndarray,
     v_layer: jnp.ndarray,

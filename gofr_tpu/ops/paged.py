@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from gofr_tpu.ops.kvcache import quantize_row
 from gofr_tpu.ops.quant import pack_int4, quantize_row_int4, unpack_int4
+from gofr_tpu.tracing import scoped
 
 # The append-lowering choice (select | scatter | pallas). Engines resolve
 # GOFR_PAGED_KV_WRITE ONCE at construction and pin it here for every trace
@@ -319,6 +320,7 @@ def kv_plane_bytes_per_position(layers: int, kv_heads: int, head_dim: int,
     return layers * kv_heads * per
 
 
+@scoped("kv_append")
 def write_prompts_paged_q(
     cache_q: jnp.ndarray,  # int8 [P, Hkv, page, D] (one of k/v)
     cache_s: jnp.ndarray,  # [P, Hkv, page]
@@ -341,6 +343,7 @@ def write_prompts_paged_q(
     return cache_q, cache_s
 
 
+@scoped("kv_append")
 def append_tokens_paged_q(
     cache_q: jnp.ndarray,   # int8 [P, Hkv, page, D]
     cache_s: jnp.ndarray,   # [P, Hkv, page]
@@ -396,6 +399,7 @@ def _corrupt_scales(gs: jnp.ndarray) -> jnp.ndarray:
     return gs
 
 
+@scoped("kv_gather")
 def gather_kv_q(
     cache_q: jnp.ndarray,  # int8 [P, Hkv, page, D]
     cache_s: jnp.ndarray,  # [P, Hkv, page]
@@ -412,6 +416,7 @@ def gather_kv_q(
     return gq, _corrupt_scales(gs)
 
 
+@scoped("kv_append")
 def write_prompts_paged_q4(
     cache_q: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed (one of k/v)
     cache_s: jnp.ndarray,  # [P, Hkv, page]
@@ -435,6 +440,7 @@ def write_prompts_paged_q4(
     return cache_q, cache_s
 
 
+@scoped("kv_append")
 def append_tokens_paged_q4(
     cache_q: jnp.ndarray,   # uint8 [P, Hkv, page, D//2] packed
     cache_s: jnp.ndarray,   # [P, Hkv, page]
@@ -474,6 +480,7 @@ def append_tokens_paged_q4(
     return cache_q, cache_s
 
 
+@scoped("kv_gather")
 def gather_kv_q4(
     cache_q: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed
     cache_s: jnp.ndarray,  # [P, Hkv, page]
@@ -492,6 +499,7 @@ def gather_kv_q4(
     return unpack_int4(gq), _corrupt_scales(gs)
 
 
+@scoped("kv_append")
 def write_prompts_paged(
     k_layer: jnp.ndarray,  # [P, Hkv, page, D]
     v_layer: jnp.ndarray,
@@ -516,6 +524,7 @@ def write_prompts_paged(
     return k_layer, v_layer
 
 
+@scoped("kv_append")
 def append_tokens_paged(
     k_layer: jnp.ndarray,   # [P, Hkv, page, D]
     v_layer: jnp.ndarray,
@@ -616,6 +625,7 @@ def swap_in_pages(cache, page_ids, payload):
     return new, jnp.sum(page_ids)
 
 
+@scoped("kv_gather")
 def gather_kv(
     k_layer: jnp.ndarray,  # [P, Hkv, page, D]
     v_layer: jnp.ndarray,

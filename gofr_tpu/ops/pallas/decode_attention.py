@@ -109,6 +109,7 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid=(b, hkv, n_kvb),
         in_specs=[
             pl.BlockSpec((b,), lambda bi, hi, ki: (0,), memory_space=pltpu.SMEM),

@@ -157,6 +157,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid=(b, hq, n_qb, n_kvb),
         in_specs=[
             pl.BlockSpec((b,), lambda bi, hi, qi, ki: (0,), memory_space=pltpu.SMEM),

@@ -82,6 +82,7 @@ def append_tokens_inplace(
     kernel = functools.partial(_append_kernel, block_s=bs, smax=smax)
     return pl.pallas_call(
         kernel,
+        name="kv_append",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
@@ -157,6 +158,7 @@ def append_tokens_paged_inplace(
 
     return pl.pallas_call(
         _kernel,
+        name="kv_append",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n,),

@@ -121,6 +121,7 @@ def paged_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n, hkv, maxp),
@@ -234,6 +235,7 @@ def paged_decode_attention_q(
     )
     out = pl.pallas_call(
         kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n, hkv, maxp),
@@ -363,6 +365,7 @@ def paged_decode_attention_q4(
     )
     out = pl.pallas_call(
         kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n, hkv, maxp),

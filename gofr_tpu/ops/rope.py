@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from gofr_tpu.tracing import scoped
+
 
 def rope_table(
     max_len: int,
@@ -27,6 +29,7 @@ def rope_table(
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
 
 
+@scoped("qkv_rope")
 def apply_rope(
     x: jnp.ndarray,
     positions: jnp.ndarray,

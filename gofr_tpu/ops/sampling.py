@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from gofr_tpu.tracing import scoped
+
 NEG_INF = -1e30
 
 
@@ -37,6 +39,7 @@ def truncate_logits(logits: jnp.ndarray, top_k: int = 0, top_p: float = 1.0) -> 
     return logits
 
 
+@scoped("sample")
 def sample_token(
     logits: jnp.ndarray,
     key: jax.Array,

@@ -192,7 +192,7 @@ def dispatch_spec_paged(eng) -> bool:
              if eng.perf is not None else None)
     eng._dq.append(("spec", (toks_dev, accs_dev), [(i, s) for i, s in lanes],
                     t0, occupancy, ("decode_spec", n, k, eng.spec_tokens),
-                    pstep))
+                    pstep, eng._next_seq()))
     return True
 
 
@@ -259,7 +259,7 @@ def dispatch_spec(eng) -> bool:
              if eng.perf is not None else None)
     eng._dq.append(("spec", (toks_dev, accs_dev), [(i, s) for i, s in lanes],
                     t0, occupancy, ("decode_spec", n, k, eng.spec_tokens),
-                    pstep))
+                    pstep, eng._next_seq()))
     return True
 
 
@@ -354,7 +354,7 @@ def dispatch_decode(eng) -> bool:
     pstep = (eng.perf.step_decode(len(lanes), k, hist, t0)
              if eng.perf is not None else None)
     eng._dq.append(("plain", chunk_dev, [(i, s) for i, s, _ in lanes],
-                    t0, occupancy, ("decode", n, k), pstep))
+                    t0, occupancy, ("decode", n, k), pstep, eng._next_seq()))
     return True
 
 
@@ -368,12 +368,12 @@ def process_decode(eng) -> bool:
     prefix-cache host→device page swap-ins."""
     if not eng._dq:
         return False
-    kind, dev, meta, t0, occupancy, sig, pstep = eng._dq.popleft()
-    if kind == "spec":
-        toks = np.asarray(dev[0])  # [k, n, g+1] int32 — tokens, never logits
-        accs = np.asarray(dev[1])  # [k, n]
-    else:
-        chunk = np.asarray(dev)  # int32 tokens, never logits
+    kind, dev, meta, t0, occupancy, sig, pstep, seq = eng._dq.popleft()
+    with eng._phases.phase("readback", seq=seq, kind=kind):
+        # int32 tokens, never logits (spec: [k, n, g+1] tokens and [k, n]
+        # acceptance counts)
+        host = (tuple(np.asarray(d) for d in dev) if kind == "spec"
+                else np.asarray(dev))
     if pstep is not None:
         # the result just landed on the host: everything from here on is
         # fold time, not device time (perf plane separates the two)
@@ -382,17 +382,25 @@ def process_decode(eng) -> bool:
         # stop() declared this thread wedged and already failed/cleared
         # everything; the slot/page state now belongs to the caller.
         return False
+    with eng._phases.phase("fold", seq=seq, kind=kind):
+        _fold(eng, kind, host, meta, t0, occupancy, sig, pstep)
+    return True
+
+
+def _fold(eng, kind, host, meta, t0, occupancy, sig, pstep) -> None:
+    """Fold one read-back ``_dq`` entry into slot state (the body of the
+    loop's ``fold`` phase)."""
     if kind == "swapin":
-        # chunk is the upload's completion marker (already read back above,
-        # i.e. the host→device page copy has landed); fold is bookkeeping
+        # host is the upload's completion marker (already read back, i.e.
+        # the host→device page copy has landed); fold is bookkeeping
         eng._fold_swapin(meta, t0, occupancy, sig, pstep)
-        return True
+        return
     if kind == "prefill":
-        eng._fold_prefill(chunk, meta, t0, occupancy, sig, pstep)
-        return True
+        eng._fold_prefill(host, meta, t0, occupancy, sig, pstep)
+        return
     if kind == "chunk":
-        eng._fold_chunk(chunk, meta, t0, occupancy, sig, pstep)
-        return True
+        eng._fold_chunk(host, meta, t0, occupancy, sig, pstep)
+        return
     n, k = sig[1], sig[2]
     with eng._state_lock:
         # per-adapter attribution covers DISPATCHED lanes — a lane freed
@@ -406,8 +414,8 @@ def process_decode(eng) -> bool:
             dev_s = eng._record_step(
                 "decode_spec", time.monotonic() - t0, occupancy,
                 sig, pstep, adapter_ids=ads)
-            _fold_spec(eng, toks, accs, meta, k, sig[3], dev_s)
-            return True
+            _fold_spec(eng, host[0], host[1], meta, k, sig[3], dev_s)
+            return
         dev_s = eng._record_step("decode", time.monotonic() - t0, occupancy,
                                  ("decode", n, k), pstep, adapter_ids=ads)
 
@@ -426,7 +434,7 @@ def process_decode(eng) -> bool:
                 s.request.complete(error=RequestTimeout())
                 continue
             for j in range(k):
-                tok = int(chunk[i, j])
+                tok = int(host[i, j])
                 s.pos += 1
                 s.last_token = tok
                 s.generated.append(tok)
@@ -436,4 +444,3 @@ def process_decode(eng) -> bool:
                 if eng.slots[i] is not s:  # EOS/length mid-chunk: rest discarded
                     break
         eng.metrics.increment_counter("app_tpu_tokens_total", accepted)
-        return True

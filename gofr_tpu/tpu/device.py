@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import os
 import threading
+import weakref
 from typing import Any
 
 import jax
@@ -45,12 +46,51 @@ def ensure_compile_cache() -> str:
     sets no other directory in code. Otherwise the cache goes to the fixed
     ``<checkout>/.cache/jax`` (gitignored) — never /tmp, a pid or a
     timestamp — so a second process from the same checkout starts warm."""
+    # An executable carries its operations' names — the tracing.SCOPES path a
+    # profiler shows. JAX leaves them out of the cache key by default, so a
+    # cache filled by another build of this code would hand back executables
+    # under the OLD names; with them in the key it compiles anew instead.
+    if not jax.config.jax_compilation_cache_include_metadata_in_key:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
     if jax.config.jax_compilation_cache_dir != _CHECKOUT_CACHE:
         jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
     return _CHECKOUT_CACHE
+
+
+# -- compiles, from JAX's own events -----------------------------------------------
+#
+# JAX reports every backend compile REQUEST (an executable built, or fetched
+# from the persistent cache: either way a program nobody had ready) as a
+# duration event, and each request the persistent cache answered as a plain
+# event. One listener pair per process — jax.monitoring has no public
+# unregister — fans them out to the TPUDevices objects alive at the time.
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_live: "weakref.WeakSet[TPUDevices]" = weakref.WeakSet()
+_listening = threading.Lock()  # held forever once the listeners are registered
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        for tpu in list(_live):
+            tpu._on_compile(seconds)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        for tpu in list(_live):
+            tpu._on_cache_hit()
+
+
+def _listen_for_compiles(tpu: "TPUDevices") -> None:
+    _live.add(tpu)
+    if _listening.acquire(blocking=False):
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
 
 
 def _maybe_init_distributed(config, logger) -> bool:
@@ -99,6 +139,7 @@ class TPUDevices:
         self.mesh = mesh_from_config(config, devices=self.devices)
         self.rules = ShardingRules()
         self._compiles = 0
+        _listen_for_compiles(self)
 
         metrics.set_gauge("app_tpu_device_count", len(self.devices))
         self._push_memory_gauges()
@@ -133,15 +174,20 @@ class TPUDevices:
             self.metrics.set_gauge("app_tpu_hbm_used_bytes", s["bytes_in_use"], device=dev_id)
             self.metrics.set_gauge("app_tpu_hbm_limit_bytes", s["bytes_limit"], device=dev_id)
 
-    def record_compile(self) -> None:
-        """Engines call this when a (shape-bucket) program compiles for the
-        first time — the compile-cache-miss signal of the north star."""
+    def _on_compile(self, seconds: float) -> None:
         with self._lock:
             self._compiles += 1
         self.metrics.increment_counter("app_tpu_compile_total", 1)
+        self.metrics.increment_counter("app_tpu_compile_seconds_total", seconds)
+
+    def _on_cache_hit(self) -> None:
+        self.metrics.increment_counter("app_tpu_compile_cache_hits", 1)
 
     @property
     def compile_count(self) -> int:
+        """Backend compile requests JAX reported in this process since this
+        object was built (whoever asked: engines, warm-up, a handler's own
+        jit) — what ``app_tpu_compile_total`` exports."""
         return self._compiles
 
     # -- health (container/health.go parity) -----------------------------------

@@ -58,7 +58,7 @@ import numpy as np
 from gofr_tpu.fleet import chaos
 from gofr_tpu.http.errors import DeadlineExceeded, RequestTimeout, ServiceUnavailable
 from gofr_tpu.qos.scheduler import QoSQueue
-from gofr_tpu.tracing import RequestTrace, current_span
+from gofr_tpu.tracing import LoopPhases, RequestTrace, current_span
 from gofr_tpu.tpu.lockstep import TAG_CHUNK, TAG_DECODE, TAG_PREFILL, TAG_SPEC
 from gofr_tpu.native import plan_prefill, planner_in_use
 from gofr_tpu.models.base import ModelSpec, get_family
@@ -667,11 +667,10 @@ class _EngineBase:
                                         knobs=knobs)
         if self.qos is not None:
             self.qos.observe_step(seconds)  # feeds the queue-wait estimator
-        if signature in self._compiled:
-            self.metrics.increment_counter("app_tpu_compile_cache_hits", 1)
-        else:
-            self._compiled.add(signature)
-            self.tpu.record_compile()
+        # the signatures this engine has run (warm-up and the benchmark read
+        # the set); compiles themselves are counted from JAX's own events
+        # (tpu/device.py)
+        self._compiled.add(signature)
         return device_s
 
     def health_check(self) -> dict[str, Any]:
@@ -1449,6 +1448,11 @@ class GenerateEngine(_EngineBase):
         self._base_key = jax.random.key(seed)
         self._step_count = 0
         self._dq: collections.deque = collections.deque()  # dispatched, unprocessed
+        # every dispatch onto _dq takes the next number; its entry, the
+        # loop.dispatch_* / loop.readback / loop.fold annotations and the
+        # request's engine.prefill span carry it (docs/observability.md)
+        self._dispatch_seq = 0
+        self._phases = LoopPhases()  # host time of _loop by phase, for /metrics and the profiler
         self._prev_last = None  # device-resident [slots] last-sampled-token carry
         self._spec_carry = None  # device-resident ([slots] token, [slots] hlen)
 
@@ -3323,9 +3327,23 @@ class GenerateEngine(_EngineBase):
             # at the very next iteration. ``depth`` is re-read every
             # iteration — a live pipeline_depth move simply changes how far
             # the drain below lets the queue refill.
-            self._apply_pending_knobs()
-            if self._control is not None:
-                self._control.maybe_tick(time.monotonic())
+            phase = self._phases.phase
+            with phase("control"):
+                self._apply_pending_knobs()
+                if self._control is not None:
+                    self._control.maybe_tick(time.monotonic())
+                if self._chaos_step is not None:
+                    self._chaos_step(step=self._step_count)
+                if self._ls is not None and self._ls.has_pending():
+                    # fleet membership change: admit (re)joining followers at
+                    # this step boundary via an epoch bump (requeue + reset)
+                    self._fleet_admit()
+                if self._pending_weights is not None:
+                    # live hot-swap staged by adopt_weights: drain + requeue +
+                    # epoch bump at this step boundary (zero-drop)
+                    self._apply_pending_weights()
+                if self._hotswap_dir is not None:
+                    self._poll_hotswap()
             depth = self.pipeline_depth
             # One bounded in-flight device queue (self._dq): batched
             # prefill, chunked prefill, and decode/spec chunks all DISPATCH
@@ -3337,40 +3355,38 @@ class GenerateEngine(_EngineBase):
             # pages for the worst-case accepted span at dispatch time and
             # the fold releases the surplus, so page allocation never waits
             # on readback (decode.dispatch_spec_paged).
-            if self._chaos_step is not None:
-                self._chaos_step(step=self._step_count)
-            if self._ls is not None and self._ls.has_pending():
-                # fleet membership change: admit (re)joining followers at
-                # this step boundary via an epoch bump (requeue + reset)
-                self._fleet_admit()
-            if self._pending_weights is not None:
-                # live hot-swap staged by adopt_weights: drain + requeue +
-                # epoch bump at this step boundary (zero-drop)
-                self._apply_pending_weights()
-            if self._hotswap_dir is not None:
-                self._poll_hotswap()
             processed = False
-            admitted = self._admit()
+            # phases nested in "admit" (the prefill dispatches, a depth-1
+            # drain's readback and fold) are counted as themselves: a phase's
+            # seconds are its SELF time
+            with phase("admit"):
+                admitted = self._admit()
+                if depth == 1:
+                    # TRULY synchronous at depth 1: each dispatch is read back
+                    # before the next phase dispatches (the pre-unification
+                    # behavior, and what "fully synchronous" promises operators
+                    # debugging with ENGINE_PIPELINE=1 — also the honest "off"
+                    # arm of the bench's overlap A/B)
+                    while self._dq:
+                        processed = process_decode(self) or processed
+                # one chunk of ONE long prompt per iteration, so decode of the
+                # other slots keeps stepping between chunks (TTFT fairness)
+                chunked = self._advance_chunked()
             if depth == 1:
-                # TRULY synchronous at depth 1: each dispatch is read back
-                # before the next phase dispatches (the pre-unification
-                # behavior, and what "fully synchronous" promises operators
-                # debugging with ENGINE_PIPELINE=1 — also the honest "off"
-                # arm of the bench's overlap A/B)
                 while self._dq:
                     processed = process_decode(self) or processed
-            # one chunk of ONE long prompt per iteration, so decode of the
-            # other slots keeps stepping between chunks (TTFT fairness)
-            chunked = self._advance_chunked()
-            if depth == 1:
-                while self._dq:
-                    processed = process_decode(self) or processed
-            if not self.spec_tokens:
-                dispatched = dispatch_decode(self)
-            elif self.kv_layout == "slot":
-                dispatched = dispatch_spec(self)
-            else:
-                dispatched = dispatch_spec_paged(self)
+            with phase("dispatch_decode") as ph:
+                seq = self._dispatch_seq
+                if not self.spec_tokens:
+                    dispatched = dispatch_decode(self)
+                elif self.kv_layout == "slot":
+                    dispatched = dispatch_spec(self)
+                else:
+                    dispatched = dispatch_spec_paged(self)
+                if self._dispatch_seq != seq:
+                    ph.tag(seq=self._dispatch_seq, kind=self._dq[-1][0])
+                else:
+                    ph.uncount()  # no lane to decode: nothing went to the device
             busy = admitted or chunked or dispatched
             # drain to depth-1 in-flight entries while work keeps arriving
             # (each blocking readback overlaps every younger dispatch);
@@ -3393,7 +3409,8 @@ class GenerateEngine(_EngineBase):
                 # idle: block briefly for work without consuming (a get/put
                 # round trip would skew QoS wait metrics and fair credits,
                 # and could reorder same-class FIFO arrivals)
-                self._queue.wait_nonempty(0.2)
+                with phase("wait_work"):
+                    self._queue.wait_nonempty(0.2)
                 if self.perf is not None:
                     # nothing queued, nothing in flight: advance the bubble
                     # floor so true idleness never counts as pipeline bubble
@@ -3524,13 +3541,17 @@ class GenerateEngine(_EngineBase):
             s.dispatched = offset + chunk
             self._step_count += 1
             step = self._step_count
+            seq = self._next_seq()
+            rt = s.request.kw.get("_rt")
+            if rt is not None:
+                rt.tag("engine.prefill", **{"step.seq": seq})  # the newest chunk's
             temp = float(s.request.kw.get("temperature", 0.0))
             t0 = time.monotonic()
 
         # device dispatch OUTSIDE the state lock: everything in the plan is
         # immutable (prompt_tokens) or snapshotted above (table row, step)
-        executor.dispatch_chunk(self, executor.ChunkPlan(
-            idx, s, chunk, offset, last, lb, table_row, temp, step, t0))
+        self._dispatch_prefill(executor.dispatch_chunk, executor.ChunkPlan(
+            idx, s, chunk, offset, last, lb, table_row, temp, step, t0, seq))
         return True
 
     def _fold_chunk(self, first: np.ndarray, meta, t0: float,
@@ -3808,6 +3829,7 @@ class GenerateEngine(_EngineBase):
             table_rows = (self._table[rows].copy()
                           if self.kv_layout == "paged" else None)
             t0 = time.monotonic()
+            seq = self._next_seq()
             meta: list[tuple[int, _Slot]] = []
             for i, (req, toks) in enumerate(ready):
                 self._mark_admitted(req, t0)
@@ -3816,7 +3838,8 @@ class GenerateEngine(_EngineBase):
                 rt = req.kw.get("_rt")
                 if rt is not None:
                     rt.begin("engine.prefill",
-                             **{"prefill.len_bucket": lb, "prefill.batch": nb})
+                             **{"prefill.len_bucket": lb, "prefill.batch": nb,
+                                "step.seq": seq})
                 ad = (ad_of.get(id(req), (None, 0))
                       if ad_of is not None else (None, 0))
                 slot = _Slot(
@@ -3841,9 +3864,22 @@ class GenerateEngine(_EngineBase):
         # device dispatch OUTSIDE the state lock (executor layer): token/
         # temp data rides the immutable `ready` list, lanes and table rows
         # were snapshotted under the lock above
-        executor.dispatch_prefill(self, executor.PrefillPlan(
-            ready, meta, nb, lb, w, rows, table_rows, step, t0))
+        self._dispatch_prefill(executor.dispatch_prefill, executor.PrefillPlan(
+            ready, meta, nb, lb, w, rows, table_rows, step, t0, seq))
         return True
+
+    def _next_seq(self) -> int:
+        """The number the next ``_dq`` entry carries (device thread only)."""
+        self._dispatch_seq += 1
+        return self._dispatch_seq
+
+    def _dispatch_prefill(self, dispatch, plan) -> None:
+        """The ONE site of the ``dispatch_prefill`` loop phase: staging,
+        ``jnp.asarray`` and the program call of a batched prefill
+        (``executor.dispatch_prefill``) or a prefill chunk
+        (``executor.dispatch_chunk``), outside the state lock."""
+        with self._phases.phase("dispatch_prefill", seq=plan.seq, kind=plan.kind):
+            dispatch(self, plan)
 
     def _fold_prefill(self, first: np.ndarray, meta, t0: float,
                       occupancy: float, sig: tuple, pstep=None) -> None:

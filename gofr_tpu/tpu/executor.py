@@ -56,9 +56,11 @@ class PrefillPlan:
     prompt token arrays); lanes/table rows were copied under the lock."""
 
     __slots__ = ("ready", "meta", "nb", "lb", "w", "rows", "table_rows",
-                 "step", "t0")
+                 "step", "t0", "seq")
+    kind = "prefill"  # the _dq entry kind this plan dispatches
 
-    def __init__(self, ready, meta, nb, lb, w, rows, table_rows, step, t0):
+    def __init__(self, ready, meta, nb, lb, w, rows, table_rows, step, t0,
+                 seq):
         self.ready = ready
         self.meta = meta
         self.nb = nb
@@ -68,6 +70,7 @@ class PrefillPlan:
         self.table_rows = table_rows
         self.step = step
         self.t0 = t0
+        self.seq = seq
 
 
 class ChunkPlan:
@@ -76,10 +79,11 @@ class ChunkPlan:
     block-table row."""
 
     __slots__ = ("idx", "slot", "chunk", "offset", "last", "lb",
-                 "table_row", "temp", "step", "t0")
+                 "table_row", "temp", "step", "t0", "seq")
+    kind = "chunk"
 
     def __init__(self, idx, slot, chunk, offset, last, lb, table_row,
-                 temp, step, t0):
+                 temp, step, t0, seq):
         self.idx = idx
         self.slot = slot
         self.chunk = chunk
@@ -90,6 +94,7 @@ class ChunkPlan:
         self.temp = temp
         self.step = step
         self.t0 = t0
+        self.seq = seq
 
 
 def dispatch_prefill(eng, plan: PrefillPlan) -> None:
@@ -142,7 +147,8 @@ def dispatch_prefill(eng, plan: PrefillPlan) -> None:
         sum(toks.shape[0] for _, toks in plan.ready), plan.t0)
         if eng.perf is not None else None)
     eng._dq.append(("prefill", first_dev, plan.meta, plan.t0,
-                    len(plan.ready) / nb, ("prefill", lb, nb), pstep))
+                    len(plan.ready) / nb, ("prefill", lb, nb), pstep,
+                    plan.seq))
 
 
 def dispatch_chunk(eng, plan: ChunkPlan) -> None:
@@ -177,7 +183,8 @@ def dispatch_chunk(eng, plan: ChunkPlan) -> None:
              if eng.perf is not None else None)
     eng._dq.append(("chunk", first_dev,
                     (plan.idx, s, chunk, offset, plan.last),
-                    plan.t0, chunk / lb, ("prefill_chunk", lb, 1), pstep))
+                    plan.t0, chunk / lb, ("prefill_chunk", lb, 1), pstep,
+                    plan.seq))
 
 
 def dispatch_swapins(eng) -> bool:
@@ -222,7 +229,7 @@ def dispatch_swapins(eng) -> bool:
         pstep = (eng.perf.step_swapin(nbytes, t0)
                  if eng.perf is not None else None)
         eng._dq.append(("swapin", marker, (idx, slot, keys, n, nbytes),
-                        t0, n / w, ("swapin", w), pstep))
+                        t0, n / w, ("swapin", w), pstep, eng._next_seq()))
     return True
 
 

@@ -71,8 +71,10 @@ import jax
 import jax.numpy as jnp
 
 from gofr_tpu.ops.sampling import sample_token, truncate_logits
+from gofr_tpu.tracing import scoped
 
 
+@scoped("sample")
 def speculative_sample(key, p_logits, drafts, temps, q_logits=None,
                        top_k=0, top_p=1.0):
     """Distribution-exact speculative sampling for one verify step

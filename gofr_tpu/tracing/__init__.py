@@ -16,11 +16,22 @@ cross threads) and hangs ``engine.queue_wait``/``engine.prefill``/
 ``engine.decode``/``engine.finish`` children under it, guarded by
 ``Tracer.enabled`` so ``TRACE_EXPORTER=none`` costs the serving loop one branch
 (docs/observability.md).
+
+Two more sets of names live here, always on, for whoever reads a JAX profiler
+trace (``GET /debug/profile``, the benchmark's ``--trace 1``): ``SCOPES`` —
+the phases of the served programs, entered with :func:`scope` /
+:func:`scoped` (``jax.named_scope``, so the name rides every operation's
+``op_name`` and survives the compiler's renumbering) — and ``LOOP_PHASES`` —
+what the engine's device loop does on the host, entered with
+:meth:`LoopPhases.phase` (a ``jax.profiler.TraceAnnotation`` on the device
+trace's clock, plus self-time counters for ``/metrics``). These two are the
+only places the package spells ``named_scope`` or ``TraceAnnotation``.
 """
 
 from __future__ import annotations
 
 import contextvars
+import functools
 import json
 import os
 import queue
@@ -46,6 +57,7 @@ class Span:
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "start", "end",
         "attributes", "status", "kind", "sampled", "events", "_tracer", "_token",
+        "_t0_ns",
     )
 
     def __init__(self, name: str, trace_id: str, span_id: str, parent_id: str | None,
@@ -55,7 +67,11 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.sampled = sampled
+        # the epoch is read ONCE; everything after it is measured on the
+        # monotonic perf counter and added to ``start``, so a wall-clock step
+        # (NTP, a suspended VM) can neither shrink nor stretch a span
         self.start = time.time()
+        self._t0_ns = time.perf_counter_ns()
         self.end: float | None = None
         self.attributes: dict[str, Any] = {}
         self.status: str = "OK"
@@ -73,8 +89,12 @@ class Span:
         — cheaper than a child span for things with no meaningful duration."""
         if self.events is None:
             self.events = []
-        self.events.append({"name": name, "ts": time.time(), "attributes": attributes})
+        self.events.append({"name": name, "ts": self._now(), "attributes": attributes})
         return self
+
+    def _now(self) -> float:
+        """Epoch seconds on this span's own clock: start + monotonic elapsed."""
+        return self.start + (time.perf_counter_ns() - self._t0_ns) / 1e9
 
     def set_status(self, status: str) -> "Span":
         self.status = status
@@ -83,7 +103,7 @@ class Span:
     def finish(self) -> None:
         if self.end is not None:
             return
-        self.end = time.time()
+        self.end = self._now()
         if self._token is not None:
             try:
                 _current_span.reset(self._token)
@@ -105,7 +125,7 @@ class Span:
 
     @property
     def duration_us(self) -> int:
-        end = self.end if self.end is not None else time.time()
+        end = self.end if self.end is not None else self._now()
         return int((end - self.start) * 1e6)
 
     def traceparent(self) -> str:
@@ -254,7 +274,7 @@ class OTLPExporter(SpanExporter):
             "name": s.name,
             "kind": _OTLP_KIND.get(s.kind, 1),
             "startTimeUnixNano": str(int(s.start * 1e9)),
-            "endTimeUnixNano": str(int((s.end if s.end is not None else time.time()) * 1e9)),
+            "endTimeUnixNano": str(int((s.end if s.end is not None else s._now()) * 1e9)),
             "attributes": _otlp_attrs(s.attributes),
             # STATUS_CODE_ERROR=2; finished-OK spans report UNSET (0), the
             # OTel default for spans nobody explicitly marked
@@ -421,6 +441,12 @@ class RequestTrace:
         if span is not None:
             span.add_event(name, **attrs)
 
+    def tag(self, within: str, **attrs: Any) -> None:
+        """Set attributes on the named phase span while it is open."""
+        span = self.spans.get(within)
+        if span is not None:
+            span.attributes.update(attrs)
+
     def close_all(self, error: Exception | None = None) -> None:
         """Finish every still-open span (and the synthetic root) — the
         request's done callback calls this so cancelled/timed-out/failed
@@ -490,3 +516,121 @@ def tracer_from_config(config, logger, service_name: str) -> Tracer:
         return Tracer(ZipkinExporter(url, service_name))
     logger.warnf("unknown TRACE_EXPORTER %r; tracing disabled", exporter_name)
     return Tracer(NoopExporter())
+
+
+# -- names inside the program: device scopes and device-loop phases --------------
+
+# The phases of every served program, one flat list. A name is entered where
+# the work is written (inside ``append_tokens_paged``, inside ``gather_kv``),
+# so every layer body that calls the op carries it; Pallas kernels take the
+# same name through their ``name=``. A reader attributes a device operation to
+# the INNERMOST of these in its ``op_name`` path; an operation with none of
+# them is the compiler's own (loop plumbing, inserted copies).
+SCOPES = ("embed", "qkv_rope", "kv_append", "kv_gather", "attention",
+          "o_proj", "mlp", "lm_head", "sample")
+
+# What the engine's device loop does on the host, a closed list (the loop's
+# own comments say which lines belong to which).
+LOOP_PHASES = ("control", "admit", "dispatch_prefill", "dispatch_decode",
+               "readback", "fold", "wait_work")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`. Metadata only:
+    it changes no operation of the compiled program."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown program scope {name!r}; the list is tracing.SCOPES")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator form of :func:`scope` for a function traced under jit."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+class _Phase:
+    """One entered loop phase (what :meth:`LoopPhases.phase` returns)."""
+
+    __slots__ = ("_owner", "name", "_ann", "_t0", "_children", "_counted")
+
+    def __init__(self, owner: "LoopPhases", name: str, attrs: dict):
+        self._owner = owner
+        self.name = name
+        self._ann = owner._annotation("loop." + name, **attrs)
+        self._children = 0.0
+        self._counted = True
+
+    def tag(self, **attrs) -> None:
+        """Attributes known only inside the phase (the ``seq`` of a dispatch)."""
+        self._ann.set_metadata(**attrs)
+
+    def uncount(self) -> None:
+        """The phase turned out to have nothing to do (no lane to decode):
+        its time still accrues, its count does not."""
+        self._counted = False
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._owner._stack.append(self)
+        self._t0 = self._owner._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        owner = self._owner
+        elapsed = owner._clock() - self._t0
+        owner._stack.pop()
+        if owner._stack:
+            owner._stack[-1]._children += elapsed
+        owner.seconds[self.name] += elapsed - self._children
+        if self._counted:
+            owner.counts[self.name] += 1
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+class LoopPhases:
+    """Host time of one device loop, by phase. ``phase(name, **attrs)`` is a
+    context manager that (a) enters ``TraceAnnotation("loop.<name>", **attrs)``
+    — an atomic load while no profiler session runs; with one, an event on
+    the host plane of the same ``.xplane.pb``, on the same clock, as the
+    device's — and (b) adds the phase's SELF time (nested phases subtracted,
+    so nothing counts twice) on ``clock`` and one to its count. Entered from
+    the loop's thread only; any thread may read ``seconds`` / ``counts`` or
+    call :meth:`flush`."""
+
+    def __init__(self, clock=time.monotonic):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._clock = clock
+        self._stack: list[_Phase] = []
+        self.seconds = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.counts = dict.fromkeys(LOOP_PHASES, 0)
+        self._flushed = (dict(self.seconds), dict(self.counts))
+        self._flush_lock = threading.Lock()
+
+    def phase(self, name: str, **attrs) -> _Phase:
+        if name not in self.seconds:
+            raise ValueError(f"unknown loop phase {name!r}; the list is tracing.LOOP_PHASES")
+        return _Phase(self, name, attrs)
+
+    def flush(self, metrics) -> None:
+        """Scrape-time export: add what accrued since the last call to
+        ``app_tpu_loop_phase_seconds_total{phase}`` and
+        ``app_tpu_loop_phase_total{phase}``."""
+        with self._flush_lock:
+            done_s, done_n = self._flushed
+            for name in LOOP_PHASES:
+                s, n = self.seconds[name], self.counts[name]
+                metrics.increment_counter(
+                    "app_tpu_loop_phase_seconds_total", s - done_s[name], phase=name)
+                metrics.increment_counter(
+                    "app_tpu_loop_phase_total", n - done_n[name], phase=name)
+                done_s[name], done_n[name] = s, n

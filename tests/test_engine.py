@@ -223,8 +223,10 @@ class TestGenerateEngine:
             assert "app_tpu_batch_occupancy" in text
             # prompt (3) + generated (4) tokens counted
             assert c.metrics.get("app_tpu_tokens_total").value() >= 7
-            # compile happened at least twice (prefill + decode programs)
+            # JAX reported at least two backend compile requests while this
+            # container's device object lived (prefill + decode programs)
             assert c.metrics.get("app_tpu_compile_total").value() >= 2
+            assert c.tpu.compile_count == c.metrics.get("app_tpu_compile_total").value()
         finally:
             eng.stop()
 
