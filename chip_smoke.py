@@ -339,7 +339,7 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
     n, hq, hkv, d = shape.slots, cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     page, chunk = shape.page_size, 8  # the engine's default decode chunk
     maxp = -(-(shape.max_len + chunk) // page)
-    pool = n * maxp + 1  # + page 0, the sink the paged append reserves
+    pool = n * maxp
     smax = -(-(shape.max_len + chunk) // 128) * 128
     rng = np.random.RandomState(0)
 
@@ -347,8 +347,11 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
         return jnp.asarray(rng.standard_normal(dims), dt)
 
     q = normal(n, hq, d)
-    k_pool, v_pool = normal(pool, hkv, page, d), normal(pool, hkv, page, d)
-    table = jnp.asarray(1 + rng.permutation(pool - 1)[: n * maxp].reshape(n, maxp), jnp.int32)
+    # the ops take whole [L, P, ...] planes and a layer index: two layers,
+    # the second one used
+    layer = 1
+    k_pool, v_pool = normal(2, pool, hkv, page, d), normal(2, pool, hkv, page, d)
+    table = jnp.asarray(rng.permutation(pool).reshape(n, maxp), jnp.int32)
     # lane 0's last logical page is unallocated (the pool-size sentinel), the
     # way the engine marks rows whose write must be dropped
     append_table = table.at[0, maxp - 1].set(pool)
@@ -375,56 +378,65 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
     def xla(fn, **kw):
         return jax.jit(functools.partial(fn, **kw))
 
-    with paged.write_mode_scope("select"):
-        cases = {
-            "flash_prefill": (
-                lambda: k_flash.flash_attention(qp, kp, vp, causal=True, kv_lengths=plen,
-                                                interpret=interpret),
-                lambda: xla(attn.mha_attention, causal=True, backend="xla")(
-                    qp, kp, vp, kv_lengths=plen), atol),
-            "slot_decode": (
-                lambda: k_slot.decode_attention(q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32),
-                                                interpret=interpret),
-                lambda: xla(attn.decode_attention, backend="xla")(
-                    q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32)), atol),
-            "paged_decode_bf16": (
-                lambda: k_paged.paged_decode_attention(q, k_pool, v_pool, table, full,
-                                                       interpret=interpret),
-                lambda: xla(attn.paged_decode_attention, backend="xla")(
-                    q, k_pool, v_pool, table, full), atol),
-            "paged_decode_int8": (
-                lambda: k_paged.paged_decode_attention_q(q, k8, v8, ks8, vs8, table, full,
-                                                         interpret=interpret),
-                lambda: xla(attn.paged_decode_attention_q, backend="xla")(
-                    q, k8, v8, ks8, vs8, table, full), atol),
-            "paged_decode_int4": (
-                lambda: k_paged.paged_decode_attention_q4(q, k4, v4, ks4, vs4, table, full,
-                                                          interpret=interpret),
-                lambda: xla(attn.paged_decode_attention_q4, backend="xla")(
-                    q, k4, v4, ks4, vs4, table, full), atol),
-            "slot_append": (
-                lambda: jax.jit(functools.partial(k_append.append_tokens_inplace,
-                                                  interpret=interpret))(
-                    k_cache, v_cache, slot_pos, k_new, v_new),
-                lambda: jax.jit(kvcache.append_tokens)(k_cache, v_cache, slot_pos, k_new, v_new),
-                0.0),
-            "paged_append": (
-                lambda: jax.jit(functools.partial(k_append.append_tokens_paged_inplace,
-                                                  interpret=interpret))(
-                    k_pool, v_pool, append_table, paged_pos, k_new, v_new),
-                lambda: jax.jit(paged.append_tokens_paged)(
-                    k_pool, v_pool, append_table, paged_pos, k_new, v_new),
-                0.0),
-        }
-        verdicts = {}
-        for name, (kernel, reference, tol) in cases.items():
-            got = jax.block_until_ready(kernel())  # a refusal by the compiler raises here
-            want = jax.block_until_ready(reference())
-            err = max(float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32))))
-                      for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
-            _require(np.isfinite(err) and err <= tol,
-                     f"kernel {name}: max |kernel - xla| = {err:g} exceeds {tol:g}")
-            verdicts[name] = {"compiled": True, "max_err": round(err, 5), "tol": tol}
+    def numpy_append(pool_plane, new):
+        """Row i at (layer, table[i, pos // page], :, pos % page); an
+        unallocated page (the pool-size sentinel) drops the row."""
+        want = np.array(pool_plane)
+        for i, p_i in enumerate(np.asarray(paged_pos)):
+            pg = int(np.asarray(append_table)[i, p_i // page])
+            if pg < pool:
+                want[layer, pg, :, p_i % page] = np.asarray(new[i])
+        return want
+
+    cases = {
+        "flash_prefill": (
+            lambda: k_flash.flash_attention(qp, kp, vp, causal=True, kv_lengths=plen,
+                                            interpret=interpret),
+            lambda: xla(attn.mha_attention, causal=True, backend="xla")(
+                qp, kp, vp, kv_lengths=plen), atol),
+        "slot_decode": (
+            lambda: k_slot.decode_attention(q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32),
+                                            interpret=interpret),
+            lambda: xla(attn.decode_attention, backend="xla")(
+                q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32)), atol),
+        "paged_decode_bf16": (
+            lambda: k_paged.paged_decode_attention(q, k_pool, v_pool, layer, table, full,
+                                                   interpret=interpret),
+            lambda: xla(attn.paged_decode_attention, backend="xla")(
+                q, k_pool, v_pool, layer, table, full), atol),
+        "paged_decode_int8": (
+            lambda: k_paged.paged_decode_attention_q(q, k8, v8, ks8, vs8, layer, table, full,
+                                                     interpret=interpret),
+            lambda: xla(attn.paged_decode_attention_q, backend="xla")(
+                q, k8, v8, ks8, vs8, layer, table, full), atol),
+        "paged_decode_int4": (
+            lambda: k_paged.paged_decode_attention_q4(q, k4, v4, ks4, vs4, layer, table, full,
+                                                      interpret=interpret),
+            lambda: xla(attn.paged_decode_attention_q4, backend="xla")(
+                q, k4, v4, ks4, vs4, layer, table, full), atol),
+        "slot_append": (
+            lambda: jax.jit(functools.partial(k_append.append_tokens_inplace,
+                                              interpret=interpret))(
+                k_cache, v_cache, slot_pos, k_new, v_new),
+            lambda: jax.jit(kvcache.append_tokens)(k_cache, v_cache, slot_pos, k_new, v_new),
+            0.0),
+        # no kernel: the one paged append (XLA's scatter into the whole
+        # planes) against a plain NumPy reference
+        "paged_append": (
+            lambda: jax.jit(paged.append_tokens_paged)(
+                k_pool, v_pool, layer, append_table, paged_pos, k_new, v_new),
+            lambda: (numpy_append(k_pool, k_new), numpy_append(v_pool, v_new)),
+            0.0),
+    }
+    verdicts = {}
+    for name, (kernel, reference, tol) in cases.items():
+        got = jax.block_until_ready(kernel())  # a refusal by the compiler raises here
+        want = jax.block_until_ready(reference())
+        err = max(float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32))))
+                  for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+        _require(np.isfinite(err) and err <= tol,
+                 f"kernel {name}: max |kernel - xla| = {err:g} exceeds {tol:g}")
+        verdicts[name] = {"compiled": True, "max_err": round(err, 5), "tol": tol}
     return verdicts
 
 

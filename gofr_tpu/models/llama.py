@@ -534,6 +534,62 @@ def make_cache_q(cfg: LlamaConfig, slots: int, max_len: int | None = None) -> QS
 
 
 # -- paged-cache entry points (ops.paged; SURVEY.md §7 stage 4) -----------------
+#
+# One idiom for the pool in all three programs: the cache pytree — whole
+# planes [L, P, Hkv, page, ...] — rides in the layer scan's CARRY beside x,
+# each layer addresses its rows by index, and the scan returns no
+# pool-shaped ``ys``. A scan cannot alias ``ys`` onto ``xs``: handing the
+# pool in as ``xs`` and taking it back as ``ys`` made XLA build a second
+# pool, restack every layer into it and copy it back each step (ROADMAP
+# S3). A carried buffer is updated in place.
+
+
+def _scan_paged_layers(params: dict, x: jnp.ndarray, cache, layer_fn):
+    """Run ``layer_fn(lp, layer, x, cache) -> (x, cache)`` over the blocks
+    with the pool carried → (x, cache)."""
+    def body(carry, xs):
+        return layer_fn(*xs, *carry), None
+
+    layers = jnp.arange(cache.num_layers, dtype=jnp.int32)
+    (x, cache), _ = lax.scan(body, (x, cache), (params["blocks"], layers))
+    return x, cache
+
+
+def _write_paged(cache, layer, pages, k, v, offsets=None):
+    """write_prompts_paged* by pool kind → the updated cache."""
+    if isinstance(cache, PagedKVCache):
+        k_pool, v_pool = write_prompts_paged(cache.k, cache.v, layer, pages, k, v, offsets)
+        return PagedKVCache(k=k_pool, v=v_pool)
+    wpp = write_prompts_paged_q4 if isinstance(cache, Q4PagedKVCache) else write_prompts_paged_q
+    kq, ks = wpp(cache.k, cache.ks, layer, pages, k, offsets)
+    vq, vs = wpp(cache.v, cache.vs, layer, pages, v, offsets)
+    return type(cache)(k=kq, v=vq, ks=ks, vs=vs)
+
+
+def _paged_views(cfg: LlamaConfig, cache, layer, table):
+    """gather_kv* by pool kind → each slot's logical K and V views at one
+    layer, [N, Hkv, MaxP*page, D] in the compute dtype."""
+    if isinstance(cache, PagedKVCache):
+        return gather_kv(cache.k, cache.v, layer, table)
+    gkv = gather_kv_q4 if isinstance(cache, Q4PagedKVCache) else gather_kv_q
+    return (dequantize_view(*gkv(cache.k, cache.ks, layer, table), cfg.dtype),
+            dequantize_view(*gkv(cache.v, cache.vs, layer, table), cfg.dtype))
+
+
+def _append_attend_paged(cache, layer, table, positions, q, k, v):
+    """One decode token per slot, by pool kind: append its K/V at
+    ``positions``, attend over ``positions + 1`` → (cache, attn)."""
+    if isinstance(cache, PagedKVCache):
+        k_pool, v_pool = append_tokens_paged(cache.k, cache.v, layer, table, positions, k, v)
+        attn = paged_decode_attention(q, k_pool, v_pool, layer, table, positions + 1)
+        return PagedKVCache(k=k_pool, v=v_pool), attn
+    q4c = isinstance(cache, Q4PagedKVCache)
+    atp = append_tokens_paged_q4 if q4c else append_tokens_paged_q
+    pda = paged_decode_attention_q4 if q4c else paged_decode_attention_q
+    kq, ks = atp(cache.k, cache.ks, layer, table, positions, k)
+    vq, vs = atp(cache.v, cache.vs, layer, table, positions, v)
+    attn = pda(q, kq, vq, ks, vs, layer, table, positions + 1)
+    return type(cache)(k=kq, v=vq, ks=ks, vs=vs), attn
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=4)
@@ -544,54 +600,28 @@ def verify_step_paged(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
     contract and stale-draft-KV invariants of ``verify_step``, with writes
     routed through per-slot block tables (``table`` [N, MaxP]; OOB rows
     drop) and attention over the gathered logical views. Handles the
-    dense, int8, and packed-int4 pools (cache-type branch, like
-    decode_step_paged — the quantized layouts share plane names, so only
-    the write/gather helpers differ)."""
+    dense, int8, and packed-int4 pools."""
     cos, sin = _rope(cfg)
     x = _embed(cfg, params, tokens)
-    n, t = tokens.shape
+    t = tokens.shape[1]
     pos2d = positions[:, None] + jnp.arange(t)[None]
     total = positions + t
-    q4c = isinstance(cache, Q4PagedKVCache)
-    quant = q4c or isinstance(cache, QPagedKVCache)
-    wpp = write_prompts_paged_q4 if q4c else write_prompts_paged_q
-    gkv = gather_kv_q4 if q4c else gather_kv_q
-    out_cls = Q4PagedKVCache if q4c else QPagedKVCache
 
-    def body(x, xs):
-        if quant:
-            lp, k_layer, ks_l, v_layer, vs_l = xs
-        else:
-            lp, k_layer, v_layer = xs
+    def layer_fn(lp, layer, x, cache):
         q, k, v = _qkv(cfg, lp, x)
         q = apply_rope(q, pos2d, cos, sin)
         k = apply_rope(k, pos2d, cos, sin)
-        if quant:
-            k_layer, ks_l = wpp(k_layer, ks_l, table, k, positions)
-            v_layer, vs_l = wpp(v_layer, vs_l, table, v, positions)
-            gkq, gks = gkv(k_layer, ks_l, table)
-            gvq, gvs = gkv(v_layer, vs_l, table)
-            k_view = dequantize_view(gkq, gks, cfg.dtype)
-            v_view = dequantize_view(gvq, gvs, cfg.dtype)
-        else:
-            k_layer, v_layer = write_prompts_paged(k_layer, v_layer, table, k, v, positions)
-            k_view, v_view = gather_kv(k_layer, v_layer, table)
+        cache = _write_paged(cache, layer, table, k, v, positions)
+        k_view, v_view = _paged_views(cfg, cache, layer, table)
         attn = mha_attention(
             q, k_view.swapaxes(1, 2), v_view.swapaxes(1, 2),
             causal=True, q_offset=positions, kv_lengths=total,
         )
         x = _o_proj(lp, x, attn)
-        x = x + _mlp(cfg, lp, x)
-        return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
+        return x + _mlp(cfg, lp, x), cache
 
-    if quant:
-        xs = (params["blocks"], cache.k, cache.ks, cache.v, cache.vs)
-        x, (new_k, new_ks, new_v, new_vs) = lax.scan(body, x, xs)
-        out_cache = out_cls(k=new_k, v=new_v, ks=new_ks, vs=new_vs)
-    else:
-        x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
-        out_cache = PagedKVCache(k=new_k, v=new_v)
-    return _lm_head(cfg, params, x, adapters), out_cache
+    x, cache = _scan_paged_layers(params, x, cache, layer_fn)
+    return _lm_head(cfg, params, x, adapters), cache
 
 
 def make_paged_cache(cfg: LlamaConfig, pages: int, page_size: int = 128,
@@ -644,71 +674,42 @@ def prefill_paged(
     cos, sin = _rope(cfg)
     x = _embed(cfg, params, tokens)
     b, s = tokens.shape
-    page = cache.page_size
     off = jnp.zeros((b,), jnp.int32) if offsets is None else offsets
     positions = off[:, None] + jnp.arange(s)[None]  # [B,S] logical positions
     row = jnp.arange(b)
-    chunked = offsets is not None
-    # pages holding THIS chunk's writes: logical pages off//page .. (off+s)//page
     total = off + lengths  # [B] cache length after this chunk
-    q4c = isinstance(cache, Q4PagedKVCache)
-    quant = q4c or isinstance(cache, QPagedKVCache)
-    wpp = write_prompts_paged_q4 if q4c else write_prompts_paged_q
-    gkv = gather_kv_q4 if q4c else gather_kv_q
-    fq = fake_quant_row_int4 if q4c else fake_quant_row
-    out_cls = Q4PagedKVCache if q4c else QPagedKVCache
+    if isinstance(cache, Q4PagedKVCache):
+        stored = fake_quant_row_int4
+    elif isinstance(cache, QPagedKVCache):
+        stored = fake_quant_row
+    else:
+        stored = lambda kv: kv  # noqa: E731 - the bf16 pool stores k/v as they are
 
-    def body(x, xs):
-        if quant:
-            lp, k_layer, ks_l, v_layer, vs_l = xs
-        else:
-            lp, k_layer, v_layer = xs
+    def layer_fn(lp, layer, x, cache):
         q, k, v = _qkv(cfg, lp, x)
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
-        if chunked:
-            if quant:
-                k_layer, ks_l = wpp(k_layer, ks_l, pages, k, off)
-                v_layer, vs_l = wpp(v_layer, vs_l, pages, v, off)
-                gkq, gks = gkv(k_layer, ks_l, pages)
-                gvq, gvs = gkv(v_layer, vs_l, pages)
-                k_view = dequantize_view(gkq, gks, cfg.dtype)
-                v_view = dequantize_view(gvq, gvs, cfg.dtype)
-            else:
-                k_layer, v_layer = write_prompts_paged(k_layer, v_layer, pages, k, v, off)
-                # attend over everything written so far (incl. this chunk)
-                k_view, v_view = gather_kv(k_layer, v_layer, pages)
+        cache = _write_paged(cache, layer, pages, k, v, offsets)
+        if offsets is not None:
+            # attend over everything written so far (incl. this chunk)
+            k_view, v_view = _paged_views(cfg, cache, layer, pages)
             attn = mha_attention(
                 q, k_view.swapaxes(1, 2), v_view.swapaxes(1, 2),
                 causal=True, q_offset=off, kv_lengths=total,
             )
         else:
-            if quant:
-                k_layer, ks_l = wpp(k_layer, ks_l, pages, k)
-                v_layer, vs_l = wpp(v_layer, vs_l, pages, v)
-                # attend to what the cache STORES (fake-quantized k/v) so a
-                # later prefix-cache hit — which reads the quantized pages —
-                # is bit-identical to this cold run (kvcache.fake_quant_row
-                # / quant.fake_quant_row_int4)
-                attn = (attn_fn or mha_attention)(
-                    q, fq(k), fq(v),
-                    causal=True, kv_lengths=lengths)
-            else:
-                k_layer, v_layer = write_prompts_paged(k_layer, v_layer, pages, k, v)
-                attn = (attn_fn or mha_attention)(q, k, v, causal=True, kv_lengths=lengths)
+            # attend to what the cache STORES (fake-quantized k/v on the
+            # quantized pools) so a later prefix-cache hit — which reads
+            # the stored pages — is bit-identical to this cold run
+            # (kvcache.fake_quant_row / quant.fake_quant_row_int4)
+            attn = (attn_fn or mha_attention)(
+                q, stored(k), stored(v), causal=True, kv_lengths=lengths)
         x = _o_proj(lp, x, attn)
-        x = x + _mlp(cfg, lp, x)
-        return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
+        return x + _mlp(cfg, lp, x), cache
 
-    if quant:
-        xs = (params["blocks"], cache.k, cache.ks, cache.v, cache.vs)
-        x, (new_k, new_ks, new_v, new_vs) = lax.scan(body, x, xs)
-        out_cache = out_cls(k=new_k, v=new_v, ks=new_ks, vs=new_vs)
-    else:
-        x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
-        out_cache = PagedKVCache(k=new_k, v=new_v)
+    x, cache = _scan_paged_layers(params, x, cache, layer_fn)
     # last live position per row → [B,E] → logits
-    return _lm_head(cfg, params, x, adapters, last=(row, lengths - 1)), out_cache
+    return _lm_head(cfg, params, x, adapters, last=(row, lengths - 1)), cache
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=4)
@@ -720,40 +721,15 @@ def decode_step_paged(
     table. Contract matches ``decode_step`` with ``table`` [N, MaxP]."""
     cos, sin = _rope(cfg)
     x = _embed(cfg, params, tokens)  # [N,E]
-    n = tokens.shape[0]
     pos1 = positions[:, None]
-    q4c = isinstance(cache, Q4PagedKVCache)
-    quant = q4c or isinstance(cache, QPagedKVCache)
-    atp = append_tokens_paged_q4 if q4c else append_tokens_paged_q
-    pda = paged_decode_attention_q4 if q4c else paged_decode_attention_q
-    out_cls = Q4PagedKVCache if q4c else QPagedKVCache
 
-    def body(x, xs):
-        if quant:
-            lp, k_layer, ks_l, v_layer, vs_l = xs
-        else:
-            lp, k_layer, v_layer = xs
+    def layer_fn(lp, layer, x, cache):
         q, k, v = _qkv(cfg, lp, x[:, None])
         q = apply_rope(q, pos1, cos, sin)[:, 0]
         k = apply_rope(k, pos1, cos, sin)[:, 0]
-        v = v[:, 0]
-        if quant:
-            k_layer, ks_l = atp(k_layer, ks_l, table, positions, k)
-            v_layer, vs_l = atp(v_layer, vs_l, table, positions, v)
-            attn = pda(
-                q, k_layer, v_layer, ks_l, vs_l, table, positions + 1)
-        else:
-            k_layer, v_layer = append_tokens_paged(k_layer, v_layer, table, positions, k, v)
-            attn = paged_decode_attention(q, k_layer, v_layer, table, positions + 1)
+        cache, attn = _append_attend_paged(cache, layer, table, positions, q, k, v[:, 0])
         x = _o_proj(lp, x, attn)
-        x = x + _mlp(cfg, lp, x)
-        return x, (k_layer, ks_l, v_layer, vs_l) if quant else (k_layer, v_layer)
+        return x + _mlp(cfg, lp, x), cache
 
-    if quant:
-        xs = (params["blocks"], cache.k, cache.ks, cache.v, cache.vs)
-        x, (new_k, new_ks, new_v, new_vs) = lax.scan(body, x, xs)
-        out_cache = out_cls(k=new_k, v=new_v, ks=new_ks, vs=new_vs)
-    else:
-        x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache.k, cache.v))
-        out_cache = PagedKVCache(k=new_k, v=new_v)
-    return _lm_head(cfg, params, x, adapters), out_cache
+    x, cache = _scan_paged_layers(params, x, cache, layer_fn)
+    return _lm_head(cfg, params, x, adapters), cache

@@ -278,29 +278,29 @@ def _kv_shard_ctx(q: jnp.ndarray, pool: jnp.ndarray):
     ctx = current_kv_shard()
     if ctx is None:
         return None
-    if q.shape[1] % ctx.shards or pool.shape[1] % ctx.shards:
+    if q.shape[1] % ctx.shards or pool.shape[2] % ctx.shards:
         return None
     return ctx
 
 
-def _shard_paged_call(impl, ctx, q, pools, table, lengths):
-    """Run ``impl(q, *pools, table, lengths)`` per-shard: q and every pool
-    plane split on their head axis (dim 1), table/lengths replicated, output
+def _shard_paged_call(impl, ctx, q, pools, layer, table, lengths):
+    """Run ``impl(q, *pools, layer, table, lengths)`` per-shard: q splits on
+    its head axis (dim 1) and every whole pool plane on its KV-head axis
+    (paged.plane_partition_spec), layer/table/lengths replicated, output
     head-sharded (no reduce — see module note above)."""
     from jax.sharding import PartitionSpec as P
 
+    from gofr_tpu.ops.paged import plane_partition_spec
+
     ax = ctx.axis
-    pool_specs = tuple(
-        P(None, ax, None, None) if p.ndim == 4 else P(None, ax, None)
-        for p in pools
-    )
+    pool_specs = tuple(plane_partition_spec(p.ndim, ax) for p in pools)
     return jax.shard_map(
         impl,
         mesh=ctx.mesh,
-        in_specs=(P(None, ax, None),) + pool_specs + (P(), P()),
+        in_specs=(P(None, ax, None),) + pool_specs + (P(), P(), P()),
         out_specs=P(None, ax, None),
         check_vma=False,
-    )(q, *pools, table, lengths)
+    )(q, *pools, jnp.asarray(layer, jnp.int32), table, lengths)
 
 
 @scoped("attention")
@@ -308,8 +308,9 @@ def paged_decode_attention_q(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # int8 [P, Hkv, page, D]
     vq_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # [P, Hkv, page]
+    ks_pool: jnp.ndarray,  # [L, P, Hkv, page]
     vs_pool: jnp.ndarray,
+    layer,                 # scalar layer index into the pool planes
     table: jnp.ndarray,    # [N, MaxP]
     lengths: jnp.ndarray,
     *,
@@ -320,9 +321,9 @@ def paged_decode_attention_q(
     if ctx is not None:
         impl = partial(_paged_decode_attention_q_local, scale=scale, backend=backend)
         return _shard_paged_call(impl, ctx, q, (kq_pool, vq_pool, ks_pool, vs_pool),
-                                 table, lengths)
+                                 layer, table, lengths)
     return _paged_decode_attention_q_local(
-        q, kq_pool, vq_pool, ks_pool, vs_pool, table, lengths,
+        q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
         scale=scale, backend=backend,
     )
 
@@ -333,6 +334,7 @@ def _paged_decode_attention_q_local(
     vq_pool: jnp.ndarray,
     ks_pool: jnp.ndarray,
     vs_pool: jnp.ndarray,
+    layer,
     table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
@@ -348,7 +350,7 @@ def _paged_decode_attention_q_local(
     the int8 logical views + scales per slot (one extra HBM round trip for
     the copy) and reuses the folded-scale dense decode path — correct
     everywhere. 'auto' follows resolve_backend (autotune pin aware)."""
-    page = kq_pool.shape[2]
+    page = kq_pool.shape[3]
     if resolve_backend(backend, op="paged_decode_q") == "pallas":
         if page % 8 == 0:
             from gofr_tpu.ops.pallas import interpret_mode
@@ -357,7 +359,7 @@ def _paged_decode_attention_q_local(
             )
 
             return pallas_paged_q(
-                q, kq_pool, vq_pool, ks_pool, vs_pool, table, lengths,
+                q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
                 scale=scale, interpret=interpret_mode(),
             )
         if backend == "pallas":
@@ -369,8 +371,8 @@ def _paged_decode_attention_q_local(
             )
     from gofr_tpu.ops.paged import gather_kv_q
 
-    gkq, gks = gather_kv_q(kq_pool, ks_pool, table)
-    gvq, gvs = gather_kv_q(vq_pool, vs_pool, table)
+    gkq, gks = gather_kv_q(kq_pool, ks_pool, layer, table)
+    gvq, gvs = gather_kv_q(vq_pool, vs_pool, layer, table)
     return decode_attention_q(q, gkq, gvq, gks, gvs, lengths, scale=scale)
 
 
@@ -379,8 +381,9 @@ def paged_decode_attention_q4(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed nibbles
     vq_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # [P, Hkv, page]
+    ks_pool: jnp.ndarray,  # [L, P, Hkv, page]
     vs_pool: jnp.ndarray,
+    layer,                 # scalar layer index into the pool planes
     table: jnp.ndarray,    # [N, MaxP]
     lengths: jnp.ndarray,
     *,
@@ -391,9 +394,9 @@ def paged_decode_attention_q4(
     if ctx is not None:
         impl = partial(_paged_decode_attention_q4_local, scale=scale, backend=backend)
         return _shard_paged_call(impl, ctx, q, (kq_pool, vq_pool, ks_pool, vs_pool),
-                                 table, lengths)
+                                 layer, table, lengths)
     return _paged_decode_attention_q4_local(
-        q, kq_pool, vq_pool, ks_pool, vs_pool, table, lengths,
+        q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
         scale=scale, backend=backend,
     )
 
@@ -404,6 +407,7 @@ def _paged_decode_attention_q4_local(
     vq_pool: jnp.ndarray,
     ks_pool: jnp.ndarray,
     vs_pool: jnp.ndarray,
+    layer,
     table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
@@ -423,7 +427,7 @@ def _paged_decode_attention_q4_local(
     'auto' follows resolve_backend (autotune pin aware, op key
     'paged_decode_q4' — tuned separately from int8 because the winner
     shifts with the unpack cost on each device generation)."""
-    page = kq_pool.shape[2]
+    page = kq_pool.shape[3]
     if resolve_backend(backend, op="paged_decode_q4") == "pallas":
         if page % 8 == 0:
             from gofr_tpu.ops.pallas import interpret_mode
@@ -432,7 +436,7 @@ def _paged_decode_attention_q4_local(
             )
 
             return pallas_paged_q4(
-                q, kq_pool, vq_pool, ks_pool, vs_pool, table, lengths,
+                q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
                 scale=scale, interpret=interpret_mode(),
             )
         if backend == "pallas":
@@ -444,8 +448,8 @@ def _paged_decode_attention_q4_local(
             )
     from gofr_tpu.ops.paged import gather_kv_q4
 
-    gkq, gks = gather_kv_q4(kq_pool, ks_pool, table)
-    gvq, gvs = gather_kv_q4(vq_pool, vs_pool, table)
+    gkq, gks = gather_kv_q4(kq_pool, ks_pool, layer, table)
+    gvq, gvs = gather_kv_q4(vq_pool, vs_pool, layer, table)
     return decode_attention_q(q, gkq, gvq, gks, gvs, lengths, scale=scale)
 
 
@@ -454,6 +458,7 @@ def paged_decode_attention(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
+    layer,
     table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
@@ -463,9 +468,9 @@ def paged_decode_attention(
     ctx = _kv_shard_ctx(q, k_pool)
     if ctx is not None:
         impl = partial(_paged_decode_attention_local, scale=scale, backend=backend)
-        return _shard_paged_call(impl, ctx, q, (k_pool, v_pool), table, lengths)
+        return _shard_paged_call(impl, ctx, q, (k_pool, v_pool), layer, table, lengths)
     return _paged_decode_attention_local(
-        q, k_pool, v_pool, table, lengths, scale=scale, backend=backend,
+        q, k_pool, v_pool, layer, table, lengths, scale=scale, backend=backend,
     )
 
 
@@ -473,30 +478,34 @@ def _paged_decode_attention_local(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
+    layer,
     table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
     scale: float | None = None,
     backend: str = "auto",
 ) -> jnp.ndarray:
-    """Single-step decode against a paged KV pool (ops.paged layout).
+    """Single-step decode against one layer of a paged KV pool (ops.paged
+    layout).
 
-    q [N, Hq, D]; k_pool/v_pool [P, Hkv, page, D]; table [N, MaxP] block
-    table (OOB entries == P); lengths [N] → out [N, Hq, D].
+    q [N, Hq, D]; k_pool/v_pool [L, P, Hkv, page, D] — the WHOLE planes,
+    read at ``layer`` without slicing it out; table [N, MaxP] block table
+    (OOB entries == P); lengths [N] → out [N, Hq, D].
 
     'pallas' streams pages straight out of the pool through scalar-prefetched
     block tables (ops.pallas.paged_decode); 'xla' materializes each slot's
     logical view with one gather (ops.paged.gather_kv) and reuses the dense
     decode path — correct everywhere, but pays an extra HBM round trip.
     """
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     if resolve_backend(backend, op="paged_decode") == "pallas":
         if page % 8 == 0:
             from gofr_tpu.ops.pallas import interpret_mode
             from gofr_tpu.ops.pallas.paged_decode import paged_decode_attention as pallas_paged
 
             return pallas_paged(
-                q, k_pool, v_pool, table, lengths, scale=scale, interpret=interpret_mode()
+                q, k_pool, v_pool, layer, table, lengths,
+                scale=scale, interpret=interpret_mode(),
             )
         if backend == "pallas":
             # Only 'auto' may degrade silently — an explicit request the
@@ -508,5 +517,5 @@ def _paged_decode_attention_local(
             )
     from gofr_tpu.ops.paged import gather_kv
 
-    k_view, v_view = gather_kv(k_pool, v_pool, table)
+    k_view, v_view = gather_kv(k_pool, v_pool, layer, table)
     return decode_attention(q, k_view, v_view, lengths, scale=scale, backend="xla")

@@ -33,8 +33,7 @@ Pallas interpreter (interpreter timings say nothing about hardware) and
 under lockstep (engine-side gate: a leader-only pin would desynchronize
 follower traces).
 
-Caveat shared with ``GOFR_PAGED_KV_WRITE``: jit caches traces
-process-globally, so the first engine to trace a given program signature
+Caveat: jit caches traces process-globally, so the first engine to trace a given program signature
 fixes that signature's backend for the life of the process — A/B across
 processes, not by re-tuning in one.
 """
@@ -54,7 +53,7 @@ BACKENDS = ("pallas", "xla")
 
 # {op: backend} pinned for the traces inside a decision_scope — consulted
 # by ops.attention.resolve_backend for backend="auto". Same engine-pins-
-# for-its-traces pattern as paged.write_mode_scope / pallas.platform_hint.
+# for-its-traces pattern as paged.kv_shard_scope / pallas.platform_hint.
 _PINS: contextvars.ContextVar[dict[str, str] | None] = contextvars.ContextVar(
     "gofr_autotune_pins", default=None
 )

@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import os
 from dataclasses import dataclass
 from functools import partial
 
@@ -40,47 +39,13 @@ from gofr_tpu.ops.kvcache import quantize_row
 from gofr_tpu.ops.quant import pack_int4, quantize_row_int4, unpack_int4
 from gofr_tpu.tracing import scoped
 
-# The append-lowering choice (select | scatter | pallas). Engines resolve
-# GOFR_PAGED_KV_WRITE ONCE at construction and pin it here for every trace
-# they drive (engine._trace_scope); the env var is only read as a fallback
-# for direct ops callers (unit tests, notebooks). jit caches traces
-# process-globally, so A/B the lowerings across processes, not by flipping
-# the env between engine builds in one process.
-_WRITE_MODE: contextvars.ContextVar[str | None] = contextvars.ContextVar(
-    "gofr_paged_kv_write", default=None
-)
-
-
-def resolve_write_mode(explicit: str | None = None) -> str:
-    """The lowering to trace with: explicit arg > engine pin > env."""
-    if explicit:
-        return explicit
-    pinned = _WRITE_MODE.get()
-    if pinned is not None:
-        return pinned
-    return os.environ.get("GOFR_PAGED_KV_WRITE", "select")
-
-
-@contextlib.contextmanager
-def write_mode_scope(mode: str | None):
-    """Pin the paged-append lowering for traces inside the scope — the
-    engine wraps its device loop / warmup / follower loop with this so the
-    choice it resolved at construction is what every trace sees."""
-    tok = _WRITE_MODE.set(mode)
-    try:
-        yield
-    finally:
-        _WRITE_MODE.reset(tok)
-
-
 # -- tensor-parallel pool sharding ------------------------------------------
 #
 # The pool planes shard over the mesh's tp axis along the KV-head dimension
 # (axis 2 of [L, P, Hkv, page, D]; axis 2 of the [L, P, Hkv, page] scale
 # planes too). Block tables stay replicated — page ids are logical, not
 # per-shard — and the decode attention ops run per-shard under shard_map
-# when an engine pins a KVShardCtx for its traces (engine._trace_scope),
-# mirroring the write-mode pin above.
+# when an engine pins a KVShardCtx for its traces (engine._trace_scope).
 
 
 @dataclass(frozen=True)
@@ -320,66 +285,218 @@ def kv_plane_bytes_per_position(layers: int, kv_heads: int, head_dim: int,
     return layers * kv_heads * per
 
 
+# -- in-place writes --------------------------------------------------------
+#
+# Every paged program carries the WHOLE pool planes [L, P, Hkv, page, ...]
+# through its layer scan (models/llama._scan_paged_layers) and writes a
+# layer's K/V straight into them with one scatter: a decode token (and any
+# run shorter than a page) as [D] rows indexed by (layer, page, head,
+# offset); a prompt or prompt chunk as whole [Hkv, page, D] blocks indexed
+# by (layer, page). XLA updates a carried buffer in place, so a step moves
+# O(tokens) bytes, not O(pool) — the pool is never restacked, sliced per
+# layer, or rebuilt through a mask. Rows and pages whose table entry is P
+# (idle lanes, padding rows, unallocated pages) write nothing
+# (``mode="drop"``).
+
+
+def _put_rows(plane: jnp.ndarray, layer, pp: jnp.ndarray, off: jnp.ndarray,
+              new: jnp.ndarray) -> jnp.ndarray:
+    """``plane[layer, pp[i], h, off[i]] = new[i, h]`` for every index ``i``
+    of ``pp`` / ``off`` (any shape) and head ``h``; ``new`` is
+    [*pp.shape, Hkv(, D)]. The head is an INDEX of the scatter, not part of
+    its window: with a [Hkv, D] window the v5e compiler re-lays the whole
+    pool out as [L, P, page, Hkv, D] inside the program and copies it in
+    and out at both ends (PERF.md §6, PR 27)."""
+    heads = jnp.arange(plane.shape[2])
+    return plane.at[layer, pp[..., None], heads, off[..., None]].set(
+        new.astype(plane.dtype), mode="drop")
+
+
+def _locate_chunk(pages, s: int, offsets, page: int):
+    """_locate for a chunk of ``s`` positions starting at ``offsets`` [B]
+    (None = 0) → (pp, off), [B, S] each."""
+    pos = jnp.arange(s)[None, :] + (offsets[:, None] if offsets is not None else 0)
+    return _locate(pages, pos, page)
+
+
+def _locate_append(table, positions, page: int, pool: int):
+    """_locate for one position per lane → (pp, off), [N] each. A position
+    past the table's span is dropped (page id P), never clamped onto the
+    lane's last page."""
+    pp, off = _locate(table, positions[:, None], page)
+    past = positions // page >= table.shape[1]
+    return jnp.where(past, pool, pp[:, 0]), off[:, 0]
+
+
+def _put_pages(plane: jnp.ndarray, layer, pages: jnp.ndarray, new: jnp.ndarray,
+               offsets) -> jnp.ndarray:
+    """Write ``new`` [B, S, Hkv(, D)] at positions offsets..offsets+S of each
+    row as WHOLE PAGES: one scatter whose index is (layer, page) and whose
+    window is a [Hkv, page(, D)] block. A page-aligned whole prompt
+    (``offsets`` None, S a multiple of the page) is laid out as S/page
+    blocks and written; any other run reads the S/page + 1 pages it can
+    touch, patches its rows in, and writes them back. Logical pages past
+    the table's span, and entries == P, write nothing."""
+    pool, page = plane.shape[1], plane.shape[3]
+    b, s = new.shape[:2]
+    tail = new.shape[2:]
+    maxp = pages.shape[1]
+    new = new.astype(plane.dtype)
+
+    def blocks(x, j):  # [B, j*page, *tail] -> [B, j, Hkv, page(, D)]
+        return jnp.moveaxis(x.reshape(b, j, page, *x.shape[2:]), 2, 3)
+
+    if offsets is None and s % page == 0 and s // page <= maxp:
+        return plane.at[layer, pages[:, : s // page]].set(blocks(new, s // page), mode="drop")
+    if offsets is None:
+        offsets = jnp.zeros((b,), jnp.int32)
+    j = -(-s // page) + 1
+    lp = (offsets // page)[:, None] + jnp.arange(j)  # [B, j] logical pages of the run
+    pg = jnp.where(lp < maxp,
+                   jnp.take_along_axis(pages, jnp.minimum(lp, maxp - 1), axis=1), pool)
+    old = plane[layer, jnp.minimum(pg, pool - 1)]  # [B, j, Hkv, page(, D)]
+    src = jnp.arange(j * page)[None, :] - (offsets % page)[:, None]  # row of ``new`` per slot
+    hit = ((src >= 0) & (src < s)).reshape((b, j * page) + (1,) * len(tail))
+    src = jnp.clip(src, 0, s - 1).reshape(hit.shape)
+    rows = jnp.take_along_axis(new, src, axis=1)
+    return plane.at[layer, pg].set(jnp.where(blocks(hit, j), blocks(rows, j), old), mode="drop")
+
+
+def _put_run(plane: jnp.ndarray, layer, pages: jnp.ndarray, new: jnp.ndarray,
+             offsets) -> jnp.ndarray:
+    """A run of S consecutive positions per row, by its length (static):
+    shorter than a page (speculative verify) as rows, like an append; a page
+    or more (prefill, chunked prefill) as whole pages. Thousands of row
+    updates in one scatter are what makes the v5e compiler re-lay the pool
+    out inside the program at head_dim 64 — a second pool, and a minute of
+    compile time a program (PERF.md §6, PR 27)."""
+    page = plane.shape[3]
+    if new.shape[1] >= page:
+        return _put_pages(plane, layer, pages, new, offsets)
+    pp, off = _locate_chunk(pages, new.shape[1], offsets, page)
+    return _put_rows(plane, layer, pp, off, new)
+
+
+def _quantize_packed_int4(new):
+    """quantize_row's contract for the packed pool: ([..., Hkv, D//2] uint8
+    nibble pairs, [..., Hkv] scales)."""
+    q, sc = quantize_row_int4(new)
+    return pack_int4(q), sc
+
+
+def _put_rows_q(pool_q, pool_s, layer, pp, off, new, quantize):
+    q, sc = quantize(new)  # [..., Hkv, D or D//2] stored bytes, [..., Hkv] scales
+    return _put_rows(pool_q, layer, pp, off, q), _put_rows(pool_s, layer, pp, off, sc)
+
+
+@scoped("kv_append")
+def write_prompts_paged(
+    k_pool: jnp.ndarray,   # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,
+    layer,                 # scalar layer index (traced in the layer scan)
+    pages: jnp.ndarray,    # [B, S_pages] physical page per logical page (P = dropped)
+    k_new: jnp.ndarray,    # [B, S, Hkv, D] activation layout
+    v_new: jnp.ndarray,
+    offsets: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Write prefilled prompts (or prompt CHUNKS) of one layer through
+    per-row block tables. ``pages[b, j]`` is the physical page holding
+    positions j*page .. (j+1)*page of row b; ``offsets`` [B] places the
+    chunk at logical positions offsets..offsets+S (None = 0)."""
+    return (_put_run(k_pool, layer, pages, k_new, offsets),
+            _put_run(v_pool, layer, pages, v_new, offsets))
+
+
 @scoped("kv_append")
 def write_prompts_paged_q(
-    cache_q: jnp.ndarray,  # int8 [P, Hkv, page, D] (one of k/v)
-    cache_s: jnp.ndarray,  # [P, Hkv, page]
+    pool_q: jnp.ndarray,   # int8 [L, P, Hkv, page, D] (one of k/v)
+    pool_s: jnp.ndarray,   # [L, P, Hkv, page]
+    layer,
     pages: jnp.ndarray,    # [B, S_pages]
     new: jnp.ndarray,      # [B, S, Hkv, D]
     offsets: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Quantized analog of write_prompts_paged for one k/v plane, with
-    chunk offsets (logical positions offsets..offsets+S)."""
-    b, s, hkv, _ = new.shape
-    page = cache_q.shape[2]
-    q, sc = quantize_row(new)  # [B,S,Hkv,D] int8, [B,S,Hkv]
-    pos = jnp.arange(s)[None, :] + (offsets[:, None] if offsets is not None else 0)
-    pp, off = _locate(pages, pos, page)  # [B,S] each
-    rows = pp[:, :, None]
-    heads = jnp.arange(hkv)[None, None, :]
-    offs = off[:, :, None]
-    cache_q = cache_q.at[rows, heads, offs].set(q)
-    cache_s = cache_s.at[rows, heads, offs].set(sc.astype(cache_s.dtype))
-    return cache_q, cache_s
+    """Quantized analog of write_prompts_paged for one k/v plane pair."""
+    q, sc = quantize_row(new)
+    return _put_run(pool_q, layer, pages, q, offsets), _put_run(pool_s, layer, pages, sc, offsets)
+
+
+@scoped("kv_append")
+def write_prompts_paged_q4(
+    pool_q: jnp.ndarray,   # uint8 [L, P, Hkv, page, D//2] packed (one of k/v)
+    pool_s: jnp.ndarray,   # [L, P, Hkv, page]
+    layer,
+    pages: jnp.ndarray,    # [B, S_pages]
+    new: jnp.ndarray,      # [B, S, Hkv, D]
+    offsets: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """int4 analog of write_prompts_paged_q: quantize to nibbles, pack
+    two-per-byte, write bytes through the block table."""
+    q, sc = _quantize_packed_int4(new)
+    return _put_run(pool_q, layer, pages, q, offsets), _put_run(pool_s, layer, pages, sc, offsets)
+
+
+@scoped("kv_append")
+def append_tokens_paged(
+    k_pool: jnp.ndarray,    # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,
+    layer,
+    table: jnp.ndarray,     # [N, MaxP] block table for every slot
+    positions: jnp.ndarray, # [N] logical write position per slot
+    k_new: jnp.ndarray,     # [N, Hkv, D]
+    v_new: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Append one token's K/V per slot at its current logical position of
+    one layer: row ``n`` lands at ``(layer, table[n, pos // page], :,
+    pos % page)``; idle lanes (table entry P) and positions past the
+    table's span write nothing."""
+    pp, off = _locate_append(table, positions, k_pool.shape[3], k_pool.shape[1])
+    return _put_rows(k_pool, layer, pp, off, k_new), _put_rows(v_pool, layer, pp, off, v_new)
 
 
 @scoped("kv_append")
 def append_tokens_paged_q(
-    cache_q: jnp.ndarray,   # int8 [P, Hkv, page, D]
-    cache_s: jnp.ndarray,   # [P, Hkv, page]
+    pool_q: jnp.ndarray,    # int8 [L, P, Hkv, page, D]
+    pool_s: jnp.ndarray,    # [L, P, Hkv, page]
+    layer,
     table: jnp.ndarray,     # [N, MaxP]
     positions: jnp.ndarray, # [N]
     new: jnp.ndarray,       # [N, Hkv, D]
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Quantized analog of append_tokens_paged for one k/v plane, honoring
-    the same write-mode lowering switch (select default — the measured
-    v5e winner; scatter optional). The one-hot fold runs in f32 and casts
-    back: int8 magnitudes <= 127 are exact in f32."""
-    n, hkv, d = new.shape
-    p_total, _, page, _ = cache_q.shape
-    q, sc = quantize_row(new)  # [N,Hkv,D] int8, [N,Hkv] f32
-    pp, off = _locate(table, positions[:, None], page)
-    pp, off = pp[:, 0], off[:, 0]
+    """Quantized analog of append_tokens_paged for one k/v plane pair."""
+    pp, off = _locate_append(table, positions, pool_q.shape[3], pool_q.shape[1])
+    return _put_rows_q(pool_q, pool_s, layer, pp, off, new, quantize_row)
 
-    if resolve_write_mode() != "scatter":
-        flat = pp * page + off  # OOB rows land >= p_total*page
-        grid = jnp.arange(p_total * page)
-        m = flat[:, None] == grid[None, :]  # [N, P*page]
-        any_m = m.reshape(n, p_total, page).any(axis=0)
-        mf = m.astype(jnp.float32)
-        upd = jnp.einsum("np,nhd->phd", mf, q.astype(jnp.float32))
-        upd = upd.reshape(p_total, page, hkv, d).transpose(0, 2, 1, 3)
-        cache_q = jnp.where(any_m[:, None, :, None], upd.astype(jnp.int8), cache_q)
-        upd_s = jnp.einsum("np,nh->ph", mf, sc).reshape(p_total, page, hkv)
-        cache_s = jnp.where(any_m[:, None, :],
-                            upd_s.transpose(0, 2, 1).astype(cache_s.dtype), cache_s)
-        return cache_q, cache_s
 
-    rows = pp[:, None]
-    heads = jnp.arange(hkv)[None, :]
-    cache_q = cache_q.at[rows, heads, off[:, None]].set(q)
-    cache_s = cache_s.at[rows, heads, off[:, None]].set(sc.astype(cache_s.dtype))
-    return cache_q, cache_s
+@scoped("kv_append")
+def append_tokens_paged_q4(
+    pool_q: jnp.ndarray,    # uint8 [L, P, Hkv, page, D//2] packed
+    pool_s: jnp.ndarray,    # [L, P, Hkv, page]
+    layer,
+    table: jnp.ndarray,     # [N, MaxP]
+    positions: jnp.ndarray, # [N]
+    new: jnp.ndarray,       # [N, Hkv, D]
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """int4 analog of append_tokens_paged_q (packed bytes round-trip
+    losslessly: the scatter stores them as they are)."""
+    pp, off = _locate_append(table, positions, pool_q.shape[3], pool_q.shape[1])
+    return _put_rows_q(pool_q, pool_s, layer, pp, off, new, _quantize_packed_int4)
+
+
+# -- reads ---------------------------------------------------------------------
+#
+# The XLA read path materializes each slot's logical view with ONE gather
+# whose index is (layer, page): the pages come straight out of the carried
+# pool, and no [P, Hkv, page, D] copy of the layer is ever made.
+
+
+def _gather_pages(plane: jnp.ndarray, layer, table: jnp.ndarray) -> jnp.ndarray:
+    """[N, Hkv, MaxP*page(, D)] logical view of one layer of a plane. OOB
+    table entries clamp — callers mask by lengths."""
+    n, maxp = table.shape
+    hkv, page = plane.shape[2], plane.shape[3]
+    g = plane[layer, jnp.minimum(table, plane.shape[1] - 1)]  # [N, MaxP, Hkv, page(, D)]
+    return jnp.moveaxis(g, 2, 1).reshape(n, hkv, maxp * page, *plane.shape[4:])
 
 
 def _corrupt_scales(gs: jnp.ndarray) -> jnp.ndarray:
@@ -400,190 +517,45 @@ def _corrupt_scales(gs: jnp.ndarray) -> jnp.ndarray:
 
 
 @scoped("kv_gather")
+def gather_kv(
+    k_pool: jnp.ndarray,   # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,
+    layer,
+    table: jnp.ndarray,    # [N, MaxP]
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Materialize the logical [N, Hkv, MaxP*page, D] view of each slot's
+    cache at one layer (XLA fallback read path; the Pallas paged-decode
+    kernel reads the pool directly instead). OOB table entries clamp —
+    callers must mask by lengths, which the attention ops already do."""
+    return _gather_pages(k_pool, layer, table), _gather_pages(v_pool, layer, table)
+
+
+@scoped("kv_gather")
 def gather_kv_q(
-    cache_q: jnp.ndarray,  # int8 [P, Hkv, page, D]
-    cache_s: jnp.ndarray,  # [P, Hkv, page]
+    pool_q: jnp.ndarray,   # int8 [L, P, Hkv, page, D]
+    pool_s: jnp.ndarray,   # [L, P, Hkv, page]
+    layer,
     table: jnp.ndarray,    # [N, MaxP]
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Logical ([N, Hkv, MaxP*page, D] int8, [N, Hkv, MaxP*page] scale)
     views of each slot's quantized cache (the XLA read path)."""
-    n, maxp = table.shape
-    _, hkv, page, d = cache_q.shape
-    safe = jnp.minimum(table, cache_q.shape[0] - 1)
-
-    gq = cache_q[safe].transpose(0, 2, 1, 3, 4).reshape(n, hkv, maxp * page, d)
-    gs = cache_s[safe].transpose(0, 2, 1, 3).reshape(n, hkv, maxp * page)
-    return gq, _corrupt_scales(gs)
-
-
-@scoped("kv_append")
-def write_prompts_paged_q4(
-    cache_q: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed (one of k/v)
-    cache_s: jnp.ndarray,  # [P, Hkv, page]
-    pages: jnp.ndarray,    # [B, S_pages]
-    new: jnp.ndarray,      # [B, S, Hkv, D]
-    offsets: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """int4 analog of write_prompts_paged_q for one k/v plane: quantize to
-    nibbles, pack two-per-byte, write bytes through the block table."""
-    b, s, hkv, _ = new.shape
-    page = cache_q.shape[2]
-    q, sc = quantize_row_int4(new)  # [B,S,Hkv,D] int8, [B,S,Hkv]
-    packed = pack_int4(q)           # [B,S,Hkv,D//2] uint8
-    pos = jnp.arange(s)[None, :] + (offsets[:, None] if offsets is not None else 0)
-    pp, off = _locate(pages, pos, page)  # [B,S] each
-    rows = pp[:, :, None]
-    heads = jnp.arange(hkv)[None, None, :]
-    offs = off[:, :, None]
-    cache_q = cache_q.at[rows, heads, offs].set(packed)
-    cache_s = cache_s.at[rows, heads, offs].set(sc.astype(cache_s.dtype))
-    return cache_q, cache_s
-
-
-@scoped("kv_append")
-def append_tokens_paged_q4(
-    cache_q: jnp.ndarray,   # uint8 [P, Hkv, page, D//2] packed
-    cache_s: jnp.ndarray,   # [P, Hkv, page]
-    table: jnp.ndarray,     # [N, MaxP]
-    positions: jnp.ndarray, # [N]
-    new: jnp.ndarray,       # [N, Hkv, D]
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """int4 analog of append_tokens_paged_q for one k/v plane, honoring the
-    same write-mode lowering switch. The one-hot fold runs in f32 over the
-    PACKED bytes and casts back — uint8 magnitudes <= 255 are exact in
-    f32, so the byte round-trips losslessly."""
-    n, hkv, d2 = new.shape[0], new.shape[1], cache_q.shape[3]
-    p_total, _, page, _ = cache_q.shape
-    q, sc = quantize_row_int4(new)  # [N,Hkv,D] int8, [N,Hkv] f32
-    packed = pack_int4(q)           # [N,Hkv,D//2] uint8
-    pp, off = _locate(table, positions[:, None], page)
-    pp, off = pp[:, 0], off[:, 0]
-
-    if resolve_write_mode() != "scatter":
-        flat = pp * page + off  # OOB rows land >= p_total*page
-        grid = jnp.arange(p_total * page)
-        m = flat[:, None] == grid[None, :]  # [N, P*page]
-        any_m = m.reshape(n, p_total, page).any(axis=0)
-        mf = m.astype(jnp.float32)
-        upd = jnp.einsum("np,nhd->phd", mf, packed.astype(jnp.float32))
-        upd = upd.reshape(p_total, page, hkv, d2).transpose(0, 2, 1, 3)
-        cache_q = jnp.where(any_m[:, None, :, None], upd.astype(jnp.uint8), cache_q)
-        upd_s = jnp.einsum("np,nh->ph", mf, sc).reshape(p_total, page, hkv)
-        cache_s = jnp.where(any_m[:, None, :],
-                            upd_s.transpose(0, 2, 1).astype(cache_s.dtype), cache_s)
-        return cache_q, cache_s
-
-    rows = pp[:, None]
-    heads = jnp.arange(hkv)[None, :]
-    cache_q = cache_q.at[rows, heads, off[:, None]].set(packed)
-    cache_s = cache_s.at[rows, heads, off[:, None]].set(sc.astype(cache_s.dtype))
-    return cache_q, cache_s
+    return (_gather_pages(pool_q, layer, table),
+            _corrupt_scales(_gather_pages(pool_s, layer, table)))
 
 
 @scoped("kv_gather")
 def gather_kv_q4(
-    cache_q: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed
-    cache_s: jnp.ndarray,  # [P, Hkv, page]
+    pool_q: jnp.ndarray,   # uint8 [L, P, Hkv, page, D//2] packed
+    pool_s: jnp.ndarray,   # [L, P, Hkv, page]
+    layer,
     table: jnp.ndarray,    # [N, MaxP]
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Logical ([N, Hkv, MaxP*page, D] int8 in [-8, 7], [N, Hkv, MaxP*page]
     scale) views of each slot's packed cache — the XLA read path unpacks
     AFTER the gather so HBM reads stay packed; the unpacked view feeds the
     same ``decode_attention_q`` contraction the int8 layout uses."""
-    n, maxp = table.shape
-    _, hkv, page, d2 = cache_q.shape
-    safe = jnp.minimum(table, cache_q.shape[0] - 1)
-
-    gq = cache_q[safe].transpose(0, 2, 1, 3, 4).reshape(n, hkv, maxp * page, d2)
-    gs = cache_s[safe].transpose(0, 2, 1, 3).reshape(n, hkv, maxp * page)
-    return unpack_int4(gq), _corrupt_scales(gs)
-
-
-@scoped("kv_append")
-def write_prompts_paged(
-    k_layer: jnp.ndarray,  # [P, Hkv, page, D]
-    v_layer: jnp.ndarray,
-    pages: jnp.ndarray,    # [B, S_pages] physical page per logical page (P = dropped)
-    k_new: jnp.ndarray,    # [B, S, Hkv, D] activation layout
-    v_new: jnp.ndarray,
-    offsets: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Write prefilled prompts (or prompt CHUNKS) through per-row block
-    tables. ``pages[b, j]`` is the physical page holding positions
-    j*page .. (j+1)*page of row b; ``offsets`` [B] places the chunk at
-    logical positions offsets..offsets+S (None = 0)."""
-    b, s, hkv, _ = k_new.shape
-    page = k_layer.shape[2]
-    pos = jnp.arange(s)[None, :] + (offsets[:, None] if offsets is not None else 0)
-    pp, off = _locate(pages, pos, page)  # [B,S] each
-    rows = pp[:, :, None]
-    heads = jnp.arange(hkv)[None, None, :]
-    offs = off[:, :, None]
-    k_layer = k_layer.at[rows, heads, offs].set(k_new.astype(k_layer.dtype))
-    v_layer = v_layer.at[rows, heads, offs].set(v_new.astype(v_layer.dtype))
-    return k_layer, v_layer
-
-
-@scoped("kv_append")
-def append_tokens_paged(
-    k_layer: jnp.ndarray,   # [P, Hkv, page, D]
-    v_layer: jnp.ndarray,
-    table: jnp.ndarray,     # [N, MaxP] block table for every slot
-    positions: jnp.ndarray, # [N] logical write position per slot
-    k_new: jnp.ndarray,     # [N, Hkv, D]
-    v_new: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Append one token's K/V per slot at its current logical position.
-
-    Two lowerings, chosen by ``GOFR_PAGED_KV_WRITE`` (default ``select``;
-    anything else means ``scatter``): ``select`` rebuilds the pool through
-    a one-hot einsum + masked select — the same trick that beat XLA's
-    scatter ~1.4-2x for the slot cache on v5e (ops/kvcache.append_tokens) —
-    while ``scatter`` keeps the advanced-indexing scatter (cheaper
-    asymptotically for very large pools, where the one-hot matmul and
-    full-pool rewrite start to dominate). The choice comes from
-    ``resolve_write_mode()``: engines resolve ``GOFR_PAGED_KV_WRITE``
-    once at construction and pin it for their traces (``write_mode_scope``);
-    the env var is only the fallback for direct callers. jit caches traces
-    process-globally, so the choice is effectively FIXED FOR THE LIFE OF
-    THE PROCESS — A/B the two lowerings across separate processes, not by
-    flipping the var between engine builds. OOB semantics are
-    preserved either way: OOB rows' flat position falls outside the one-hot
-    range, producing an all-false mask row (the scatter path relies on XLA
-    dropping OOB updates)."""
-    n, hkv, d = k_new.shape
-    p_total, _, page, _ = k_layer.shape
-
-    mode = resolve_write_mode()
-    if mode == "pallas":
-        from gofr_tpu.ops.pallas import interpret_mode, require_kernel_platform
-        from gofr_tpu.ops.pallas.kv_append import append_tokens_paged_inplace
-
-        require_kernel_platform("GOFR_PAGED_KV_WRITE=pallas")
-        return append_tokens_paged_inplace(
-            k_layer, v_layer, table, positions, k_new, v_new,
-            interpret=interpret_mode(),
-        )
-
-    pp, off = _locate(table, positions[:, None], page)
-    pp, off = pp[:, 0], off[:, 0]  # [N]
-
-    if mode != "scatter":
-        flat = pp * page + off  # [N]; OOB rows land >= p_total*page
-        grid = jnp.arange(p_total * page)
-        m = flat[:, None] == grid[None, :]  # [N, P*page]
-        any_m = m.reshape(n, p_total, page).any(axis=0)[:, None, :, None]
-        def fold(new, layer):
-            upd = jnp.einsum("np,nhd->phd", m.astype(layer.dtype), new.astype(layer.dtype))
-            upd = upd.reshape(p_total, page, hkv, d).transpose(0, 2, 1, 3)
-            return jnp.where(any_m, upd, layer)
-        return fold(k_new, k_layer), fold(v_new, v_layer)
-
-    rows = pp[:, None]
-    heads = jnp.arange(hkv)[None, :]
-    k_layer = k_layer.at[rows, heads, off[:, None]].set(k_new.astype(k_layer.dtype))
-    v_layer = v_layer.at[rows, heads, off[:, None]].set(v_new.astype(v_layer.dtype))
-    return k_layer, v_layer
+    return (unpack_int4(_gather_pages(pool_q, layer, table)),
+            _corrupt_scales(_gather_pages(pool_s, layer, table)))
 
 
 # -- hierarchical prefix cache: per-page host spill / swap-in -------------------
@@ -623,23 +595,3 @@ def swap_in_pages(cache, page_ids, payload):
         lambda a, p: a.at[:, page_ids].set(p.astype(a.dtype)), cache, payload
     )
     return new, jnp.sum(page_ids)
-
-
-@scoped("kv_gather")
-def gather_kv(
-    k_layer: jnp.ndarray,  # [P, Hkv, page, D]
-    v_layer: jnp.ndarray,
-    table: jnp.ndarray,    # [N, MaxP]
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Materialize the logical [N, Hkv, MaxP*page, D] view of each slot's
-    cache (XLA fallback read path; the Pallas paged-decode kernel reads the
-    pool directly instead). OOB table entries clamp — callers must mask by
-    lengths, which the attention ops already do."""
-    n, maxp = table.shape
-    _, hkv, page, d = k_layer.shape
-
-    def view(layer):
-        g = layer[jnp.minimum(table, layer.shape[0] - 1)]  # [N, MaxP, Hkv, page, D]
-        return g.transpose(0, 2, 1, 3, 4).reshape(n, hkv, maxp * page, d)
-
-    return view(k_layer), view(v_layer)

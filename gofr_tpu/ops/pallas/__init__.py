@@ -58,9 +58,8 @@ def kernel_platform() -> bool:
 
 def require_kernel_platform(what: str) -> None:
     """Raise unless the Pallas kernels can lower here. Call sites that were
-    asked for a kernel BY NAME (``backend='pallas'``, ``GOFR_KV_WRITE=pallas``,
-    ``GOFR_PAGED_KV_WRITE=pallas``) use this so the request is never
-    quietly served by a different lowering."""
+    asked for a kernel BY NAME (``backend='pallas'``, ``GOFR_KV_WRITE=pallas``)
+    use this so the request is never quietly served by a different lowering."""
     if not kernel_platform():
         platform = _PLATFORM.get() or jax.default_backend()
         raise RuntimeError(

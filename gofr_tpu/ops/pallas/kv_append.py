@@ -17,10 +17,12 @@ axis long-context decode is bound by.
 Out-of-bounds convention (engine padding/bubble rows): positions >= Smax
 clamp to the last tile in the index_map and the select mask is empty, so
 the tile is copied through unchanged — the same dropped-write semantics as
-the XLA paths (ops/kvcache.append_tokens, ops/paged.append_tokens_paged).
+the XLA path (ops/kvcache.append_tokens).
 
-The paged variant routes the tile pick through the slot's block table
-(physical page = table[n, pos // page]), writing straight into the pool.
+Slot layout only. The paged pool is written by XLA's scatter into the
+buffer the layer scan carries (ops/paged.py): at the served shapes it
+matched a paged twin of this kernel to 0.03% of a decode chunk on the v5e
+(PERF.md §6, PR 27), for all three pool kinds and with no reserved page.
 """
 
 from __future__ import annotations
@@ -109,77 +111,3 @@ def append_tokens_inplace(
         ),
         interpret=interpret,
     )(pos, k_new[:, :, None, :], v_new[:, :, None, :], k_layer, v_layer)
-
-
-def append_tokens_paged_inplace(
-    k_pool: jnp.ndarray,    # [P, Hkv, page, D]
-    v_pool: jnp.ndarray,
-    table: jnp.ndarray,     # [N, MaxP] (OOB entries == P)
-    positions: jnp.ndarray, # [N]
-    k_new: jnp.ndarray,     # [N, Hkv, D]
-    v_new: jnp.ndarray,
-    *,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Paged-pool append writing only the page holding each slot's row.
-
-    OOB rows (table entry == P) redirect their tile fetch to page 0 and
-    patch nothing. Page 0 is RESERVED as a never-allocated sink by
-    the engine whenever this lowering is enabled (GOFR_PAGED_KV_WRITE=
-    pallas), so an OOB copy-through can never revisit a tile that a real
-    row writes in the same call — under Mosaic's double-buffered block
-    pipelining such a revisit could write back a stale copy over the real
-    row (ADVICE r4). Positions beyond the table span clamp to the lane's
-    OWN last page (each lane appears in the grid once, so no cross-step
-    tile sharing there either)."""
-    n, hkv, d = k_new.shape
-    pool, _, page, _ = k_pool.shape
-    _, maxp = table.shape
-    pos = positions.astype(jnp.int32)
-    tbl = table.astype(jnp.int32)
-
-    def pool_map(bi, pos_ref, table_ref):
-        logical = jnp.minimum(pos_ref[bi] // page, maxp - 1)
-        entry = table_ref[bi, logical]
-        # OOB sentinel (== pool) -> the reserved sink page 0, never a
-        # clamp onto a page another grid step may write
-        return (jnp.where(entry < pool, entry, 0), 0, 0, 0)
-
-    def _kernel(pos_ref, table_ref, knew_ref, vnew_ref, k_ref, v_ref, ko_ref, vo_ref):
-        i = pl.program_id(0)
-        p = pos_ref[i]
-        logical = p // page
-        # OOB pages (table entry == pool size) must drop the write
-        entry = table_ref[i, jnp.minimum(logical, maxp - 1)]
-        valid = (logical < maxp) & (p >= 0) & (entry < pool)
-        off = jnp.where(valid, p % page, -1)
-        _patch_tile(off, knew_ref, k_ref, ko_ref)
-        _patch_tile(off, vnew_ref, v_ref, vo_ref)
-
-    return pl.pallas_call(
-        _kernel,
-        name="kv_append",  # tracing.SCOPES: the kernel is named for its phase
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n,),
-            in_specs=[
-                pl.BlockSpec((1, hkv, 1, d), lambda bi, p, t: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, hkv, 1, d), lambda bi, p, t: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, hkv, page, d), pool_map),
-                pl.BlockSpec((1, hkv, page, d), pool_map),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, hkv, page, d), pool_map),
-                pl.BlockSpec((1, hkv, page, d), pool_map),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-        ],
-        input_output_aliases={4: 0, 5: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(pos, tbl, k_new[:, :, None, :], v_new[:, :, None, :], k_pool, v_pool)

@@ -1,11 +1,12 @@
 """Decode attention over the PAGED KV cache as a Pallas kernel.
 
 Same HBM-bound hot loop as decode_attention.py, but K/V tiles come out of
-the physical page pool [P, Hkv, page, D] through each slot's block table
-instead of a contiguous [Smax] row. The table and per-slot lengths ride in
-as SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the BlockSpec
-index_map can resolve ``grid step (slot, head, logical_page) -> physical
-page`` BEFORE the DMA is issued — the kernel streams exactly the pages a
+the physical page pool [L, P, Hkv, page, D] through each slot's block table
+instead of a contiguous [Smax] row. The table, the per-slot lengths and the
+layer index ride in as SCALAR-PREFETCH operands
+(pltpu.PrefetchScalarGridSpec), so the BlockSpec index_map can resolve
+``grid step (slot, head, logical_page) -> (layer, physical page)`` BEFORE
+the DMA is issued — the kernel streams exactly the pages a
 slot owns, never a gather-materialized copy of the logical view (that copy
 is the XLA fallback, ops.paged.gather_kv).
 
@@ -51,12 +52,18 @@ from gofr_tpu.ops.pallas.common import (
 )
 
 
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the [1] int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _paged_decode_kernel(
     ln_ref,    # SMEM [N] per-slot live length (scalar prefetch)
     table_ref, # SMEM [N, MaxP] block table (scalar prefetch)
+    layer_ref, # SMEM [1] layer index (scalar prefetch; index_maps only)
     q_ref,     # VMEM [1, 1, G, d]
-    k_ref,     # VMEM [1, 1, page, d] — the physical page picked by index_map
-    v_ref,     # VMEM [1, 1, page, d]
+    k_ref,     # VMEM [1, 1, 1, page, d] — the (layer, page) picked by index_map
+    v_ref,     # VMEM [1, 1, 1, page, d]
     o_ref,     # VMEM [1, 1, G, d]
     acc_ref,   # scratch f32 [G, d]
     m_ref,     # scratch f32 [G, 128]
@@ -72,8 +79,8 @@ def _paged_decode_kernel(
     init_softmax_scratch(pi, acc_ref, m_ref, l_ref)
 
     q = q_ref[0, 0]  # [G, d]
-    k = k_ref[0, 0]  # [page, d]
-    v = v_ref[0, 0]
+    k = k_ref[0, 0, 0]  # [page, d]
+    v = v_ref[0, 0, 0]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -93,8 +100,9 @@ def _paged_decode_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,        # [N, Hq, D]
-    k_pool: jnp.ndarray,   # [P, Hkv, page, D]
-    v_pool: jnp.ndarray,   # [P, Hkv, page, D]
+    k_pool: jnp.ndarray,   # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,   # [L, P, Hkv, page, D]
+    layer,                 # scalar layer index
     table: jnp.ndarray,    # [N, MaxP] int32, OOB entries == P
     lengths: jnp.ndarray,  # [N] live length per slot
     *,
@@ -103,7 +111,7 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Single-step decode against the paged pool → [N, Hq, D]."""
     n, hq, d = q.shape
-    pool, hkv, page, _ = k_pool.shape
+    _, pool, hkv, page, _ = k_pool.shape
     _, maxp = table.shape
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
@@ -113,8 +121,8 @@ def paged_decode_attention(
     q4 = q.reshape(n, hkv, group, d)
     safe_table = jnp.minimum(table, pool - 1).astype(jnp.int32)
 
-    def kv_map(bi, hi, pi, ln_ref, table_ref):
-        return (table_ref[bi, pi], hi, 0, 0)
+    def kv_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
+        return (layer_ref[0], table_ref[bi, pi], hi, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, page=page, n_pages=maxp, group=group
@@ -123,14 +131,14 @@ def paged_decode_attention(
         kernel,
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(n, hkv, maxp),
             in_specs=[
-                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page, d), kv_map),
+                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
+                pl.BlockSpec((1, 1, 1, page, d), kv_map),
+                pl.BlockSpec((1, 1, 1, page, d), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, d), jnp.float32),
                 pltpu.VMEM((group, 128), jnp.float32),
@@ -142,18 +150,19 @@ def paged_decode_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), safe_table, q4, k_pool, v_pool)
+    )(lengths.astype(jnp.int32), safe_table, _layer_operand(layer), q4, k_pool, v_pool)
     return out.reshape(n, hq, d)
 
 
 def _paged_decode_q_kernel(
     ln_ref,    # SMEM [N] per-slot live length (scalar prefetch)
     table_ref, # SMEM [N, MaxP] block table (scalar prefetch)
+    layer_ref, # SMEM [1] layer index (scalar prefetch; index_maps only)
     q_ref,     # VMEM [1, 1, G, d]
-    k_ref,     # VMEM int8 [1, 1, page, d] — the physical page from index_map
-    v_ref,     # VMEM int8 [1, 1, page, d]
-    ks_ref,    # VMEM [1, Hkv, page] per-position K scales (same page pick)
-    vs_ref,    # VMEM [1, Hkv, page]
+    k_ref,     # VMEM int8 [1, 1, 1, page, d] — the (layer, page) from index_map
+    v_ref,     # VMEM int8 [1, 1, 1, page, d]
+    ks_ref,    # VMEM [1, 1, Hkv, page] per-position K scales (same page pick)
+    vs_ref,    # VMEM [1, 1, Hkv, page]
     o_ref,     # VMEM [1, 1, G, d]
     acc_ref,   # scratch f32 [G, d]
     m_ref,     # scratch f32 [G, 128]
@@ -170,22 +179,22 @@ def _paged_decode_q_kernel(
     init_softmax_scratch(pi, acc_ref, m_ref, l_ref)
 
     q = q_ref[0, 0]                      # [G, d]
-    k = k_ref[0, 0].astype(q.dtype)      # int8 → compute dtype, in VMEM
+    k = k_ref[0, 0, 0].astype(q.dtype)   # int8 → compute dtype, in VMEM
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, page]
     # K-scale fold: constant along the d reduction, so it multiplies the
     # finished scores per key position (decode_attention_q order: scale
     # before the mask, where a masked position's value is irrelevant).
-    s = s * select_head_row(ks_ref[0], hi)
+    s = s * select_head_row(ks_ref[0, 0], hi)
 
     kv_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
     s = jnp.where(kv_pos < ln_ref[bi], s, NEG_INF)
 
     # V-scale fold happens inside the recurrence (common.py): probabilities
     # pick up vs before the PV matmul, v converts from int8 at the input.
-    softmax_block_update(s, v_ref[0, 0], acc_ref, m_ref, l_ref,
-                         v_scale=select_head_row(vs_ref[0], hi))
+    softmax_block_update(s, v_ref[0, 0, 0], acc_ref, m_ref, l_ref,
+                         v_scale=select_head_row(vs_ref[0, 0], hi))
 
     def write(out):
         o_ref[0, 0] = out.astype(o_ref.dtype)
@@ -196,10 +205,11 @@ def _paged_decode_q_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_q(
     q: jnp.ndarray,        # [N, Hq, D]
-    kq_pool: jnp.ndarray,  # int8 [P, Hkv, page, D]
-    vq_pool: jnp.ndarray,  # int8 [P, Hkv, page, D]
-    ks_pool: jnp.ndarray,  # [P, Hkv, page] per-position K scales
-    vs_pool: jnp.ndarray,  # [P, Hkv, page]
+    kq_pool: jnp.ndarray,  # int8 [L, P, Hkv, page, D]
+    vq_pool: jnp.ndarray,  # int8 [L, P, Hkv, page, D]
+    ks_pool: jnp.ndarray,  # [L, P, Hkv, page] per-position K scales
+    vs_pool: jnp.ndarray,  # [L, P, Hkv, page]
+    layer,                 # scalar layer index
     table: jnp.ndarray,    # [N, MaxP] int32, OOB entries == P
     lengths: jnp.ndarray,  # [N] live length per slot
     *,
@@ -212,7 +222,7 @@ def paged_decode_attention_q(
     gather: int8 pages and their scale rows are block-streamed per
     (slot, head, logical page) and dequantized in-register."""
     n, hq, d = q.shape
-    pool, hkv, page, _ = kq_pool.shape
+    _, pool, hkv, page, _ = kq_pool.shape
     _, maxp = table.shape
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
@@ -222,13 +232,13 @@ def paged_decode_attention_q(
     q4 = q.reshape(n, hkv, group, d)
     safe_table = jnp.minimum(table, pool - 1).astype(jnp.int32)
 
-    def kv_map(bi, hi, pi, ln_ref, table_ref):
-        return (table_ref[bi, pi], hi, 0, 0)
+    def kv_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
+        return (layer_ref[0], table_ref[bi, pi], hi, 0, 0)
 
-    def sc_map(bi, hi, pi, ln_ref, table_ref):
+    def sc_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
         # all Hkv scale rows of the page; the kernel picks row hi
         # (common.select_head_row says why)
-        return (table_ref[bi, pi], 0, 0)
+        return (layer_ref[0], table_ref[bi, pi], 0, 0)
 
     kernel = functools.partial(
         _paged_decode_q_kernel, scale=scale, page=page, n_pages=maxp, group=group
@@ -237,16 +247,16 @@ def paged_decode_attention_q(
         kernel,
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(n, hkv, maxp),
             in_specs=[
-                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, hkv, page), sc_map),
-                pl.BlockSpec((1, hkv, page), sc_map),
+                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
+                pl.BlockSpec((1, 1, 1, page, d), kv_map),
+                pl.BlockSpec((1, 1, 1, page, d), kv_map),
+                pl.BlockSpec((1, 1, hkv, page), sc_map),
+                pl.BlockSpec((1, 1, hkv, page), sc_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, d), jnp.float32),
                 pltpu.VMEM((group, 128), jnp.float32),
@@ -258,18 +268,20 @@ def paged_decode_attention_q(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), safe_table, q4, kq_pool, vq_pool, ks_pool, vs_pool)
+    )(lengths.astype(jnp.int32), safe_table, _layer_operand(layer), q4,
+      kq_pool, vq_pool, ks_pool, vs_pool)
     return out.reshape(n, hq, d)
 
 
 def _paged_decode_q4_kernel(
     ln_ref,    # SMEM [N] per-slot live length (scalar prefetch)
     table_ref, # SMEM [N, MaxP] block table (scalar prefetch)
+    layer_ref, # SMEM [1] layer index (scalar prefetch; index_maps only)
     q_ref,     # VMEM [1, 1, G, d]
-    k_ref,     # VMEM uint8 [1, 1, page, d//2] packed nibbles (index_map page)
-    v_ref,     # VMEM uint8 [1, 1, page, d//2]
-    ks_ref,    # VMEM [1, Hkv, page] per-position K scales (same page pick)
-    vs_ref,    # VMEM [1, Hkv, page]
+    k_ref,     # VMEM uint8 [1, 1, 1, page, d//2] packed nibbles (index_map page)
+    v_ref,     # VMEM uint8 [1, 1, 1, page, d//2]
+    ks_ref,    # VMEM [1, 1, Hkv, page] per-position K scales (same page pick)
+    vs_ref,    # VMEM [1, 1, Hkv, page]
     o_ref,     # VMEM [1, 1, G, d]
     acc_ref,   # scratch f32 [G, d]
     m_ref,     # scratch f32 [G, 128]
@@ -297,21 +309,21 @@ def _paged_decode_q4_kernel(
     # packed → [page, d] nibbles; int32 → compute dtype goes through f32
     # (exact for [-8, 7]; the direct int32 → bf16 convert is not one the
     # v5e kernel compiler is known to take)
-    k = unpack(k_ref[0, 0]).astype(jnp.float32).astype(q.dtype)
+    k = unpack(k_ref[0, 0, 0]).astype(jnp.float32).astype(q.dtype)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, page]
     # K-scale fold: identical order to the int8 kernel — constant along the
     # d reduction, multiplies the finished scores per key position.
-    s = s * select_head_row(ks_ref[0], hi)
+    s = s * select_head_row(ks_ref[0, 0], hi)
 
     kv_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
     s = jnp.where(kv_pos < ln_ref[bi], s, NEG_INF)
 
     # V-scale fold inside the recurrence (common.py), with V unpacked from
     # nibbles in-register — the PV matmul input converts to f32 there.
-    softmax_block_update(s, unpack(v_ref[0, 0]), acc_ref, m_ref, l_ref,
-                         v_scale=select_head_row(vs_ref[0], hi))
+    softmax_block_update(s, unpack(v_ref[0, 0, 0]), acc_ref, m_ref, l_ref,
+                         v_scale=select_head_row(vs_ref[0, 0], hi))
 
     def write(out):
         o_ref[0, 0] = out.astype(o_ref.dtype)
@@ -322,10 +334,11 @@ def _paged_decode_q4_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_q4(
     q: jnp.ndarray,        # [N, Hq, D]
-    kq_pool: jnp.ndarray,  # uint8 [P, Hkv, page, D//2] packed nibbles
-    vq_pool: jnp.ndarray,  # uint8 [P, Hkv, page, D//2]
-    ks_pool: jnp.ndarray,  # [P, Hkv, page] per-position K scales
-    vs_pool: jnp.ndarray,  # [P, Hkv, page]
+    kq_pool: jnp.ndarray,  # uint8 [L, P, Hkv, page, D//2] packed nibbles
+    vq_pool: jnp.ndarray,  # uint8 [L, P, Hkv, page, D//2]
+    ks_pool: jnp.ndarray,  # [L, P, Hkv, page] per-position K scales
+    vs_pool: jnp.ndarray,  # [L, P, Hkv, page]
+    layer,                 # scalar layer index
     table: jnp.ndarray,    # [N, MaxP] int32, OOB entries == P
     lengths: jnp.ndarray,  # [N] live length per slot
     *,
@@ -340,7 +353,7 @@ def paged_decode_attention_q4(
     traffic for the KV read is the packed byte stream — half the int8
     kernel's, a quarter of bf16's."""
     n, hq, d = q.shape
-    pool, hkv, page, d2 = kq_pool.shape
+    _, pool, hkv, page, d2 = kq_pool.shape
     _, maxp = table.shape
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
@@ -352,13 +365,13 @@ def paged_decode_attention_q4(
     q4 = q.reshape(n, hkv, group, d)
     safe_table = jnp.minimum(table, pool - 1).astype(jnp.int32)
 
-    def kv_map(bi, hi, pi, ln_ref, table_ref):
-        return (table_ref[bi, pi], hi, 0, 0)
+    def kv_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
+        return (layer_ref[0], table_ref[bi, pi], hi, 0, 0)
 
-    def sc_map(bi, hi, pi, ln_ref, table_ref):
+    def sc_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
         # all Hkv scale rows of the page; the kernel picks row hi
         # (common.select_head_row says why)
-        return (table_ref[bi, pi], 0, 0)
+        return (layer_ref[0], table_ref[bi, pi], 0, 0)
 
     kernel = functools.partial(
         _paged_decode_q4_kernel, scale=scale, page=page, n_pages=maxp, group=group
@@ -367,16 +380,16 @@ def paged_decode_attention_q4(
         kernel,
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(n, hkv, maxp),
             in_specs=[
-                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page, d2), kv_map),
-                pl.BlockSpec((1, 1, page, d2), kv_map),
-                pl.BlockSpec((1, hkv, page), sc_map),
-                pl.BlockSpec((1, hkv, page), sc_map),
+                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
+                pl.BlockSpec((1, 1, 1, page, d2), kv_map),
+                pl.BlockSpec((1, 1, 1, page, d2), kv_map),
+                pl.BlockSpec((1, 1, hkv, page), sc_map),
+                pl.BlockSpec((1, 1, hkv, page), sc_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, d), jnp.float32),
                 pltpu.VMEM((group, 128), jnp.float32),
@@ -388,5 +401,6 @@ def paged_decode_attention_q4(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), safe_table, q4, kq_pool, vq_pool, ks_pool, vs_pool)
+    )(lengths.astype(jnp.int32), safe_table, _layer_operand(layer), q4,
+      kq_pool, vq_pool, ks_pool, vs_pool)
     return out.reshape(n, hq, d)

@@ -66,7 +66,7 @@ def assert_paged_pool_consistent(engine, slots_empty: bool = False) -> None:
     assert (refs == engine._page_refs).all(), "refcounts diverge from holders"
     free = set(engine._free_pages)
     assert len(free) == len(engine._free_pages), "free list holds duplicates"
-    for p in range(getattr(engine, "_page_sink", 0), engine.total_pages):
+    for p in range(engine.total_pages):
         assert (p in free) == (refs[p] == 0), f"page {p}: free/held mismatch"
 
 

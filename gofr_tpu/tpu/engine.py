@@ -310,20 +310,14 @@ class _EngineBase:
                 + len(getattr(self, "_pending_long", [])))
 
     def _trace_scope(self):
-        """Context every trace-driving section runs under: paged engines pin
-        the KV append lowering they resolved at construction
-        (ops/paged.write_mode_scope), and generate engines pin the decode
-        attention backends their warmup autotuner measured
-        (ops/autotune.decision_scope) — so no trace re-reads os.environ and
-        every trace this engine drives resolves 'auto' the same way."""
+        """Context every trace-driving section runs under: engines with a
+        tp-sharded pool pin its KVShardCtx (ops/paged.kv_shard_scope), and
+        generate engines pin the decode attention backends their warmup
+        autotuner measured (ops/autotune.decision_scope) — so every trace
+        this engine drives resolves 'auto' the same way."""
         import contextlib
 
         stack = contextlib.ExitStack()
-        mode = getattr(self, "paged_kv_write", None)
-        if mode:
-            from gofr_tpu.ops.paged import write_mode_scope
-
-            stack.enter_context(write_mode_scope(mode))
         ctx = self._kv_shard_ctx() if hasattr(self, "_kv_shard_ctx") else None
         if ctx is not None:
             from gofr_tpu.ops.paged import kv_shard_scope
@@ -949,7 +943,6 @@ class GenerateEngine(_EngineBase):
         kv_layout: str = "slot",
         page_size: int = 128,
         total_pages: int | None = None,
-        paged_kv_write: str = "",
         max_restarts: int = 3,
         decode_pipeline: int = 2,
         prefix_cache: bool = True,
@@ -1200,13 +1193,6 @@ class GenerateEngine(_EngineBase):
             # default pool = same HBM as the slot cache; shrink to
             # oversubscribe, or keep and raise `slots` for more concurrency
             self.total_pages = total_pages if total_pages else slots * self.pages_per_slot
-            # KV append lowering, resolved from GOFR_PAGED_KV_WRITE exactly
-            # ONCE here and pinned for every trace this engine drives
-            # (_trace_scope → ops/paged.write_mode_scope) — ops/paged never
-            # re-reads os.environ at trace time on the engine's behalf.
-            from gofr_tpu.ops.paged import resolve_write_mode
-
-            self.paged_kv_write = resolve_write_mode(paged_kv_write or None)
             # Shard the pool over the mesh's tp axis along KV heads
             # (ops/paged.pool_sharding): per-device plane bytes drop to
             # 1/tp, and every trace this engine drives pins a KVShardCtx
@@ -1214,19 +1200,13 @@ class GenerateEngine(_EngineBase):
             # shard_map. "auto" stands down (1 shard, bit-identical to the
             # unsharded engine) whenever the mesh/geometry can't split.
             self.kv_shards, self._kv_pool_sharding = self._resolve_kv_shard(kv_shard)
-            # The in-place Pallas page append redirects OOB rows' aliased
-            # tile fetch to page 0 (ops/pallas/kv_append.py) — reserve it
-            # as a never-allocated sink so an OOB copy-through can never
-            # share a tile with a real write in the same call (ADVICE r4)
-            self._page_sink = 1 if self.paged_kv_write == "pallas" else 0
-            if self.total_pages - self._page_sink < self.pages_per_slot:
+            if self.total_pages < self.pages_per_slot:
                 raise ValueError(
-                    f"total_pages {self.total_pages} (minus {self._page_sink} "
-                    f"sink) < pages_per_slot {self.pages_per_slot}: one "
-                    "max-length request cannot fit"
+                    f"total_pages {self.total_pages} < pages_per_slot "
+                    f"{self.pages_per_slot}: one max-length request cannot fit"
                 )
             self.cache = self._build_paged_cache()
-            self._free_pages: list[int] = list(range(self._page_sink, self.total_pages))
+            self._free_pages: list[int] = list(range(self.total_pages))
             self._slot_pages: list[list[int]] = [[] for _ in range(slots)]
             # OOB convention: unallocated entries point one past the pool
             self._table = np.full((slots, self.pages_per_slot), self.total_pages, np.int32)
@@ -1763,10 +1743,11 @@ class GenerateEngine(_EngineBase):
         n = self.num_slots
 
         if self.kv_layout == "paged":
-            # Candidate inputs reuse the engine's own layer-0 pool planes
-            # (right per-shard shape AND dtype, no second pool in HBM) with
-            # a full-occupancy block table and full lengths — the worst-case
-            # stream each serving decode step pays.
+            # Candidate inputs are the engine's own pool planes, read at
+            # layer 0 the way a serving step reads them (right per-shard
+            # shape AND dtype, no second pool in HBM), with a full-occupancy
+            # block table and full lengths — the worst-case stream each
+            # serving decode step pays.
             maxp, page = self.pages_per_slot, self.page_size
             pool = self.total_pages
             rng = np.random.RandomState(0)
@@ -1778,37 +1759,17 @@ class GenerateEngine(_EngineBase):
             skey = autotune.shape_key(n, hq, hkv, d, page, maxp, pool)
             kv = self.kv_cache  # spec mode wraps the pool in (kv, hist)
             if self.kv_quantize == "int4":
-                kq, vq = kv.k[0], kv.v[0]  # packed uint8, last dim d//2
-                ks, vs = kv.ks[0], kv.vs[0]
-                cands = {"xla": self._at_fn(
-                    attn_ops.paged_decode_attention_q4, "xla",
-                    q, kq, vq, ks, vs, table, lengths)}
-                if pallas_ok and page % 8 == 0:
-                    cands["pallas"] = self._at_fn(
-                        attn_ops.paged_decode_attention_q4, "pallas",
-                        q, kq, vq, ks, vs, table, lengths)
-                tuner.measure("paged_decode_q4", skey, "int4", cands)
+                op, op_fn, kv_dtype = "paged_decode_q4", attn_ops.paged_decode_attention_q4, "int4"
             elif self.kv_quantize:
-                kq, vq = kv.k[0], kv.v[0]
-                ks, vs = kv.ks[0], kv.vs[0]
-                cands = {"xla": self._at_fn(
-                    attn_ops.paged_decode_attention_q, "xla",
-                    q, kq, vq, ks, vs, table, lengths)}
-                if pallas_ok and page % 8 == 0:
-                    cands["pallas"] = self._at_fn(
-                        attn_ops.paged_decode_attention_q, "pallas",
-                        q, kq, vq, ks, vs, table, lengths)
-                tuner.measure("paged_decode_q", skey, "int8", cands)
+                op, op_fn, kv_dtype = "paged_decode_q", attn_ops.paged_decode_attention_q, "int8"
             else:
-                kp, vp = kv.k[0], kv.v[0]
-                cands = {"xla": self._at_fn(
-                    attn_ops.paged_decode_attention, "xla",
-                    q, kp, vp, table, lengths)}
-                if pallas_ok and page % 8 == 0:
-                    cands["pallas"] = self._at_fn(
-                        attn_ops.paged_decode_attention, "pallas",
-                        q, kp, vp, table, lengths)
-                tuner.measure("paged_decode", skey, str(kp.dtype), cands)
+                op, op_fn, kv_dtype = "paged_decode", attn_ops.paged_decode_attention, str(kv.k.dtype)
+            planes = (kv.k, kv.v, kv.ks, kv.vs) if self.kv_quantize else (kv.k, kv.v)
+            args = (q, *planes, jnp.zeros((), jnp.int32), table, lengths)
+            cands = {"xla": self._at_fn(op_fn, "xla", *args)}
+            if pallas_ok and page % 8 == 0:
+                cands["pallas"] = self._at_fn(op_fn, "pallas", *args)
+            tuner.measure(op, skey, kv_dtype, cands)
         elif not self.kv_quantize:
             # slot layout, dense cache (the int8 slot path has no kernel
             # variant to race). With spec on the cache is (kv, aux).
@@ -1947,7 +1908,6 @@ class GenerateEngine(_EngineBase):
             free = len(self._free_pages)
             held = sum(len(p) for p in self._slot_pages)
             live = sum(s.pos for s in self.slots if s is not None)
-        usable = max(1, self.total_pages - self._page_sink)
         covered = held * self.page_size
         # Byte fields are SHARD-LOCAL (per-device): on a tp-sharded pool
         # each device holds 1/kv_shards of every plane, and a fleet rollup
@@ -1962,7 +1922,7 @@ class GenerateEngine(_EngineBase):
             "kv_shards": shards,
             "page_bytes_device": getattr(self, "_page_bytes", 0) // shards,
             "pool_bytes_device": getattr(self, "_pool_bytes", 0) // shards,
-            "occupancy": round(1.0 - free / usable, 4),
+            "occupancy": round(1.0 - free / self.total_pages, 4),
             "fragmentation": round(1.0 - min(1.0, live / covered), 4)
             if covered else 0.0,
         }
@@ -2420,7 +2380,7 @@ class GenerateEngine(_EngineBase):
         with self._state_lock:
             if self.kv_layout == "paged":
                 self.cache = self._place_cache(self._build_paged_cache())
-                self._free_pages = list(range(self._page_sink, self.total_pages))
+                self._free_pages = list(range(self.total_pages))
                 self._slot_pages = [[] for _ in range(self.num_slots)]
                 self._table = np.full(
                     (self.num_slots, self.pages_per_slot), self.total_pages, np.int32
@@ -4341,8 +4301,6 @@ def build_engine(spec: ModelSpec, container, **kw: Any):
             kv_layout=kv_layout,
             page_size=int(kw.pop("page_size", conf.get_int("ENGINE_PAGE_SIZE", 128))),
             total_pages=int(kw.pop("total_pages", conf.get_int("ENGINE_TOTAL_PAGES", 0))) or None,
-            paged_kv_write=str(kw.pop("paged_kv_write",
-                                      conf.get_or_default("ENGINE_PAGED_KV_WRITE", ""))),
             seed=seed,
             prefix_cache=prefix_cache,
             prefix_host_mb=float(kw.pop("prefix_host_mb",

@@ -22,15 +22,18 @@ from gofr_tpu.ops.attention import (
 pytestmark = pytest.mark.quick
 
 
+LAYER = 1  # the ops read whole [L, P, ...] planes at a layer index
+
+
 def _qpools(key, pool, hkv, page, d):
-    """int8 K/V page pools with non-trivial, DISTINCT per-position scales —
-    a wrong ks/vs fold cannot cancel out."""
+    """int8 K/V page pools (2 layers) with non-trivial, DISTINCT
+    per-position scales — a wrong ks/vs fold cannot cancel out."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    kq = jax.random.randint(k1, (pool, hkv, page, d), -127, 128, jnp.int8)
-    vq = jax.random.randint(k2, (pool, hkv, page, d), -127, 128, jnp.int8)
-    ks = jax.random.uniform(k3, (pool, hkv, page), minval=0.005,
+    kq = jax.random.randint(k1, (2, pool, hkv, page, d), -127, 128, jnp.int8)
+    vq = jax.random.randint(k2, (2, pool, hkv, page, d), -127, 128, jnp.int8)
+    ks = jax.random.uniform(k3, (2, pool, hkv, page), minval=0.005,
                             maxval=0.05).astype(jnp.bfloat16)
-    vs = jax.random.uniform(k4, (pool, hkv, page), minval=0.02,
+    vs = jax.random.uniform(k4, (2, pool, hkv, page), minval=0.02,
                             maxval=0.2).astype(jnp.bfloat16)
     return kq, vq, ks, vs
 
@@ -51,9 +54,9 @@ def test_paged_decode_q_kernel_matches_gather_path(monkeypatch, hq, hkv):
     table = table.at[2, 2:].set(pool)  # OOB unallocated tail
     lengths = jnp.array([page * maxp, 19, page + 3], jnp.int32)
 
-    want = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="xla")
+    want = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="xla")
     monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
-    got = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="pallas")
+    got = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="pallas")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
@@ -68,11 +71,11 @@ def test_paged_decode_q_empty_slot_zero_not_nan(monkeypatch):
 
     monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
     got = np.asarray(paged_decode_attention_q(
-        q, kq, vq, ks, vs, table, lengths, backend="pallas"))
+        q, kq, vq, ks, vs, LAYER, table, lengths, backend="pallas"))
     assert not np.isnan(got).any()
     np.testing.assert_allclose(got[0], np.zeros_like(got[0]), atol=1e-7)
     want = np.asarray(paged_decode_attention_q(
-        q, kq, vq, ks, vs, table, lengths, backend="xla"))
+        q, kq, vq, ks, vs, LAYER, table, lengths, backend="xla"))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -90,14 +93,14 @@ def test_paged_decode_q_scale_folds_match_dequantized_dense(monkeypatch):
     table = jnp.asarray(rng.permutation(pool)[: n * maxp].reshape(n, maxp), jnp.int32)
     lengths = jnp.array([maxp * page, 11], jnp.int32)
 
-    gkq, gks = gather_kv_q(kq, ks, table)
-    gvq, gvs = gather_kv_q(vq, vs, table)
+    gkq, gks = gather_kv_q(kq, ks, LAYER, table)
+    gvq, gvs = gather_kv_q(vq, vs, LAYER, table)
     k_dense = gkq.astype(jnp.float32) * gks.astype(jnp.float32)[..., None]
     v_dense = gvq.astype(jnp.float32) * gvs.astype(jnp.float32)[..., None]
     want = decode_attention(q, k_dense, v_dense, lengths, backend="xla")
 
     monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
-    got = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="pallas")
+    got = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="pallas")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
@@ -118,7 +121,7 @@ def test_fused_path_skips_gather(monkeypatch):
     kq, vq, ks, vs = _qpools(key, pool, hkv, page, d)
     table = jnp.arange(n * maxp, dtype=jnp.int32).reshape(n, maxp)
     lengths = jnp.array([page, 3], jnp.int32)
-    out = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="pallas")
+    out = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="pallas")
     assert np.isfinite(np.asarray(out)).all()
 
 
@@ -133,10 +136,10 @@ def test_paged_decode_q_explicit_pallas_bad_page_raises(monkeypatch):
     table = jnp.arange(n * maxp, dtype=jnp.int32).reshape(n, maxp)
     lengths = jnp.array([page, 3], jnp.int32)
     with pytest.raises(ValueError, match="backend='pallas'"):
-        paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="pallas")
+        paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="pallas")
     # 'auto' may degrade silently — and must agree with the explicit xla path
-    got = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="auto")
-    want = paged_decode_attention_q(q, kq, vq, ks, vs, table, lengths, backend="xla")
+    got = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="auto")
+    want = paged_decode_attention_q(q, kq, vq, ks, vs, LAYER, table, lengths, backend="xla")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 

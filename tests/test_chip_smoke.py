@@ -1,7 +1,7 @@
 """chip_smoke.py rehearsed on the CPU mesh, and the loud paths it relies on.
 
 The smoke's body (``run_smoke``) runs here at ``LlamaConfig.tiny()`` width on
-the virtual devices — both KV layouts over HTTP, the seven kernels under the
+the virtual devices — both KV layouts over HTTP, the six kernels under the
 Pallas interpreter, the tp:4 pass — so a change that breaks the script is
 caught before it costs chip time. Only the script ENTRY insists on a TPU.
 """
@@ -67,7 +67,6 @@ def test_script_entry_refuses_a_non_tpu_platform():
 def test_explicit_pallas_request_raises_where_no_kernel_can_lower(monkeypatch):
     from gofr_tpu.ops.attention import resolve_backend
     from gofr_tpu.ops.kvcache import append_tokens
-    from gofr_tpu.ops.paged import append_tokens_paged, write_mode_scope
 
     monkeypatch.delenv("GOFR_PALLAS_INTERPRET", raising=False)
     assert resolve_backend("auto") == "xla"  # 'auto' may pick XLA; a named kernel may not
@@ -79,8 +78,6 @@ def test_explicit_pallas_request_raises_where_no_kernel_can_lower(monkeypatch):
     monkeypatch.setenv("GOFR_KV_WRITE", "pallas")
     with pytest.raises(RuntimeError, match="GOFR_KV_WRITE=pallas"):
         append_tokens(kv, kv, pos, new, new)
-    with write_mode_scope("pallas"), pytest.raises(RuntimeError, match="GOFR_PAGED_KV_WRITE=pallas"):
-        append_tokens_paged(kv, kv, jnp.asarray([[0], [1]]), pos, new, new)
 
 
 @pytest.mark.quick

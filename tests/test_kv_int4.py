@@ -94,15 +94,16 @@ def test_write_append_gather_roundtrip():
     to its own fake-quant round-trip; positions past the length are
     untouched (zero scale planes)."""
     page, hkv, d = 8, 2, 32
-    kq = jnp.zeros((6, hkv, page, d // 2), jnp.uint8)
-    ks = jnp.zeros((6, hkv, page), jnp.bfloat16)
+    kq = jnp.zeros((2, 6, hkv, page, d // 2), jnp.uint8)  # 2 layers, layer 1 used
+    ks = jnp.zeros((2, 6, hkv, page), jnp.bfloat16)
     table = jnp.asarray([[0, 1], [3, 6]], jnp.int32)  # slot 1 page 1 is OOB
     prompt = jax.random.normal(jax.random.key(3), (2, 5, hkv, d), jnp.float32)
-    kq, ks = write_prompts_paged_q4(kq, ks, table, prompt, jnp.asarray([0, 0]))
+    kq, ks = write_prompts_paged_q4(kq, ks, 1, table, prompt, jnp.asarray([0, 0]))
     step = jax.random.normal(jax.random.key(4), (2, hkv, d), jnp.float32)
-    kq, ks = append_tokens_paged_q4(kq, ks, table, jnp.asarray([5, 5]), step)
+    kq, ks = append_tokens_paged_q4(kq, ks, 1, table, jnp.asarray([5, 5]), step)
+    assert not np.asarray(kq[0]).any() and not np.asarray(ks[0]).any()
 
-    gq, gs = gather_kv_q4(kq, ks, table)  # [2, hkv, 16, d], [2, hkv, 16]
+    gq, gs = gather_kv_q4(kq, ks, 1, table)  # [2, hkv, 16, d], [2, hkv, 16]
     view = gq.astype(jnp.float32) * gs.astype(jnp.float32)[..., None]
     full = jnp.concatenate([prompt, step[:, None]], axis=1)  # [2, 6, hkv, d]
     want = fake_quant_row_int4(full, scale_dtype=jnp.bfloat16)
@@ -119,15 +120,15 @@ def test_write_append_gather_roundtrip():
 def _build_case(key, n, hq, hkv, d, page, max_pages, table):
     """Random q + a packed pool whose pages are filled through the same
     write helper the model uses (so parity covers the layout end to end)."""
-    kq = vq = jnp.zeros((max_pages * n, hkv, page, d // 2), jnp.uint8)
-    ks = vs = jnp.zeros((max_pages * n, hkv, page), jnp.bfloat16)
+    kq = vq = jnp.zeros((1, max_pages * n, hkv, page, d // 2), jnp.uint8)
+    ks = vs = jnp.zeros((1, max_pages * n, hkv, page), jnp.bfloat16)
     ka, kb, kc = jax.random.split(key, 3)
     q = jax.random.normal(ka, (n, hq, d), jnp.float32)
     k = jax.random.normal(kb, (n, max_pages * page, hkv, d), jnp.float32)
     v = jax.random.normal(kc, (n, max_pages * page, hkv, d), jnp.float32)
     off = jnp.zeros((n,), jnp.int32)
-    kq, ks = write_prompts_paged_q4(kq, ks, table, k, off)
-    vq, vs = write_prompts_paged_q4(vq, vs, table, v, off)
+    kq, ks = write_prompts_paged_q4(kq, ks, 0, table, k, off)
+    vq, vs = write_prompts_paged_q4(vq, vs, 0, table, v, off)
     return q, kq, vq, ks, vs
 
 
@@ -147,9 +148,9 @@ def test_paged_decode_q4_kernel_matches_gather(monkeypatch, hq, hkv):
     q, kq, vq, ks, vs = _build_case(
         jax.random.key(7), n, hq, hkv, d, page, maxp, table)
     want = paged_decode_attention_q4(
-        q, kq, vq, ks, vs, table, lengths, backend="xla")
+        q, kq, vq, ks, vs, 0, table, lengths, backend="xla")
     got = paged_decode_attention_q4(
-        q, kq, vq, ks, vs, table, lengths, backend="pallas")
+        q, kq, vq, ks, vs, 0, table, lengths, backend="pallas")
     np.testing.assert_allclose(
         np.asarray(got[:2]), np.asarray(want[:2]), rtol=2e-2, atol=2e-2)
     assert np.isfinite(np.asarray(got[:2])).all()
@@ -167,7 +168,7 @@ def test_paged_decode_q4_explicit_pallas_rejects_bad_page(monkeypatch):
         jax.random.key(8), n, 2, 2, d, page, 1, table)
     with pytest.raises(ValueError, match="multiple of 8"):
         paged_decode_attention_q4(
-            q, kq, vq, ks, vs, table, jnp.asarray([2]), backend="pallas")
+            q, kq, vq, ks, vs, 0, table, jnp.asarray([2]), backend="pallas")
 
 
 # -- engine level --------------------------------------------------------------

@@ -166,35 +166,6 @@ def test_append_inplace_matches_select(monkeypatch):
     np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v), rtol=1e-6)
 
 
-def test_append_paged_inplace_matches_select():
-    """Paged-pool in-place append == the select path through a shuffled
-    block table, OOB table rows dropped."""
-    import numpy as np
-
-    from gofr_tpu.ops.paged import append_tokens_paged
-    from gofr_tpu.ops.pallas.kv_append import append_tokens_paged_inplace
-
-    n, hkv, d, page, maxp = 3, 2, 16, 8, 3
-    pool = 10
-    key = jax.random.key(5)
-    k_pool = jax.random.normal(jax.random.fold_in(key, 1), (pool, hkv, page, d))
-    v_pool = jax.random.normal(jax.random.fold_in(key, 2), (pool, hkv, page, d))
-    k_new = jax.random.normal(jax.random.fold_in(key, 3), (n, hkv, d))
-    v_new = jax.random.normal(jax.random.fold_in(key, 4), (n, hkv, d))
-    # page 0 is the reserved OOB sink under this lowering (the engine
-    # never allocates it) — real rows use pages >= 1. The pre-fix clamp
-    # (OOB -> pool-1) demonstrably LOSES a real write to the shared tile
-    # even in interpreter mode, which is why the sink exists (ADVICE r4).
-    table = jnp.array([[7, 2, 9], [4, 5, 3], [pool, pool, pool]], jnp.int32)
-    positions = jnp.array([page + 3, 0, 5], jnp.int32)  # row 2 = OOB table
-
-    want_k, want_v = append_tokens_paged(k_pool, v_pool, table, positions, k_new, v_new)
-    got_k, got_v = append_tokens_paged_inplace(
-        k_pool, v_pool, table, positions, k_new, v_new, interpret=True)
-    np.testing.assert_allclose(np.asarray(got_k), np.asarray(want_k), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v), rtol=1e-6)
-
-
 def test_kv_write_env_dispatch(monkeypatch):
     """GOFR_KV_WRITE=pallas routes append_tokens through the kernel (under
     the interpreter here) with identical results to select."""
@@ -216,59 +187,3 @@ def test_kv_write_env_dispatch(monkeypatch):
     got = append_tokens(k_layer, v_layer, positions, k_new, v_new)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=1e-6)
-
-
-def test_append_paged_oob_redirects_to_sink_page():
-    """ADVICE r4: an OOB row must route its aliased tile fetch to the
-    RESERVED sink page 0 — never clamp onto a page a real row writes in
-    the same call (under Mosaic pipelining a stale copy-through could
-    overwrite the real write). Here a real row writes the pool's LAST
-    page while another row is OOB; the write must land and page 0 must
-    be byte-identical."""
-    import numpy as np
-
-    from gofr_tpu.ops.pallas.kv_append import append_tokens_paged_inplace
-
-    n, hkv, d, page, maxp = 2, 2, 16, 8, 2
-    pool = 4
-    key = jax.random.key(7)
-    k_pool = jax.random.normal(jax.random.fold_in(key, 1), (pool, hkv, page, d))
-    v_pool = jax.random.normal(jax.random.fold_in(key, 2), (pool, hkv, page, d))
-    k_new = jax.random.normal(jax.random.fold_in(key, 3), (n, hkv, d))
-    v_new = jax.random.normal(jax.random.fold_in(key, 4), (n, hkv, d))
-    # row 0 writes the LAST page (the pre-fix clamp target); row 1 is OOB
-    table = jnp.array([[pool - 1, 1], [pool, pool]], jnp.int32)
-    positions = jnp.array([3, 0], jnp.int32)
-
-    got_k, got_v = append_tokens_paged_inplace(
-        k_pool, v_pool, table, positions, k_new, v_new, interpret=True)
-    np.testing.assert_allclose(np.asarray(got_k[pool - 1, :, 3, :]),
-                               np.asarray(k_new[0]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(got_v[pool - 1, :, 3, :]),
-                               np.asarray(v_new[0]), rtol=1e-6)
-    # sink page 0 untouched by the OOB copy-through
-    np.testing.assert_array_equal(np.asarray(got_k[0]), np.asarray(k_pool[0]))
-    np.testing.assert_array_equal(np.asarray(got_v[0]), np.asarray(v_pool[0]))
-
-
-def test_engine_reserves_sink_page_under_pallas_paged_write(monkeypatch):
-    """With GOFR_PAGED_KV_WRITE=pallas the engine must never allocate
-    page 0 (the kernel's OOB sink)."""
-    from gofr_tpu.container import new_mock_container
-    from gofr_tpu.models import LlamaConfig, llama
-    from gofr_tpu.tpu.engine import GenerateEngine
-
-    monkeypatch.setenv("GOFR_PAGED_KV_WRITE", "pallas")
-    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
-    cfg = LlamaConfig.tiny()
-    params = llama.init(cfg, jax.random.key(7))
-    eng = GenerateEngine(llama, cfg, params, new_mock_container(),
-                         slots=2, max_len=64, kv_layout="paged", page_size=8)
-    try:
-        assert eng._page_sink == 1
-        assert 0 not in eng._free_pages
-        out = eng.generate([5, 3, 9], max_new_tokens=4, timeout=300)
-        assert len(out["tokens"]) == 4
-        assert 0 not in [p for pages in eng._slot_pages for p in pages]
-    finally:
-        eng.stop()

@@ -178,7 +178,7 @@ def test_overclaim_trims_to_actual_position_at_fold(setup):
                     break
             time.sleep(0.02)
         with eng._state_lock:
-            held = int(eng._page_refs[eng._page_sink:].sum())
+            held = int(eng._page_refs.sum())
         assert held == 0, f"{held} pages leaked past the fold's trim"
         assert_paged_pool_consistent(eng, slots_empty=True)
     finally:

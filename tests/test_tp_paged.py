@@ -124,6 +124,45 @@ def test_pool_planes_sharded_over_tp_and_stay_sharded(setup):
         eng.stop()
 
 
+@pytest.mark.parametrize("kind", ["bf16", "int8", "int4"])
+def test_decode_step_returns_the_pool_sharded_as_given(setup, kind):
+    """The model-level program itself, no engine: a decode step under
+    ``KVShardCtx`` takes a tp-sharded pool and returns every plane with the
+    sharding it was given — the in-place write partitions along the KV-head
+    axis like the read, and no plane is gathered onto one device (no
+    all-gather of a plane in the compiled step). Logits match the
+    unsharded step."""
+    import re
+
+    import jax.numpy as jnp
+
+    from gofr_tpu.models import llama
+    from gofr_tpu.ops.paged import KVShardCtx, kv_shard_scope, pool_sharding
+    from gofr_tpu.parallel.mesh import build_mesh
+
+    cfg, params, _ = setup
+    make = {"bf16": llama.make_paged_cache, "int8": llama.make_paged_cache_q,
+            "int4": llama.make_paged_cache_q4}[kind]
+    mesh = build_mesh("dp:2,tp:4")
+    table = jnp.asarray([[5, 2, 9], [0, 7, 12], [12, 12, 12]], jnp.int32)  # lane 2 idle
+    toks, pos = jnp.asarray([3, 8, 1], jnp.int32), jnp.asarray([9, 17, 0], jnp.int32)
+
+    want, _ = llama.decode_step_paged(cfg, params, toks, pos, make(cfg, 12, 8), table)
+    cache = make(cfg, 12, 8, sharding=pool_sharding(mesh))
+    given = [leaf.sharding for leaf in jax.tree.leaves(cache)]
+    with kv_shard_scope(KVShardCtx(mesh=mesh, axis="tp", shards=4)):
+        compiled = llama.decode_step_paged.lower(cfg, params, toks, pos, cache, table).compile()
+        got, out = llama.decode_step_paged(cfg, params, toks, pos, cache, table)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for leaf, sharding in zip(jax.tree.leaves(out), given):
+        assert leaf.sharding.is_equivalent_to(sharding, leaf.ndim), (leaf.sharding, sharding)
+        assert {sh.data.shape[2] for sh in leaf.addressable_shards} == {leaf.shape[2] // 4}
+    # a plane gathered whole would be an all-gather whose result has all 4 KV heads
+    for shape in re.findall(r"= \w+\[([\d,]+)\][^=]* all-gather", compiled.as_text()):
+        dims = [int(x) for x in shape.split(",")]
+        assert not (len(dims) >= 4 and dims[0] == cfg.num_layers), f"a pool plane is all-gathered: {dims}"
+
+
 # -- spec rounds + preemption + prefix swap-in on the sharded pool -------------
 
 
