@@ -356,6 +356,13 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
     # way the engine marks rows whose write must be dropped
     append_table = table.at[0, maxp - 1].set(pool)
     full = jnp.full((n,), maxp * page, jnp.int32)
+    # ragged lanes: empty, one token, either side of a page boundary, full;
+    # past its live pages a lane's table holds the sentinel, as the engine's does
+    ragged = jnp.asarray(
+        [(0, 1, page - 1, page, page + 1, maxp * page)[i % 6] for i in range(n)], jnp.int32)
+    ragged_table = jnp.where(
+        jnp.arange(maxp)[None, :] * page < ragged[:, None], table, pool)
+    reads = ((table, full), (ragged_table, ragged))
     k_cache, v_cache = normal(n, hkv, smax, d), normal(n, hkv, smax, d)
     k8, ks8 = kvcache.quantize_row(k_pool)
     v8, vs8 = kvcache.quantize_row(v_pool)
@@ -400,10 +407,10 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
             lambda: xla(attn.decode_attention, backend="xla")(
                 q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32)), atol),
         "paged_decode_bf16": (
-            lambda: k_paged.paged_decode_attention(q, k_pool, v_pool, layer, table, full,
-                                                   interpret=interpret),
-            lambda: xla(attn.paged_decode_attention, backend="xla")(
-                q, k_pool, v_pool, layer, table, full), atol),
+            lambda: [k_paged.paged_decode_attention(q, k_pool, v_pool, layer, tb, ln,
+                                                    interpret=interpret) for tb, ln in reads],
+            lambda: [xla(attn.paged_decode_attention, backend="xla")(
+                q, k_pool, v_pool, layer, tb, ln) for tb, ln in reads], atol),
         "paged_decode_int8": (
             lambda: k_paged.paged_decode_attention_q(q, k8, v8, ks8, vs8, layer, table, full,
                                                      interpret=interpret),

@@ -48,7 +48,9 @@ import os
 import time
 from typing import Any, Callable
 
-FORMAT_VERSION = 1
+# 2: the bf16 paged-decode kernel was rewritten (a whole page of every KV head
+# per copy, live pages only); a version-1 pin was raced against the old one.
+FORMAT_VERSION = 2
 BACKENDS = ("pallas", "xla")
 
 # {op: backend} pinned for the traces inside a decision_scope — consulted

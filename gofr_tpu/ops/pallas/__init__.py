@@ -77,8 +77,9 @@ def flash_attention_available() -> bool:
     (ops/autotune.py — measured per (op, shape, kv dtype, device_kind) on
     the engine's real serving shapes) whenever one is in scope, and
     GOFR_PALLAS, when explicitly set, overrides both. The static default
-    here is XLA on hardware: no kernel has a chip measurement that earns
-    it the default (PERF.md; ROADMAP S6). Interpreter tests still exercise
+    here is XLA on hardware: a kernel serves where it wins the warm-up
+    race on the engine's own shapes (the bf16 paged-decode kernel does, at
+    both benchmark widths; PERF.md), none by default. Interpreter tests still exercise
     the kernels (GOFR_PALLAS_INTERPRET=1), and an explicit
     ``backend='pallas'`` bypasses this gate entirely."""
     if os.environ.get("GOFR_PALLAS", "") == "0":

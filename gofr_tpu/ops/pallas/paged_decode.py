@@ -1,19 +1,30 @@
 """Decode attention over the PAGED KV cache as a Pallas kernel.
 
-Same HBM-bound hot loop as decode_attention.py, but K/V tiles come out of
-the physical page pool [L, P, Hkv, page, D] through each slot's block table
+Same HBM-bound hot loop as decode_attention.py, but K/V come out of the
+physical page pool [L, P, Hkv, page, D] through each slot's block table
 instead of a contiguous [Smax] row. The table, the per-slot lengths and the
 layer index ride in as SCALAR-PREFETCH operands
-(pltpu.PrefetchScalarGridSpec), so the BlockSpec index_map can resolve
-``grid step (slot, head, logical_page) -> (layer, physical page)`` BEFORE
-the DMA is issued — the kernel streams exactly the pages a
-slot owns, never a gather-materialized copy of the logical view (that copy
-is the XLA fallback, ops.paged.gather_kv).
+(pltpu.PrefetchScalarGridSpec), so a kernel knows (layer, physical page)
+before it asks for a byte — it reads the pages a slot owns where they lie,
+never a gather-materialized copy of the logical view (that copy is the XLA
+fallback, ops.paged.gather_kv).
 
-Grid: (slot, kv_head, logical_page); the page axis is ``arbitrary`` so the
-online-softmax scratch (common.py recurrence) carries across pages of one
-(slot, head). Unallocated logical pages (table entry == P) clamp to P-1 and
-are fully position-masked, contributing nothing.
+``paged_decode_attention`` (bf16 pool; what the served decode program runs
+where it wins the warm-up race) — grid (slot,), one lane a step, the pool
+planes left in HBM. A lane's pages are a loop over ceil(len / page), each
+page ONE copy a plane of [Hkv, page, D] — a whole page of every KV head,
+which the pool's layout makes one contiguous run — into one of two VMEM
+buffers, the next page's copy (the next lane's first, at a lane's end) in
+flight while this one is attended. A page past a lane's length costs no
+copy, no arithmetic and no grid step. Every head is attended at once: one
+[Hq, D] x [D, Hkv*page] product whose blocks off a query head's own KV head
+are masked away together with the positions past the length. Unallocated
+table entries (== P) clamp to P-1 and are only ever read behind that mask.
+docs/kernels.md has the design's A/B against a BlockSpec-only grid of
+(slot, logical_page).
+
+The int8 and int4 kernels below keep the older grid, (slot, kv_head,
+logical_page) with one [page, D] tile a step, dead pages included.
 
 ``paged_decode_attention_q`` is the fused int8-KV variant (ISSUE 6 /
 ROADMAP O3): quantized K/V pages plus their per-position scale planes
@@ -57,44 +68,97 @@ def _layer_operand(layer) -> jnp.ndarray:
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
+# Scores of a query head against another KV head's keys carry this "position":
+# beyond every length, so the length mask drops them too.
+_OTHER_HEAD = 1 << 30
+_LANES = 128
+
+
 def _paged_decode_kernel(
     ln_ref,    # SMEM [N] per-slot live length (scalar prefetch)
     table_ref, # SMEM [N, MaxP] block table (scalar prefetch)
-    layer_ref, # SMEM [1] layer index (scalar prefetch; index_maps only)
-    q_ref,     # VMEM [1, 1, G, d]
-    k_ref,     # VMEM [1, 1, 1, page, d] — the (layer, page) picked by index_map
-    v_ref,     # VMEM [1, 1, 1, page, d]
-    o_ref,     # VMEM [1, 1, G, d]
-    acc_ref,   # scratch f32 [G, d]
-    m_ref,     # scratch f32 [G, 128]
-    l_ref,     # scratch f32 [G, 128]
+    layer_ref, # SMEM [1] layer index (scalar prefetch)
+    q_ref,     # VMEM [Hq, d]
+    pos_ref,   # VMEM int32 [Hq, Hkv*page]: position in the page, _OTHER_HEAD off the head's own block
+    k_hbm,     # the whole plane [L, P, Hkv, page, d], left where it lies
+    v_hbm,
+    o_ref,     # VMEM [Hq, d]
+    k_buf,     # scratch [2, Hkv, page, d]: the page being attended and the one on its way
+    v_buf,
+    sem,       # DMA semaphores [2 planes, 2 buffers]
+    acc_ref,   # scratch f32 [Hq, d]
+    m_ref,     # scratch f32 [Hq, 128]
+    l_ref,     # scratch f32 [Hq, 128]
+    seq_ref,   # SMEM [1]: pages copied by the lanes before this one
     *,
     scale: float,
     page: int,
-    n_pages: int,
-    group: int,
 ):
+    """One grid step is one lane; its pages are a loop, not grid steps.
+
+    The copies form one chain over the whole call: page ``j`` of a lane
+    lands in buffer ``(seq + j) % 2``, and the copy of the page after it —
+    the NEXT LANE's first when ``j`` is this lane's last — starts before
+    page ``j`` is attended. Every lane copies at least one page (an empty
+    lane its table's first entry, which it does not attend), so the chain
+    never breaks and the last lane leaves no copy in flight."""
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
-    init_softmax_scratch(pi, acc_ref, m_ref, l_ref)
+    lanes = pl.num_programs(0)
+    length = ln_ref[bi]
+    hkv, _, d = k_buf.shape[1:]
 
-    q = q_ref[0, 0]  # [G, d]
-    k = k_ref[0, 0, 0]  # [page, d]
-    v = v_ref[0, 0, 0]
+    def page_copies(lane, j, buf):
+        src = (layer_ref[0], table_ref[lane, j])
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[buf], sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[buf], sem.at[1, buf]))
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [G, page]
+    def start(lane, j, buf):
+        for copy in page_copies(lane, j, buf):
+            copy.start()
 
-    kv_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
-    s = jnp.where(kv_pos < ln_ref[bi], s, NEG_INF)
+    @pl.when(bi == 0)
+    def _():
+        seq_ref[0] = 0
+        start(0, 0, 0)
 
-    softmax_block_update(s, v, acc_ref, m_ref, l_ref)
+    seq = seq_ref[0]
+    mine = jnp.maximum(pl.cdiv(length, page), 1)  # pages this lane copies
+    init_softmax_scratch(0, acc_ref, m_ref, l_ref)
+
+    def attend(j, carry):
+        buf = (seq + j) % 2
+        last = j + 1 == mine
+        next_lane = jnp.where(last, bi + 1, bi)
+
+        @pl.when(next_lane < lanes)
+        def _():
+            start(next_lane, jnp.where(last, 0, j + 1), 1 - buf)
+
+        for copy in page_copies(bi, j, buf):
+            copy.wait()
+
+        @pl.when(j * page < length)  # false only for an empty lane's one page
+        def _():
+            # every head at once: one [Hq, d] x [d, Hkv*page] product whose
+            # off-head blocks are masked away with the dead positions (a
+            # product per head pays the same MXU weight loads: A/B in
+            # docs/kernels.md)
+            s = jax.lax.dot_general(
+                q_ref[...], k_buf[buf].reshape(hkv * page, d),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale  # [Hq, Hkv*page]
+            s = jnp.where(pos_ref[...] + j * page < length, s, NEG_INF)
+            softmax_block_update(s, v_buf[buf].reshape(hkv * page, d), acc_ref, m_ref, l_ref)
+
+        return carry
+
+    jax.lax.fori_loop(0, mine, attend, 0)
+    seq_ref[0] = seq + mine
 
     def write(out):
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
-    softmax_finish(pi, n_pages, acc_ref, l_ref, write)
+    softmax_finish(0, 1, acc_ref, l_ref, write)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -118,40 +182,60 @@ def paged_decode_attention(
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / (d**0.5)
 
-    q4 = q.reshape(n, hkv, group, d)
+    if d % _LANES:
+        # A copy cannot cut a page whose rows are narrower than the lane
+        # width out of the plane. Such a pool's layer is padded to it first:
+        # the zeros add nothing to a score and their output columns are
+        # dropped. That is a copy of one layer a call, not the read in
+        # place; the warm-up race prices it.
+        def widen(x):
+            return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -d % _LANES)])
+
+        def layer_of(plane):
+            return widen(jax.lax.dynamic_index_in_dim(plane, layer, 0, keepdims=True))
+
+        return paged_decode_attention(
+            widen(q), layer_of(k_pool), layer_of(v_pool), 0, table, lengths,
+            scale=scale, interpret=interpret)[..., :d]
+
     safe_table = jnp.minimum(table, pool - 1).astype(jnp.int32)
+    col = jnp.arange(hkv * page, dtype=jnp.int32)[None, :]
+    own = jnp.arange(hq, dtype=jnp.int32)[:, None] // group == col // page
+    pos = jnp.where(own, col % page, _OTHER_HEAD)
 
-    def kv_map(bi, hi, pi, ln_ref, table_ref, layer_ref):
-        return (layer_ref[0], table_ref[bi, pi], hi, 0, 0)
+    def lane_map(bi, ln_ref, table_ref, layer_ref):
+        return (bi, 0, 0)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, page=page, n_pages=maxp, group=group
-    )
-    out = pl.pallas_call(
+    kernel = functools.partial(_paged_decode_kernel, scale=scale, page=page)
+    return pl.pallas_call(
         kernel,
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n, hkv, maxp),
+            grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, 1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, 1, page, d), kv_map),
+                pl.BlockSpec((None, hq, d), lane_map),
+                pl.BlockSpec((hq, hkv * page), lambda bi, ln, tb, ly: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, d), lambda bi, hi, pi, ln, tb, ly: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((None, hq, d), lane_map),
             scratch_shapes=[
-                pltpu.VMEM((group, d), jnp.float32),
-                pltpu.VMEM((group, 128), jnp.float32),
-                pltpu.VMEM((group, 128), jnp.float32),
+                pltpu.VMEM((2, hkv, page, d), k_pool.dtype),
+                pltpu.VMEM((2, hkv, page, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hq, d), jnp.float32),
+                pltpu.VMEM((hq, 128), jnp.float32),
+                pltpu.VMEM((hq, 128), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, hkv, group, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        out_shape=jax.ShapeDtypeStruct((n, hq, d), q.dtype),
+        # the lanes run in order: the chain of copies crosses from one to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), safe_table, _layer_operand(layer), q4, k_pool, v_pool)
-    return out.reshape(n, hq, d)
+    )(jnp.minimum(lengths.astype(jnp.int32), maxp * page), safe_table,
+      _layer_operand(layer), q, pos, k_pool, v_pool)
 
 
 def _paged_decode_q_kernel(

@@ -326,6 +326,10 @@ def test_sharding_key_isolates_pins_and_stays_read_compatible(tmp_path):
 @pytest.mark.parametrize("content", [
     "not json at all {",
     json.dumps({"version": 999, "entries": {"k": {"backend": "pallas"}}}),
+    # a well-formed pin for this very key, written under the format version
+    # before the paged-decode kernel was rewritten: raced again, not served
+    json.dumps({"version": autotune.FORMAT_VERSION - 1,
+                "entries": {"v5e|decode|8x16|int8": {"backend": "xla"}}}),
     json.dumps({"version": autotune.FORMAT_VERSION, "entries": "nope"}),
     json.dumps({"version": autotune.FORMAT_VERSION,
                 "entries": {"v5e|decode|8x16|int8": {"backend": "cuda"}}}),

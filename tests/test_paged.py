@@ -110,6 +110,76 @@ def test_paged_decode_kernel_matches_gather_path(monkeypatch, hq, hkv):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+def _ragged_case(hkv, d, group, page=8, maxp=3, seed=0):
+    """Six lanes whose lengths are 0, 1, page - 1, page, page + 1 and the full
+    span; each lane's table holds the pages its length needs and P (out of
+    bounds) after them, the empty lane's whole row is P."""
+    hq = hkv * group
+    lens = [0, 1, page - 1, page, page + 1, maxp * page]
+    n, pool = len(lens), len(lens) * maxp + 2  # two pages nobody owns
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = _rand(ks[0], (n, hq, d))
+    k_pool = _rand(ks[1], (LAYERS, pool, hkv, page, d))
+    v_pool = _rand(ks[2], (LAYERS, pool, hkv, page, d))
+    table = np.full((n, maxp), pool, np.int32)
+    perm = np.random.RandomState(seed).permutation(pool)
+    for i, ln in enumerate(lens):
+        need = -(-ln // page)
+        table[i, :need] = perm[i * maxp: i * maxp + need]
+    return q, k_pool, v_pool, table, np.asarray(lens, np.int32)
+
+
+@pytest.mark.parametrize("group", [2, 4, 8])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("hkv", [1, 2, 8])
+def test_paged_decode_kernel_ragged_lengths_every_head_geometry(monkeypatch, hkv, d, group):
+    """The kernel reads a whole page of every KV head per copy and stops at
+    each lane's last live page: lengths 0, 1, page - 1, page, page + 1 and
+    full in ONE batch, out-of-bounds table entries after the live pages,
+    for every head geometry the repo builds (a tp shard has 1 or 2 KV
+    heads; head_dim 64 and 128; 2, 4 and 8 query heads a KV head)."""
+    q, k_pool, v_pool, table, lens = _ragged_case(hkv, d, group)
+    table, lengths = jnp.asarray(table), jnp.asarray(lens)
+    want = paged_decode_attention(q, k_pool, v_pool, LAYER, table, lengths, backend="xla")
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    got = paged_decode_attention(q, k_pool, v_pool, LAYER, table, lengths, backend="pallas")
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[0]).any(), "a lane of length 0 returns zeros, not NaN"
+
+
+@pytest.mark.parametrize("poison", [float("nan"), 1e30])
+def test_paged_decode_kernel_reads_nothing_past_lengths(monkeypatch, poison):
+    """Poisoned pool: every page a lane does not own, every owned page past
+    its length (the table still names it), the other layers, and the K rows
+    past the length inside the last live page hold ``poison``. The result
+    equals XLA's over the same pool with the poison zeroed: no dead page is
+    read into it. (V rows past the length inside the live page stay finite:
+    there both paths multiply them by a probability of exactly 0.)"""
+    hkv, d, group, page, maxp = 2, 64, 4, 8, 3
+    q, k_pool, v_pool, table, lens = _ragged_case(hkv, d, group, page, maxp, seed=1)
+    n, pool = table.shape[0], k_pool.shape[1]
+    live = np.zeros((LAYERS, pool, hkv, page, d), bool)   # rows a result may depend on
+    fetched = np.zeros((LAYERS, pool), bool)              # pages with at least one live row
+    for i, ln in enumerate(lens):
+        for t in range(ln):
+            live[LAYER, table[i, t // page], :, t % page] = True
+            fetched[LAYER, table[i, t // page]] = True
+    # the table names a dead page for every lane but the full one: owned, never read
+    spare = [p for p in range(pool) if not fetched[LAYER, p]]
+    for i, ln in enumerate(lens[:-1]):
+        table[i, -(-ln // page)] = spare[i]
+    k_clean = jnp.where(live, k_pool, 0.0)
+    v_clean = jnp.where(live, v_pool, 0.0)
+    k_bad = jnp.where(live, k_pool, poison)
+    v_bad = jnp.where(live | fetched[:, :, None, None, None], v_clean, poison)
+    table, lengths = jnp.asarray(table), jnp.asarray(lens)
+    want = paged_decode_attention(q, k_clean, v_clean, LAYER, table, lengths, backend="xla")
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    got = paged_decode_attention(q, k_bad, v_bad, LAYER, table, lengths, backend="pallas")
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
 def test_paged_matches_dense_decode():
     """Paged attention over a contiguous table == dense decode over the
     equivalent [N, Hkv, Smax, D] cache."""

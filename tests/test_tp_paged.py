@@ -163,6 +163,51 @@ def test_decode_step_returns_the_pool_sharded_as_given(setup, kind):
         assert not (len(dims) >= 4 and dims[0] == cfg.num_layers), f"a pool plane is all-gathered: {dims}"
 
 
+def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
+    """The bf16 paged-decode kernel under ``shard_map``: each shard runs the
+    same kernel over ONE of the 4 KV heads (the block's head count comes from
+    the local plane), lengths ragged across a page boundary, lane 2 idle. The
+    step's logits match the unsharded XLA read path's."""
+    import jax.numpy as jnp
+
+    from gofr_tpu.models import llama
+    from gofr_tpu.ops import autotune
+    from gofr_tpu.ops.paged import KVShardCtx, kv_shard_scope, pool_sharding
+    from gofr_tpu.parallel.mesh import build_mesh
+
+    cfg, params, _ = setup
+    mesh = build_mesh(MESH)
+    table = jnp.asarray([[5, 2, 9], [0, 7, 12], [12, 12, 12]], jnp.int32)  # lane 2 idle
+    toks, pos = jnp.asarray([3, 8, 1], jnp.int32), jnp.asarray([16, 7, 0], jnp.int32)
+    fill = jax.random.normal(jax.random.key(5), (2,) + llama.make_paged_cache(cfg, 12, 8).k.shape)
+
+    def cache(**kw):
+        c = llama.make_paged_cache(cfg, 12, 8, **kw)
+        return type(c)(k=c.k + fill[0], v=c.v + fill[1])
+
+    jax.clear_caches()  # decode_step_paged's trace holds the backend it was first made with
+    with autotune.decision_scope({"paged_decode": "xla"}):
+        want, _ = llama.decode_step_paged(cfg, params, toks, pos, cache(), table)
+    from gofr_tpu.ops.pallas import paged_decode as kernels
+
+    seen = []  # (query heads, KV heads) of each kernel trace: a shard's own
+
+    def spy(q, k_pool, *args, _kernel=kernels.paged_decode_attention, **kw):
+        seen.append((q.shape[1], k_pool.shape[2]))
+        return _kernel(q, k_pool, *args, **kw)
+
+    monkeypatch.setattr(kernels, "paged_decode_attention", spy)
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    jax.clear_caches()
+    with kv_shard_scope(KVShardCtx(mesh=mesh, axis="tp", shards=4)), \
+            autotune.decision_scope({"paged_decode": "pallas"}):
+        got, _ = llama.decode_step_paged(
+            cfg, params, toks, pos, cache(sharding=pool_sharding(mesh)), table)
+    jax.clear_caches()
+    assert seen and set(seen) == {(cfg.num_heads // 4, cfg.num_kv_heads // 4)}, seen
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
 # -- spec rounds + preemption + prefix swap-in on the sharded pool -------------
 
 

@@ -22,7 +22,7 @@ import pytest
 
 from gofr_tpu.models import LlamaConfig, llama
 
-pytestmark = pytest.mark.quick  # six compiles of about three seconds; skips where no topology can be described
+pytestmark = pytest.mark.quick  # eight compiles of about three seconds; skips where no topology can be described
 
 SLOTS, PAGE, PAGES_PER_SLOT = 32, 128, 9
 WIDTHS = {  # published widths AND depths: a pool small enough for fast memory is laid out otherwise
@@ -59,10 +59,11 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill", "chunk_prefill"])
-@pytest.mark.parametrize("width", sorted(WIDTHS))
-def test_pool_is_updated_in_place_on_the_v5e(one_chip, no_compile_cache, width, program):
-    from gofr_tpu.ops import pallas
+def _compile(one_chip, width, program, pins=None):
+    """One paged program at ``width`` compiled for the described chip →
+    (compiled, cache shapes). ``pins`` are autotune decisions, as an
+    engine's warm-up would pin them around its traces."""
+    from gofr_tpu.ops import autotune, pallas
 
     cfg = LlamaConfig(**WIDTHS[width])
     pool = SLOTS * PAGES_PER_SLOT
@@ -83,13 +84,50 @@ def test_pool_is_updated_in_place_on_the_v5e(one_chip, no_compile_cache, width, 
         "chunk_prefill": (llama.prefill_paged,
                           (ints(1, 512), ints(1), cache, ints(1, PAGES_PER_SLOT), ints(1))),
     }[program]
-    with pallas.platform_hint("tpu"):
+    jax.clear_caches()  # a trace made under other pins would be served again
+    with pallas.platform_hint("tpu"), autotune.decision_scope(pins):
         compiled = jax.jit(lambda p, *a: fn(cfg, p, *a), donate_argnums=(3,)).lower(
             params, *args).compile()
+    return compiled, cache
 
-    plane = "bf16[%d,%d,%d,%d,%d]" % cache.k.shape
-    copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
-              if re.search(r"= %s\S* copy\(" % re.escape(plane), line)]
+
+def _ops_typed(compiled, kinds, dims):
+    """Lines of the compiled program whose result is bf16[dims] made by one of ``kinds``."""
+    typed = re.escape("bf16[%s]" % ",".join(str(x) for x in dims))
+    return [line.strip()[:160] for line in compiled.as_text().splitlines()
+            if re.search(r"= %s\S* (%s)\(" % (typed, "|".join(kinds)), line)]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk_prefill"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_pool_is_updated_in_place_on_the_v5e(one_chip, no_compile_cache, width, program):
+    compiled, cache = _compile(one_chip, width, program)
+    copies = _ops_typed(compiled, ["copy"], cache.k.shape)
     assert not copies, f"the compiler copies a whole pool plane: {copies}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < cache.k.size * 2, f"temporaries {temp} B: a second pool plane is back"
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_decode_with_the_paged_kernel_gathers_nothing_on_the_v5e(one_chip, no_compile_cache, width):
+    """The decode step with ``paged_decode`` pinned to the kernel, as an
+    engine whose warm-up race it won would trace it: the kernel is in the
+    program, no operation builds a gathered view of the pool — neither
+    ``[N, MaxP, Hkv, page, D]`` as the gather leaves it (also named by its
+    flat form ``[N*MaxP, Hkv, page, D]``) nor ``[N, Hkv, MaxP, page, D]`` as
+    the re-layout did — and no pool plane is copied in front of the kernel.
+    At head_dim 64 the kernel's wrapper pads ONE layer's pages to the lane
+    width a call: two layer-sized temporaries, not a pool."""
+    compiled, cache = _compile(one_chip, width, "decode", pins={"paged_decode": "pallas"})
+    assert "tpu_custom_call" in compiled.as_text(), "the pinned kernel is not in the program"
+    layers, _, hkv, page, d = cache.k.shape
+    for dims in [(SLOTS, PAGES_PER_SLOT, hkv, page, d), (SLOTS * PAGES_PER_SLOT, hkv, page, d),
+                 (SLOTS, hkv, PAGES_PER_SLOT, page, d)]:
+        views = _ops_typed(compiled, ["copy", "fusion"], dims)
+        assert not views, f"a gathered view of the pool is back: {views}"
+    copies = _ops_typed(compiled, ["copy"], cache.k.shape)
+    assert not copies, f"the compiler copies a whole pool plane in front of the kernel: {copies}"
+    padded_layers = 2 * (cache.k.size * 2 // layers) * 128 // d if d % 128 else 0
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < padded_layers + (4 << 20), (
+        f"temporaries {temp} B (the XLA read path's were 76 MB at InternLM2's width)")
