@@ -146,13 +146,9 @@ Env knobs:
                               adaptive: headline elapsed / requests / 2)
     GOFR_BENCH_LATENCY        1 = also measure sequential single-request latency
     GOFR_BENCH_SWEEP          1 = sweep slots x decode_chunk, keep best
-    GOFR_BENCH_PALLAS_AB      1 = record kernel-on/off engine A/B
     GOFR_BENCH_DEBUG          1 = per-phase device-call accounting in extra
     GOFR_TPU_PEAK_TFLOPS      override bf16 peak for MFU (default by device kind)
     GOFR_TPU_PEAK_GBS         override HBM GB/s for MBU (default by device kind)
-    GOFR_AUTOTUNE             0 = disable the warmup kernel autotuner; the
-                              decision table lands in extra.autotune either way
-    GOFR_AUTOTUNE_CACHE       path for autotune decisions (restarts skip re-timing)
 """
 
 from __future__ import annotations
@@ -204,13 +200,6 @@ def _device_peaks(device) -> tuple[float, float]:
             "GOFR_DEVICE_PEAKS) — utilization against an assumed chip is not "
             "reported")
     return peaks
-
-
-def _pallas_active() -> bool:
-    """The single source of truth for whether 'auto' resolves to kernels."""
-    from gofr_tpu.ops.pallas import flash_attention_available
-
-    return flash_attention_available()
 
 
 def _percentile(xs: list[float], p: float) -> float:
@@ -542,24 +531,6 @@ def main() -> None:
         "inputs": dict(lb_inputs, elapsed_s=elapsed, peak_bw=peaks[1]),
         "engine": m.get("perf"),
     }
-    # warmup autotuner decision table (ops/autotune.py): which backend each
-    # decode op pinned for this run's engine, with the measured timings —
-    # the per-PR record ROADMAP O3 asks for. The headline engine is the
-    # last to warm up before this point, so the module-level report is its.
-    from gofr_tpu.ops import autotune as _autotune
-
-    at_rep = _autotune.last_report()
-    extra["autotune"] = at_rep or {"enabled": _autotune.enabled(), "decisions": {}}
-    # kernel status derives from what actually served the run: the autotune
-    # pins when the tuner decided, else the static GOFR_PALLAS gate (the
-    # pre-autotuner posture — see docs/kernels.md for the precedence chain)
-    if at_rep and at_rep.get("decisions"):
-        extra["pallas"] = "autotuned: " + ", ".join(
-            f"{op}->{rec.get('backend')}"
-            for op, rec in sorted(at_rep["decisions"].items()))
-    else:
-        extra["pallas"] = ("on (GOFR_PALLAS static gate)" if _pallas_active()
-                           else "off (static gate; see docs/kernels.md)")
     if kv_layout != "slot":
         extra["kv_layout"] = kv_layout
     if kv_quantize:
@@ -1067,8 +1038,8 @@ def main() -> None:
 
             def factory(name: str) -> GenerateEngine:
                 # the warm-spare contract: weights are already in `params`
-                # and warmup() resolves its attention pins from the shared
-                # GOFR_AUTOTUNE_CACHE, so a mid-trace spawn is near-free
+                # and warmup() finds its programs in the persistent compile
+                # cache, so a mid-trace spawn is near-free
                 eng = GenerateEngine(llama, cfg, params, cont,
                                      **engine_kw(d_slots, best[1]))
                 eng.warmup()
@@ -2065,24 +2036,6 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001
             qual["corrupt_int8"] = f"error: {e}"[:200]
         extra["quality"] = qual
-
-    # kernel A/B on the chip: engine throughput with the Pallas kernels
-    # forced on vs off (fresh engines retrace under the env toggle)
-    if os.environ.get("GOFR_BENCH_PALLAS_AB") == "1" and on_accel:
-        short = prompts[: max(8, n_requests // 8)]
-        ab: dict = {}
-        for mode, env_val in (("xla", "0"), ("pallas", "1")):
-            os.environ["GOFR_PALLAS"] = env_val
-            try:
-                r = _run_once(engine_kw(*best), cfg, params, container, llama,
-                              short, max_new, timeout)
-                ab[mode] = round(len(short) / r["elapsed"], 3)
-            except Exception as e:  # noqa: BLE001
-                ab[mode] = f"error: {e}"[:120]
-        os.environ.pop("GOFR_PALLAS", None)
-        extra["pallas_ab_req_per_s"] = ab
-        if isinstance(ab.get("pallas"), float) and isinstance(ab.get("xla"), float):
-            extra["pallas_speedup"] = round(ab["pallas"] / ab["xla"], 3)
 
     # vs_baseline is only meaningful against the north-star bar (125 req/s/chip
     # for one_b-class generate on TPU); a tiny-model CPU run could "beat"

@@ -239,8 +239,7 @@ async def _serve_pass(build_app, cfg, shape: Shape, layout: str, prompts: list[l
     warmup_s = time.monotonic() - t1
     programs = len(engine._compiled)
     _require(programs > 0, f"{tag}: warmup compiled no programs")
-    report = engine.autotune_report() or {}
-    _require("errors" not in report, f"{tag}: autotune candidates failed: {report.get('errors')}")
+    report = engine.autotune_report()  # what serves the decode op (the rule; a shape the kernel refuses fails the boot above)
 
     base = f"http://127.0.0.1:{app.http_port}"
     metrics_url = f"http://127.0.0.1:{app.metrics_port}/metrics"
@@ -304,7 +303,7 @@ async def _serve_pass(build_app, cfg, shape: Shape, layout: str, prompts: list[l
         "devices": devices, "mesh": mesh or "dp:1", "requests": len(answers) + 3,
         "sse_chunks": chunks, "first_tokens": firsts, "programs": programs,
         "build_s": round(build_s, 1), "warmup_s": round(warmup_s, 1),
-        "autotune": {op: rec["backend"] for op, rec in (report.get("decisions") or {}).items()},
+        "decode_backend": {op: rec["backend"] for op, rec in report["decisions"].items()},
         "bytes_in_use": in_use, "params": n_params,
         "firsts": [a[0] for a in answers], "reference": reference,
     }
@@ -385,6 +384,9 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
     def xla(fn, **kw):
         return jax.jit(functools.partial(fn, **kw))
 
+    def jitted(fn):  # the paged kernels' wrappers carry no jit of their own
+        return jax.jit(functools.partial(fn, interpret=interpret))
+
     def numpy_append(pool_plane, new):
         """Row i at (layer, table[i, pos // page], :, pos % page); an
         unallocated page (the pool-size sentinel) drops the row."""
@@ -407,18 +409,18 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
             lambda: xla(attn.decode_attention, backend="xla")(
                 q, k_cache, v_cache, jnp.full((n,), smax, jnp.int32)), atol),
         "paged_decode_bf16": (
-            lambda: [k_paged.paged_decode_attention(q, k_pool, v_pool, layer, tb, ln,
-                                                    interpret=interpret) for tb, ln in reads],
+            lambda: [jitted(k_paged.paged_decode_attention)(q, k_pool, v_pool, layer, tb, ln)
+                     for tb, ln in reads],
             lambda: [xla(attn.paged_decode_attention, backend="xla")(
                 q, k_pool, v_pool, layer, tb, ln) for tb, ln in reads], atol),
         "paged_decode_int8": (
-            lambda: k_paged.paged_decode_attention_q(q, k8, v8, ks8, vs8, layer, table, full,
-                                                     interpret=interpret),
+            lambda: jitted(k_paged.paged_decode_attention_q)(
+                q, k8, v8, ks8, vs8, layer, table, full),
             lambda: xla(attn.paged_decode_attention_q, backend="xla")(
                 q, k8, v8, ks8, vs8, layer, table, full), atol),
         "paged_decode_int4": (
-            lambda: k_paged.paged_decode_attention_q4(q, k4, v4, ks4, vs4, layer, table, full,
-                                                      interpret=interpret),
+            lambda: jitted(k_paged.paged_decode_attention_q4)(
+                q, k4, v4, ks4, vs4, layer, table, full),
             lambda: xla(attn.paged_decode_attention_q4, backend="xla")(
                 q, k4, v4, ks4, vs4, layer, table, full), atol),
         "slot_append": (

@@ -739,8 +739,8 @@ class App:
     async def _debug_engine_handler(self, request: web.Request) -> web.Response:
         """GET /debug/engine?n=K → the last K device steps (kind, wall time,
         batch occupancy, compile signature, backlog) plus a health snapshot
-        of every served engine, including the warmup autotuner's pinned
-        kernel backend per op with its timings (ops/autotune.py)."""
+        of every served engine, including which backend serves its decode
+        op (``engine.autotune_report()``; docs/kernels.md)."""
         steps = self.container.flight.steps(limit=self._debug_limit(request))
         engines = {}
         for name, engine in self.container.engines.items():
@@ -772,9 +772,9 @@ class App:
     async def _debug_perf_handler(self, request: web.Request) -> web.Response:
         """GET /debug/perf → the live roofline view (metrics/perf.py): per
         engine a windowed MFU/MBU snapshot per step kind, the pipeline
-        bubble ratio, the page-pool waste stats, and every autotune-pinned
-        op joined with the roofline estimate of the step kind it runs in —
-        "is the pinned kernel the bottleneck, or is the device starved?"
+        bubble ratio, the page-pool waste stats, and the engine's decode op
+        joined with the roofline estimate of the step kind it runs in —
+        "is the kernel the bottleneck, or is the device starved?"
         answered from one endpoint (docs/observability.md)."""
         import time as _time
 
@@ -792,9 +792,9 @@ class App:
             report = getattr(engine, "autotune_report", None)
             rep = report() if report is not None else None
             if rep and rep.get("decisions"):
-                # every warmed op today is a decode-step kernel, so each
-                # pin joins the "decode" kind's roofline; spec engines
-                # fold the same pinned op inside "spec" steps too
+                # the reported op is the decode step's, so it joins the
+                # "decode" kind's roofline; spec engines fold the same op
+                # inside "spec" steps too
                 kinds = snap.get("kinds", {})
                 joined = {}
                 for op, rec in rep["decisions"].items():
@@ -844,7 +844,7 @@ class App:
         """GET /debug/quality → the numerics/quality plane joined with the
         serving state that produced it (metrics/quality.py; docs/
         observability.md): per engine the shadow-scorer totals and recent
-        per-sample divergence reports keyed by autotune pins, weights epoch
+        per-sample divergence reports keyed by decode backend, weights epoch
         and kv dtype, the per-adapter speculative-decode acceptance ratios
         (the always-on quality proxy), and each class's quality SLO windows
         — "are the tokens still right, and if not, since when and under
@@ -963,7 +963,7 @@ class App:
             self._start_profiler_server()
 
         # engines first (device warm-up), then servers. ENGINE_WARMUP=true
-        # front-loads every program compile AND the kernel-backend autotune
+        # front-loads every program compile
         # (docs/serving.md: seconds at boot instead of inside the first
         # requests' latency window; generate engines need no example).
         warm = self.config.get_or_default("ENGINE_WARMUP", "false").lower() == "true"

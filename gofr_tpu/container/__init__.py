@@ -154,12 +154,12 @@ class Container:
         m.new_gauge("app_tpu_draining", "1 while the engine is in its scale-in drain")
         m.new_counter("app_tpu_drain_shed_total",
                       "requests shed 503 because they arrived during a drain")
-        # kernel-backend autotuner (ops/autotune.py, docs/kernels.md):
-        # info-style gauge — 1 on the (op, backend) pair the warmup
-        # autotuner pinned for 'auto' resolution, 0 on the loser
+        # which implementation serves an engine's decode op (the rule in
+        # ops/attention.resolve_backend, docs/kernels.md): info-style gauge,
+        # set at warm-up — 1 on the (op, backend) pair that serves, 0 on the other
         m.new_gauge("app_tpu_kernel_backend",
-                    "pinned attention-kernel backend per op (1 = op resolves "
-                    "backend='auto' to this backend; labels: op, backend)")
+                    "attention-kernel backend per decode op (1 = backend='auto' "
+                    "resolves the op to this backend; labels: op, backend, kv_dtype)")
         # data-plane router (gofr_tpu.router, docs/routing.md): the
         # front-end tier's routing/spillover/shed accounting — affinity hit
         # ratio = routed_total{affinity="home"} / requests_total

@@ -15,7 +15,7 @@ pieces:
   band) and proposes bounded single-knob moves for pipeline depth,
   chunked-prefill chunk size, speculative round length and admission
   batch width, judged by measured roofline attainment and ``_dq``
-  bubble ratio, with decisions pinned/persisted like autotune so a
+  bubble ratio, with decisions pinned and persisted so a
   restarted fleet resumes tuned.
 
 The thesis is PAPERS.md 1605.08695 applied at the step level: the

@@ -31,11 +31,11 @@ device loop at the loop-top safe seam):
    proved.
 
 Commits are pinned per (knob, kv dtype, occupancy band, device kind,
-shard) and persisted autotune-style: versioned JSON, read-merge-write of
+shard) and persisted: versioned JSON, read-merge-write of
 our own keys only, atomic replace — a restarted or scaled-out replica
 resumes tuned instead of re-exploring (``CONTROL_CACHE``).
 
-Stand-down: like the autotuner, the controller disables itself where
+Stand-down: the controller disables itself where
 acting would be wrong — an injected ``standdown_fn`` returning a reason
 (the engine wires lockstep roles here: leader-only knob moves would
 desync followers) parks the controller with one recorded decision.
@@ -65,9 +65,8 @@ KNOB_NAMES = ("pipeline_depth", "prefill_chunk", "spec_tokens",
 def entry_key(knob: str, band: str, *, kv_dtype: str, device_kind: str,
               shard: str) -> str:
     """Persisted-pin key: one decision per (knob, kv dtype, occupancy
-    band, device kind, shard) — the same dimensions autotune keys its
-    kernel pins by, because a knob that wins on int4/v5e/tp4 can lose on
-    bf16/cpu/tp1."""
+    band, device kind, shard), because a knob that wins on int4/v5e/tp4
+    can lose on bf16/cpu/tp1."""
     return (f"{knob}|kv={kv_dtype}|band={band}|dev={device_kind}"
             f"|shard={shard}")
 
@@ -254,7 +253,7 @@ class StepController:
             _load_cache(policy.cache_path) if policy.cache_path else {})
         self._last_evidence: dict[str, Any] = {}
 
-    # -- persistence (the autotune read-merge-write discipline) -------------
+    # -- persistence (read-merge-write of our own keys) ----------------------
 
     def _key(self, knob: str, band: str) -> str:
         return entry_key(knob, band, kv_dtype=self.kv_dtype,

@@ -8,8 +8,8 @@ The pieces PRs 7-10 built are composed here into one closed loop:
   the QoS predicted-wait estimator
   (``AdmissionController.max_predicted_wait()``);
 - **scale-out** spawns a warm spare through the driver: weights are
-  pre-loaded by the replica factory and autotune pins are reused from
-  ``GOFR_AUTOTUNE_CACHE``, so warmup is near-free. Gossip admits the
+  pre-loaded by the replica factory and its programs come from the
+  persistent compile cache, so warmup is near-free. Gossip admits the
   spare at a bumped epoch and the PR 7 ring moves only the keys it takes;
 - **scale-in** puts a cooling replica into the ``draining`` registry
   state (router/registry.py: out of BOTH rings, keys migrate to ring
@@ -363,7 +363,7 @@ def requeue(requests, peer) -> int:
 class LocalEngineFleet:
     """In-process replica set: one warmed ``GenerateEngine`` per replica,
     built by ``factory(name)`` (the factory pre-loads weights and warms
-    against the shared ``GOFR_AUTOTUNE_CACHE``, which is what makes the
+    against the persistent compile cache, which is what makes the
     spare *warm*). Membership transitions are mirrored into an optional
     ``ReplicaRegistry`` with the SAME observe() messages gossip would
     carry — UP at a bumped epoch on spawn, ``draining`` during scale-in,
@@ -414,7 +414,7 @@ class LocalEngineFleet:
         with self._lock:
             name = f"{self.name_prefix}{self._counter}"
             self._counter += 1
-        eng = self.factory(name)  # warm: weights + autotune pins pre-loaded
+        eng = self.factory(name)  # warm: weights pre-loaded
         with self._lock:
             self.replicas[name] = eng
             self._epoch += 1  # gossip admits the spare at a bumped epoch

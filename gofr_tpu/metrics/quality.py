@@ -3,7 +3,7 @@ golden reference configuration (docs/observability.md "Quality plane").
 
 The serving stack answers *how fast* everywhere (tracing, SLO, perf
 rooflines) but nothing answers *is the math still right*: int8/int4 KV
-with fused dequant, autotuner-pinned kernels, LoRA deltas and live weight
+with fused dequant, Pallas kernels, LoRA deltas and live weight
 hot-swap all produce plausible-looking tokens when they drift. This module
 closes that gap with a teacher-forced shadow scorer:
 

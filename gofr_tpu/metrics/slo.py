@@ -556,7 +556,7 @@ class CaptureWatcher:
             # quality-plane enrichment (metrics/quality.py): per-sample
             # replay payloads (prompt ids, emitted tokens, divergence
             # report) joined with the sampler seed, adapter digest, weights
-            # epoch, kv dtype, autotune pins, and config fingerprint — the
+            # epoch, kv dtype, decode backend, and config fingerprint — the
             # complete deterministic input set scripts/replay_bundle.py
             # needs to re-execute the divergence offline
             try:

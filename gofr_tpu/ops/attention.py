@@ -22,29 +22,30 @@ from gofr_tpu.tracing import scoped
 NEG_INF = -1e30
 
 
+# The ops whose kernel serves ``backend="auto"`` on a TPU: each entry names
+# the chip measurement that put it there. Every other op stays on XLA there
+# (docs/kernels.md has the timings on record for those).
+KERNEL_OPS = (
+    "paged_decode",  # bf16 pool: 1.04 against XLA's 1.74 ms a layer at full lengths, 2.5x / 1.7x tokens/s (PERF.md §6, PR 30)
+)
+
+
 def resolve_backend(backend: str, op: str | None = None) -> str:
-    """'auto' resolves, in precedence order (docs/kernels.md): an explicit
-    GOFR_PALLAS env value (0/1 — the operator override), then a pinned
-    warmup-autotune decision for ``op`` (ops.autotune.decision_scope;
-    engines pin measured winners for the decode ops around every trace
-    they drive), then the static default (XLA on hardware, Pallas
-    under the interpreter — ops/pallas/__init__.flash_attention_available);
-    'auto' picks XLA wherever no kernel can lower, so one model code path
+    """Which implementation serves ``op``: an explicit ``backend`` argument
+    first, then the rule. 'auto' is the kernel where it has won its
+    measurement on the chip (``KERNEL_OPS``) and the traced computation
+    targets a TPU (``ops.pallas.kernel_platform``, which honours
+    ``platform_hint``); under the interpreter (GOFR_PALLAS_INTERPRET=1, the
+    CPU tests) every op's kernel; otherwise XLA — so one model code path
     serves the CPU test mesh and real chips. An explicit 'pallas' is a
     request for the kernel by name: where it cannot lower it RAISES
     (ops.pallas.require_kernel_platform) rather than running XLA."""
     if backend == "auto":
-        import os
+        from gofr_tpu.ops.pallas import interpret_mode, kernel_platform
 
-        from gofr_tpu.ops.pallas import flash_attention_available, kernel_platform
-
-        if os.environ.get("GOFR_PALLAS", "") not in ("0", "1"):
-            from gofr_tpu.ops.autotune import pinned_backend
-
-            pinned = pinned_backend(op)
-            if pinned is not None:
-                return "pallas" if pinned == "pallas" and kernel_platform() else "xla"
-        return "pallas" if flash_attention_available() else "xla"
+        if interpret_mode() or (op in KERNEL_OPS and kernel_platform()):
+            return "pallas"
+        return "xla"
     if backend == "pallas":
         from gofr_tpu.ops.pallas import require_kernel_platform
 
@@ -303,6 +304,18 @@ def _shard_paged_call(impl, ctx, q, pools, layer, table, lengths):
     )(q, *pools, jnp.asarray(layer, jnp.int32), table, lengths)
 
 
+def _require_kernel_page(pool: jnp.ndarray) -> None:
+    """The paged kernels tile a page on the f32 sublane: a pool they cannot
+    read is an error where the program is traced (warm-up), never a quiet
+    XLA run."""
+    page = pool.shape[3]
+    if page % 8:
+        raise ValueError(
+            f"the paged-decode kernel needs page_size % 8 == 0 (f32 sublane "
+            f"tile); this pool has pages of {page} (plane {tuple(pool.shape)}): "
+            f"use a page_size that is a multiple of 8, or backend='xla'")
+
+
 @scoped("attention")
 def paged_decode_attention_q(
     q: jnp.ndarray,        # [N, Hq, D]
@@ -349,26 +362,18 @@ def _paged_decode_attention_q_local(
     no materialized logical view, HBM traffic stays int8. 'xla' gathers
     the int8 logical views + scales per slot (one extra HBM round trip for
     the copy) and reuses the folded-scale dense decode path — correct
-    everywhere. 'auto' follows resolve_backend (autotune pin aware)."""
-    page = kq_pool.shape[3]
+    everywhere. 'auto' follows resolve_backend."""
     if resolve_backend(backend, op="paged_decode_q") == "pallas":
-        if page % 8 == 0:
-            from gofr_tpu.ops.pallas import interpret_mode
-            from gofr_tpu.ops.pallas.paged_decode import (
-                paged_decode_attention_q as pallas_paged_q,
-            )
+        from gofr_tpu.ops.pallas import interpret_mode
+        from gofr_tpu.ops.pallas.paged_decode import (
+            paged_decode_attention_q as pallas_paged_q,
+        )
 
-            return pallas_paged_q(
-                q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
-                scale=scale, interpret=interpret_mode(),
-            )
-        if backend == "pallas":
-            # explicit requests never degrade silently (ADVICE.md round 2)
-            raise ValueError(
-                f"backend='pallas' requested but page size {page} is not a "
-                f"multiple of 8 (f32 sublane tile); use a page_size % 8 == 0 "
-                f"or backend='auto'"
-            )
+        _require_kernel_page(kq_pool)
+        return pallas_paged_q(
+            q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
+            scale=scale, interpret=interpret_mode(),
+        )
     from gofr_tpu.ops.paged import gather_kv_q
 
     gkq, gks = gather_kv_q(kq_pool, ks_pool, layer, table)
@@ -424,28 +429,18 @@ def _paged_decode_attention_q4_local(
     kernel's. 'xla' gathers the packed views, unpacks after the gather
     (ops.paged.gather_kv_q4), and reuses the folded-scale dense decode
     path — correct everywhere, the parity reference for the kernel.
-    'auto' follows resolve_backend (autotune pin aware, op key
-    'paged_decode_q4' — tuned separately from int8 because the winner
-    shifts with the unpack cost on each device generation)."""
-    page = kq_pool.shape[3]
+    'auto' follows resolve_backend (op key 'paged_decode_q4')."""
     if resolve_backend(backend, op="paged_decode_q4") == "pallas":
-        if page % 8 == 0:
-            from gofr_tpu.ops.pallas import interpret_mode
-            from gofr_tpu.ops.pallas.paged_decode import (
-                paged_decode_attention_q4 as pallas_paged_q4,
-            )
+        from gofr_tpu.ops.pallas import interpret_mode
+        from gofr_tpu.ops.pallas.paged_decode import (
+            paged_decode_attention_q4 as pallas_paged_q4,
+        )
 
-            return pallas_paged_q4(
-                q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
-                scale=scale, interpret=interpret_mode(),
-            )
-        if backend == "pallas":
-            # explicit requests never degrade silently (ADVICE.md round 2)
-            raise ValueError(
-                f"backend='pallas' requested but page size {page} is not a "
-                f"multiple of 8 (f32 sublane tile); use a page_size % 8 == 0 "
-                f"or backend='auto'"
-            )
+        _require_kernel_page(kq_pool)
+        return pallas_paged_q4(
+            q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
+            scale=scale, interpret=interpret_mode(),
+        )
     from gofr_tpu.ops.paged import gather_kv_q4
 
     gkq, gks = gather_kv_q4(kq_pool, ks_pool, layer, table)
@@ -497,24 +492,15 @@ def _paged_decode_attention_local(
     logical view with one gather (ops.paged.gather_kv) and reuses the dense
     decode path — correct everywhere, but pays an extra HBM round trip.
     """
-    page = k_pool.shape[3]
     if resolve_backend(backend, op="paged_decode") == "pallas":
-        if page % 8 == 0:
-            from gofr_tpu.ops.pallas import interpret_mode
-            from gofr_tpu.ops.pallas.paged_decode import paged_decode_attention as pallas_paged
+        from gofr_tpu.ops.pallas import interpret_mode
+        from gofr_tpu.ops.pallas.paged_decode import paged_decode_attention as pallas_paged
 
-            return pallas_paged(
-                q, k_pool, v_pool, layer, table, lengths,
-                scale=scale, interpret=interpret_mode(),
-            )
-        if backend == "pallas":
-            # Only 'auto' may degrade silently — an explicit request the
-            # kernel cannot satisfy must not be ignored (ADVICE.md round 2).
-            raise ValueError(
-                f"backend='pallas' requested but page size {page} is not a "
-                f"multiple of 8 (f32 sublane tile); use a page_size % 8 == 0 "
-                f"or backend='auto'"
-            )
+        _require_kernel_page(k_pool)
+        return pallas_paged(
+            q, k_pool, v_pool, layer, table, lengths,
+            scale=scale, interpret=interpret_mode(),
+        )
     from gofr_tpu.ops.paged import gather_kv
 
     k_view, v_view = gather_kv(k_pool, v_pool, layer, table)

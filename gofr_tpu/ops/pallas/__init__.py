@@ -4,13 +4,11 @@ Kernels target the TPU memory hierarchy (HBM→VMEM blocks, MXU-sized
 tiles). On CPU they run only under the Pallas interpreter — set
 ``GOFR_PALLAS_INTERPRET=1``, as tests/test_pallas.py does for its parity
 cases (the rest of the suite runs the XLA path). ``backend='auto'``
-callers go through ``flash_attention_available()`` and resolve to XLA
-where no kernel can lower, so the same model code runs on the test mesh
-and real chips; an EXPLICIT request for a kernel that cannot be honoured
-raises (:func:`require_kernel_platform`) instead of running something else.
-
-``GOFR_PALLAS=0`` force-disables the kernels even on TPU (escape hatch /
-A-B benchmarking).
+callers go through ``ops.attention.resolve_backend`` — the one rule that
+says which op's kernel serves where — and resolve to XLA where no kernel
+can lower, so the same model code runs on the test mesh and real chips; an
+EXPLICIT request for a kernel that cannot be honoured raises
+(:func:`require_kernel_platform`) instead of running something else.
 """
 
 from __future__ import annotations
@@ -68,28 +66,7 @@ def require_kernel_platform(what: str) -> None:
             f"GOFR_PALLAS_INTERPRET=1 to run them under the interpreter)")
 
 
-def flash_attention_available() -> bool:
-    """Should ``backend='auto'`` pick the hand-written kernels, absent a
-    per-op autotune decision?
-
-    This is the LAST stop in resolve_backend's precedence chain
-    (ops/attention.py): the decode ops prefer a warmup-autotune pin
-    (ops/autotune.py — measured per (op, shape, kv dtype, device_kind) on
-    the engine's real serving shapes) whenever one is in scope, and
-    GOFR_PALLAS, when explicitly set, overrides both. The static default
-    here is XLA on hardware: a kernel serves where it wins the warm-up
-    race on the engine's own shapes (the bf16 paged-decode kernel does, at
-    both benchmark widths; PERF.md), none by default. Interpreter tests still exercise
-    the kernels (GOFR_PALLAS_INTERPRET=1), and an explicit
-    ``backend='pallas'`` bypasses this gate entirely."""
-    if os.environ.get("GOFR_PALLAS", "") == "0":
-        return False
-    if interpret_mode():
-        return True
-    return os.environ.get("GOFR_PALLAS", "") == "1" and kernel_platform()
-
-
 __all__ = [
-    "flash_attention_available", "interpret_mode", "kernel_platform",
-    "platform_hint", "require_kernel_platform",
+    "interpret_mode", "kernel_platform", "platform_hint",
+    "require_kernel_platform",
 ]

@@ -10,7 +10,7 @@ never a gather-materialized copy of the logical view (that copy is the XLA
 fallback, ops.paged.gather_kv).
 
 ``paged_decode_attention`` (bf16 pool; what the served decode program runs
-where it wins the warm-up race) — grid (slot,), one lane a step, the pool
+on a TPU, ops/attention.resolve_backend) — grid (slot,), one lane a step, the pool
 planes left in HBM. A lane's pages are a loop over ceil(len / page), each
 page ONE copy a plane of [Hkv, page, D] — a whole page of every KV head,
 which the pool's layout makes one contiguous run — into one of two VMEM
@@ -43,6 +43,11 @@ packed bytes through the identical scalar-prefetched block tables, and the
 nibble unpack + dequant happen in-register — HBM reads per KV token halve
 again vs int8. The scale folds are byte-for-byte the int8 kernel's: ks on
 the scores after the QK matmul, vs inside the online-softmax recurrence.
+
+None of the three wrappers carries a ``jax.jit`` of its own: each is traced
+inside the program that calls it, so its operations are named (and located)
+by that program alone, whatever else traced the kernel first in the process —
+the persistent compile cache keys on those names (tpu/device.py).
 """
 
 from __future__ import annotations
@@ -161,7 +166,6 @@ def _paged_decode_kernel(
     softmax_finish(0, 1, acc_ref, l_ref, write)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,        # [N, Hq, D]
     k_pool: jnp.ndarray,   # [L, P, Hkv, page, D]
@@ -187,7 +191,7 @@ def paged_decode_attention(
         # width out of the plane. Such a pool's layer is padded to it first:
         # the zeros add nothing to a score and their output columns are
         # dropped. That is a copy of one layer a call, not the read in
-        # place; the warm-up race prices it.
+        # place (it still beat XLA at Llama-1B's shape, docs/kernels.md).
         def widen(x):
             return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -d % _LANES)])
 
@@ -286,7 +290,6 @@ def _paged_decode_q_kernel(
     softmax_finish(pi, n_pages, acc_ref, l_ref, write)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_q(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # int8 [L, P, Hkv, page, D]
@@ -415,7 +418,6 @@ def _paged_decode_q4_kernel(
     softmax_finish(pi, n_pages, acc_ref, l_ref, write)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_q4(
     q: jnp.ndarray,        # [N, Hq, D]
     kq_pool: jnp.ndarray,  # uint8 [L, P, Hkv, page, D//2] packed nibbles

@@ -46,7 +46,6 @@ import itertools
 import math
 import os
 import queue
-from functools import partial
 import threading
 import time
 from typing import Any, Callable, Iterator
@@ -311,24 +310,16 @@ class _EngineBase:
 
     def _trace_scope(self):
         """Context every trace-driving section runs under: engines with a
-        tp-sharded pool pin its KVShardCtx (ops/paged.kv_shard_scope), and
-        generate engines pin the decode attention backends their warmup
-        autotuner measured (ops/autotune.decision_scope) — so every trace
-        this engine drives resolves 'auto' the same way."""
+        tp-sharded pool pin its KVShardCtx (ops/paged.kv_shard_scope), so
+        every trace this engine drives reads the pool per shard."""
         import contextlib
 
-        stack = contextlib.ExitStack()
         ctx = self._kv_shard_ctx() if hasattr(self, "_kv_shard_ctx") else None
-        if ctx is not None:
-            from gofr_tpu.ops.paged import kv_shard_scope
+        if ctx is None:
+            return contextlib.nullcontext()
+        from gofr_tpu.ops.paged import kv_shard_scope
 
-            stack.enter_context(kv_shard_scope(ctx))
-        pins = getattr(self, "_autotune_pins", None)
-        if pins:
-            from gofr_tpu.ops import autotune
-
-            stack.enter_context(autotune.decision_scope(pins))
-        return stack
+        return kv_shard_scope(ctx)
 
     def _run(self) -> None:
         from gofr_tpu.ops.pallas import platform_hint
@@ -1405,14 +1396,6 @@ class GenerateEngine(_EngineBase):
         # packing runs on the device thread; the population is bounded
         # like _compiled (bucket ladder).
         self._staging_bufs: dict[tuple, tuple] = {}
-        # Warmup-time kernel-backend autotuner (ops/autotune.py; ROADMAP O3):
-        # {op: backend} pins consulted by every trace via _trace_scope, the
-        # report served at /debug/engine, and an injectable timer for
-        # CPU-safe unit tests. Empty until warmup() measures (or loads the
-        # GOFR_AUTOTUNE_CACHE entry for this exact shape/device).
-        self._autotune_pins: dict[str, str] = {}
-        self._autotune: dict | None = None
-        self._autotune_timer = None
         self._pending: list[tuple[Request, np.ndarray]] = []
         # builds the C++ planner now (not inside the first admission) and
         # says which one serves; a failed build was already logged loudly
@@ -1535,7 +1518,7 @@ class GenerateEngine(_EngineBase):
                 seed=(self._seed if quality_seed is None
                       or int(quality_seed) < 0 else int(quality_seed)),
                 kv_dtype=self.kv_quantize or "bf16",
-                backend_fn=self._quality_backend,
+                backend_fn=self._decode_backend,
                 adapter_fn=_adapter_factors,
                 max_pending=quality_max_pending,
                 max_tokens=quality_max_tokens,
@@ -1684,12 +1667,16 @@ class GenerateEngine(_EngineBase):
         # traces on the caller thread could resolve kernels for the wrong
         # backend (e.g. Pallas for a CPU test mesh under an attached TPU),
         # and jit would cache that mis-resolved program per shape
-        with platform_hint(getattr(self.tpu, "platform", None)):
-            # backend autotune runs BEFORE the programs trace: the pins it
-            # produces are what _trace_scope makes the traces below see
-            self._autotune_backends()
-            with self._trace_scope():
-                return self._warmup_traced(lbs, bbs)
+        with platform_hint(getattr(self.tpu, "platform", None)), self._trace_scope():
+            op, serves = self._decode_op(), self._decode_backend()
+            for b in ("pallas", "xla"):
+                # info-style gauge: 1 on the backend the rule resolves this
+                # engine's decode op to, 0 on the other
+                self.metrics.set_gauge(
+                    "app_tpu_kernel_backend", 1.0 if b == serves else 0.0,
+                    op=op, backend=b, kv_dtype=self.kv_quantize or "bf16")
+            self.logger.infof("decode op %s -> %s (rule)", op, serves)
+            return self._warmup_traced(lbs, bbs)
 
     def _warmup_traced(self, lbs: list[int], bbs: list[int]) -> int:
         # the compile body lives in the executor layer (tpu/executor.py,
@@ -1698,143 +1685,27 @@ class GenerateEngine(_EngineBase):
         # batched-prefill ladder — most of a role spare's warmup win
         return executor.warmup_compile(self, lbs, bbs)
 
-    def _autotune_backends(self) -> None:
-        """Measure Pallas vs XLA for this engine's decode attention op on
-        its REAL serving shapes and pin the winner for every trace
-        (ops/autotune.py; ROADMAP O3). Replaces the static GOFR_PALLAS
-        gate with a per-(op, shape, kv dtype, device_kind) decision, cached
-        across restarts via GOFR_AUTOTUNE_CACHE. Stands down when the
-        autotuner is disabled (GOFR_AUTOTUNE=0 / explicit GOFR_PALLAS /
-        interpreter mode) and under lockstep — a leader-only pin would make
-        leader and follower trace DIFFERENT decode programs, and the
-        announce protocol has no way to reproduce a timing on the
-        follower's behalf."""
-        from gofr_tpu.ops import autotune
+    def _decode_op(self) -> str:
+        """The decode attention op this engine's decode program traces
+        (ops/attention.resolve_backend's ``op`` key; the slot int8 read,
+        ``decode_q``, has no kernel)."""
+        quant = {"int8": "_q", "int4": "_q4"}.get(self.kv_quantize or "", "")
+        return ("paged_decode" if self.kv_layout == "paged" else "decode") + quant
 
-        if self.lockstep_role or self._autotune_pins or not autotune.enabled():
-            return
-        if self.role == "prefill":
-            # every op the tuner races is decode attention; a prefill-role
-            # worker never traces one. Pins stay role-scoped regardless via
-            # autotune.entry_key(..., role), so a colocated engine's cache
-            # entries are untouched either way.
-            self._autotune = {"skipped": "prefill role: no decode ops to tune"}
-            return
-        from gofr_tpu.ops import attention as attn_ops
-        from gofr_tpu.ops.pallas import kernel_platform
+    def _decode_backend(self) -> str:
+        """What serves that op, read from the rule for this engine's platform."""
+        from gofr_tpu.ops.attention import resolve_backend
+        from gofr_tpu.ops.pallas import platform_hint
 
-        cfg = self.cfg
-        hq = getattr(cfg, "num_heads", 0)
-        hkv = getattr(cfg, "num_kv_heads", hq)
-        d = getattr(cfg, "head_size", None) or getattr(cfg, "head_dim", 0)
-        if not (hq and hkv and d):  # family exposes no GQA geometry
-            return
-        qdtype = getattr(cfg, "dtype", jnp.bfloat16)
-        devices = getattr(self.tpu, "devices", None)
-        kind = (getattr(devices[0], "device_kind", None) if devices
-                else None) or getattr(self.tpu, "platform", "cpu")
-        tuner = autotune.Autotuner(
-            device_kind=str(kind), cache_file=autotune.cache_path(),
-            timer=self._autotune_timer, logger=self.logger, role=self.role,
-            sharding=(f"tp{self.kv_shards}"
-                      if getattr(self, "kv_shards", 1) > 1 else ""))
-        pallas_ok = kernel_platform()
-        t0 = time.monotonic()
-        n = self.num_slots
+        with platform_hint(getattr(self.tpu, "platform", None)):
+            return resolve_backend("auto", self._decode_op())
 
-        if self.kv_layout == "paged":
-            # Candidate inputs are the engine's own pool planes, read at
-            # layer 0 the way a serving step reads them (right per-shard
-            # shape AND dtype, no second pool in HBM), with a full-occupancy
-            # block table and full lengths — the worst-case stream each
-            # serving decode step pays.
-            maxp, page = self.pages_per_slot, self.page_size
-            pool = self.total_pages
-            rng = np.random.RandomState(0)
-            table = jnp.asarray(
-                rng.permutation(n * maxp)[: n * maxp] % max(pool, 1),
-                jnp.int32).reshape(n, maxp)
-            lengths = jnp.full((n,), maxp * page, jnp.int32)
-            q = jnp.asarray(rng.standard_normal((n, hq, d)), qdtype)
-            skey = autotune.shape_key(n, hq, hkv, d, page, maxp, pool)
-            kv = self.kv_cache  # spec mode wraps the pool in (kv, hist)
-            if self.kv_quantize == "int4":
-                op, op_fn, kv_dtype = "paged_decode_q4", attn_ops.paged_decode_attention_q4, "int4"
-            elif self.kv_quantize:
-                op, op_fn, kv_dtype = "paged_decode_q", attn_ops.paged_decode_attention_q, "int8"
-            else:
-                op, op_fn, kv_dtype = "paged_decode", attn_ops.paged_decode_attention, str(kv.k.dtype)
-            planes = (kv.k, kv.v, kv.ks, kv.vs) if self.kv_quantize else (kv.k, kv.v)
-            args = (q, *planes, jnp.zeros((), jnp.int32), table, lengths)
-            cands = {"xla": self._at_fn(op_fn, "xla", *args)}
-            if pallas_ok and page % 8 == 0:
-                cands["pallas"] = self._at_fn(op_fn, "pallas", *args)
-            tuner.measure(op, skey, kv_dtype, cands)
-        elif not self.kv_quantize:
-            # slot layout, dense cache (the int8 slot path has no kernel
-            # variant to race). With spec on the cache is (kv, aux).
-            kv = self.cache[0] if isinstance(self.cache, tuple) else self.cache
-            kc, vc = kv.k[0], kv.v[0]
-            smax = kc.shape[2]
-            rng = np.random.RandomState(0)
-            q = jnp.asarray(rng.standard_normal((n, hq, d)), qdtype)
-            lengths = jnp.full((n,), smax, jnp.int32)
-            cands = {"xla": self._at_fn(
-                attn_ops.decode_attention, "xla", q, kc, vc, lengths)}
-            if pallas_ok and attn_ops.slot_decode_kernel_ok(smax):
-                cands["pallas"] = self._at_fn(
-                    attn_ops.decode_attention, "pallas", q, kc, vc, lengths)
-            tuner.measure("decode", autotune.shape_key(n, hq, hkv, d, smax),
-                          str(kc.dtype), cands)
-
-        self._autotune_pins = tuner.pins()
-        self._autotune = {"elapsed_s": round(time.monotonic() - t0, 3),
-                          **tuner.report()}
-        autotune.set_last_report(self._autotune)
-        for op, rec in tuner.decisions.items():
-            # info-style gauge: 1 on the pinned (op, backend) pair, 0 on
-            # the loser so a re-tune never leaves both labels asserted.
-            # kv_dtype rides as a label so a kv-dtype A/B (bf16/int8/int4
-            # arms pin DIFFERENT ops) stays distinguishable in one scrape.
-            for b in ("pallas", "xla"):
-                self.metrics.set_gauge(
-                    "app_tpu_kernel_backend",
-                    1.0 if b == rec["backend"] else 0.0, op=op, backend=b,
-                    kv_dtype=str(rec.get("kv_dtype", "")))
-            self.logger.infof(
-                "autotune: %s -> %s (%s, shapes %s, %s)", op, rec["backend"],
-                rec["source"], rec["shape"], rec.get("timings_ms") or "untimed")
-
-    def _at_fn(self, op_fn, backend: str, *arrays):
-        """A timed autotune candidate: the op jitted over REAL device-shaped
-        array arguments (arguments, not closure constants — XLA must not
-        fold the benchmark away) with the backend bound explicitly. On a
-        tp-sharded pool the candidate traces under the engine's KVShardCtx
-        so the timing races the per-shard program the serving traces will
-        actually run — that is what the sharding-scoped cache key pins."""
-        jf = jax.jit(partial(op_fn, backend=backend))
-        ctx = self._kv_shard_ctx()
-        if ctx is None:
-            return lambda: jf(*arrays)
-        from gofr_tpu.ops.paged import kv_shard_scope
-
-        def run():
-            with kv_shard_scope(ctx):
-                return jf(*arrays)
-
-        return run
-
-    def autotune_report(self) -> dict | None:
-        """The warmup autotuner's decision table (None until warmup ran or
-        when autotune is disabled) — surfaced at /debug/engine and recorded
-        in the bench JSON."""
-        return self._autotune
-
-    def _quality_backend(self) -> str:
-        """Backend label for quality telemetry: the distinct autotune-pinned
-        kernel backends serving this engine ("xla" before warmup pins)."""
-        pins = self._autotune_pins
-        return "+".join(sorted(set(pins.values()))) if pins else "xla"
+    def autotune_report(self) -> dict:
+        """Which backend serves this engine's decode op, in the shape
+        benchmarks/run.py, chip_smoke.py and /debug/engine read (the name is
+        theirs; nothing is tuned — ops/attention.resolve_backend decides)."""
+        return {"decisions": {self._decode_op(): {
+            "backend": self._decode_backend(), "source": "rule"}}}
 
     def spec_accept_totals(self) -> dict[str, tuple[float, float]]:
         """Lifetime per-adapter (accepted, proposed) speculative-decode
@@ -1846,14 +1717,13 @@ class GenerateEngine(_EngineBase):
     def quality_snapshot(self) -> dict | None:
         """The /debug/quality + capture-bundle join: plane totals and recent
         divergence reports, keyed by the serving state that produced them —
-        autotune pins, weights epoch, kv dtype — plus the replay config
+        decode backend, weights epoch, kv dtype — plus the replay config
         scripts/replay_bundle.py needs to re-execute samples offline."""
         if self._quality is None:
             return None
         snap = self._quality.snapshot()
-        snap["autotune_pins"] = dict(self._autotune_pins)
         snap["weights_epoch"] = self.weights_epoch
-        snap["backend"] = self._quality_backend()
+        snap["backend"] = self._decode_backend()
         snap["replay"] = self.replay_config()
         return snap
 

@@ -55,10 +55,9 @@ byte-identical to the above.
 
 Backend resolution is a TRACE-time property of these programs: the decode
 attention ops inside them resolve ``backend="auto"`` when a program first
-traces (warmup), consulting the engine's pinned autotune decisions
-(ops/autotune.decision_scope, entered via ``engine._trace_scope``). A
-compiled program keeps whatever backend its trace resolved for its whole
-life — re-tuning means a new process, same as the KV write lowerings.
+traces (warmup), by the one rule in ``ops/attention.resolve_backend``
+(platform and op). A compiled program keeps what its trace resolved for
+its whole life, same as the KV write lowerings.
 """
 
 from __future__ import annotations

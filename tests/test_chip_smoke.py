@@ -60,7 +60,7 @@ def test_script_entry_refuses_a_non_tpu_platform():
 
 
 # -- the loud paths --------------------------------------------------------------
-# (an autotune candidate that raises: tests/test_autotune.py)
+# (a pool the kernel cannot read: tests/test_kernel_backend.py)
 
 
 @pytest.mark.quick

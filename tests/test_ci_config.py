@@ -120,22 +120,23 @@ def test_quick_tier_marker_coverage():
     assert len(marked) >= 5, f"quick tier shrank to {marked}"
 
 
-def test_kernel_autotune_suite_is_in_quick_tier():
+def test_kernel_backend_suite_is_in_quick_tier():
     """ISSUE 6 satellite: the fused int8 paged-decode parity tests and the
-    autotuner units (tests/test_autotune.py) must ride the `-m quick` CI
-    job on every push — interpreter-mode parity and fake-timer units are
-    CPU-safe by construction, so exemption would be a coverage hole."""
-    path = REPO / "tests" / "test_autotune.py"
-    assert path.exists(), "tests/test_autotune.py missing"
+    tests of the backend rule (tests/test_kernel_backend.py) must ride the
+    `-m quick` CI job on every push — interpreter-mode parity and a rule on
+    platform and op are CPU-safe by construction, so exemption would be a
+    coverage hole."""
+    path = REPO / "tests" / "test_kernel_backend.py"
+    assert path.exists(), "tests/test_kernel_backend.py missing"
     text = path.read_text()
     assert "pytestmark = pytest.mark.quick" in text, (
-        "test_autotune.py must be quick-marked module-wide"
+        "test_kernel_backend.py must be quick-marked module-wide"
     )
-    assert "test_autotune.py" not in QUICK_EXEMPT, (
-        "test_autotune.py must not be exempted from the quick tier"
+    assert "test_kernel_backend.py" not in QUICK_EXEMPT, (
+        "test_kernel_backend.py must not be exempted from the quick tier"
     )
-    # the two halves of ISSUE 6 are both present: kernel parity + autotuner
-    assert "paged_decode_q" in text and "Autotuner" in text
+    # both halves are present: kernel parity + the rule
+    assert "paged_decode_q" in text and "resolve_backend" in text
 
 
 def test_router_suite_is_in_quick_tier():

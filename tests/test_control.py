@@ -11,7 +11,7 @@ Three layers, cheapest first:
 - **controller units** — StepController with injected windows/clock/
   knobs: the try→judge→commit trial loop, worsening-move revert with
   doubling backoff, a→b→a oscillation freeze, lockstep stand-down,
-  evidence starvation accumulating across ticks, and the autotune-style
+  evidence starvation accumulating across ticks, and the
   pin persistence (versioned JSON, corrupt file tolerance, read-merge-
   write preserving foreign keys, resume-from-pin on restart).
 - **engine seams** — the live-knob contract on a real (tiny, CPU)

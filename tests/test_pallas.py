@@ -84,8 +84,9 @@ def test_auto_backend_dispatches_interpret(monkeypatch):
 
 
 def test_llama_forward_with_pallas_backend(monkeypatch):
-    """Whole-model parity: tiny Llama forward, XLA vs Pallas-interpret."""
-    monkeypatch.setenv("GOFR_PALLAS", "0")
+    """Whole-model parity: tiny Llama forward, XLA vs Pallas-interpret
+    (GOFR_PALLAS_INTERPRET alone switches sides: unset, 'auto' is XLA on a CPU)."""
+    monkeypatch.delenv("GOFR_PALLAS_INTERPRET", raising=False)
     from gofr_tpu.models import llama
 
     cfg = llama.LlamaConfig.tiny()
@@ -94,7 +95,6 @@ def test_llama_forward_with_pallas_backend(monkeypatch):
     lengths = jnp.array([32, 20], jnp.int32)
     want = llama.forward(cfg, params, tokens, lengths)
 
-    monkeypatch.setenv("GOFR_PALLAS", "1")
     monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
     jax.clear_caches()  # backend resolution happens at trace time
     got = llama.forward(cfg, params, tokens, lengths)

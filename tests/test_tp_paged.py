@@ -171,7 +171,6 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
     import jax.numpy as jnp
 
     from gofr_tpu.models import llama
-    from gofr_tpu.ops import autotune
     from gofr_tpu.ops.paged import KVShardCtx, kv_shard_scope, pool_sharding
     from gofr_tpu.parallel.mesh import build_mesh
 
@@ -186,8 +185,8 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
         return type(c)(k=c.k + fill[0], v=c.v + fill[1])
 
     jax.clear_caches()  # decode_step_paged's trace holds the backend it was first made with
-    with autotune.decision_scope({"paged_decode": "xla"}):
-        want, _ = llama.decode_step_paged(cfg, params, toks, pos, cache(), table)
+    monkeypatch.delenv("GOFR_PALLAS_INTERPRET", raising=False)  # on the CPU 'auto' is the XLA read path
+    want, _ = llama.decode_step_paged(cfg, params, toks, pos, cache(), table)
     from gofr_tpu.ops.pallas import paged_decode as kernels
 
     seen = []  # (query heads, KV heads) of each kernel trace: a shard's own
@@ -199,8 +198,7 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
     monkeypatch.setattr(kernels, "paged_decode_attention", spy)
     monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
     jax.clear_caches()
-    with kv_shard_scope(KVShardCtx(mesh=mesh, axis="tp", shards=4)), \
-            autotune.decision_scope({"paged_decode": "pallas"}):
+    with kv_shard_scope(KVShardCtx(mesh=mesh, axis="tp", shards=4)):  # interpreter: 'auto' is the kernel
         got, _ = llama.decode_step_paged(
             cfg, params, toks, pos, cache(sharding=pool_sharding(mesh)), table)
     jax.clear_caches()

@@ -59,11 +59,12 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(one_chip, width, program, pins=None):
-    """One paged program at ``width`` compiled for the described chip →
-    (compiled, cache shapes). ``pins`` are autotune decisions, as an
-    engine's warm-up would pin them around its traces."""
-    from gofr_tpu.ops import autotune, pallas
+def _compile(one_chip, width, program):
+    """One paged program at ``width`` compiled for the described chip, its
+    read path resolved as an engine on a TPU resolves it (the rule in
+    ops/attention.resolve_backend under ``platform_hint("tpu")``) →
+    (compiled, cache shapes)."""
+    from gofr_tpu.ops import pallas
 
     cfg = LlamaConfig(**WIDTHS[width])
     pool = SLOTS * PAGES_PER_SLOT
@@ -84,8 +85,8 @@ def _compile(one_chip, width, program, pins=None):
         "chunk_prefill": (llama.prefill_paged,
                           (ints(1, 512), ints(1), cache, ints(1, PAGES_PER_SLOT), ints(1))),
     }[program]
-    jax.clear_caches()  # a trace made under other pins would be served again
-    with pallas.platform_hint("tpu"), autotune.decision_scope(pins):
+    jax.clear_caches()  # a trace made for another platform would be served again
+    with pallas.platform_hint("tpu"):
         compiled = jax.jit(lambda p, *a: fn(cfg, p, *a), donate_argnums=(3,)).lower(
             params, *args).compile()
     return compiled, cache
@@ -110,16 +111,16 @@ def test_pool_is_updated_in_place_on_the_v5e(one_chip, no_compile_cache, width, 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_decode_with_the_paged_kernel_gathers_nothing_on_the_v5e(one_chip, no_compile_cache, width):
-    """The decode step with ``paged_decode`` pinned to the kernel, as an
-    engine whose warm-up race it won would trace it: the kernel is in the
+    """The decode step as an engine on a TPU traces it, no pin and no
+    environment variable set: the kernel is in the
     program, no operation builds a gathered view of the pool — neither
     ``[N, MaxP, Hkv, page, D]`` as the gather leaves it (also named by its
     flat form ``[N*MaxP, Hkv, page, D]``) nor ``[N, Hkv, MaxP, page, D]`` as
     the re-layout did — and no pool plane is copied in front of the kernel.
     At head_dim 64 the kernel's wrapper pads ONE layer's pages to the lane
     width a call: two layer-sized temporaries, not a pool."""
-    compiled, cache = _compile(one_chip, width, "decode", pins={"paged_decode": "pallas"})
-    assert "tpu_custom_call" in compiled.as_text(), "the pinned kernel is not in the program"
+    compiled, cache = _compile(one_chip, width, "decode")
+    assert "tpu_custom_call" in compiled.as_text(), "the kernel is not in the program"
     layers, _, hkv, page, d = cache.k.shape
     for dims in [(SLOTS, PAGES_PER_SLOT, hkv, page, d), (SLOTS * PAGES_PER_SLOT, hkv, page, d),
                  (SLOTS, hkv, PAGES_PER_SLOT, page, d)]:
@@ -131,3 +132,95 @@ def test_decode_with_the_paged_kernel_gathers_nothing_on_the_v5e(one_chip, no_co
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < padded_layers + (4 << 20), (
         f"temporaries {temp} B (the XLA read path's were 76 MB at InternLM2's width)")
+
+
+# -- the engine's own decode chunk, read path chosen by the rule alone -----------
+
+
+def _engine_decode_chunk(one_chip, traced_before=None, **engine_kw):
+    """A small engine (heads of 128, pages of 128) built on the CPU, its OWN
+    jitted decode chunk lowered for the described chip under the scopes its
+    warm-up enters → the lowered program. No pin exists and no environment
+    variable is set: what is in the program is what the rule put there.
+    ``traced_before`` runs after the caches are cleared, before the lowering."""
+    from gofr_tpu.container import new_mock_container
+    from gofr_tpu.ops import pallas
+    from gofr_tpu.tpu.engine import GenerateEngine
+
+    cfg = LlamaConfig(vocab_size=256, hidden_size=256, intermediate_size=512,
+                      num_layers=2, num_heads=2, num_kv_heads=1)
+    eng = GenerateEngine(llama, cfg, llama.init(cfg, jax.random.key(0)), new_mock_container(),
+                         slots=4, max_len=256, kv_layout="paged", page_size=128,
+                         prefill_buckets=[128], **engine_kw)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    try:
+        args = jax.tree.map(described, (eng.params, eng._base_key, eng.cache))
+        packed = jax.ShapeDtypeStruct((5 + eng.pages_per_slot, eng.num_slots), jnp.int32,
+                                      sharding=one_chip)
+        carry = jax.tree.map(described, eng._zero_carry())
+        jax.clear_caches()
+        if traced_before is not None:
+            traced_before()
+        with pallas.platform_hint("tpu"), eng._trace_scope():
+            return eng._decode_chunk.lower(*args, eng.decode_chunk, packed, carry)
+    finally:
+        eng.stop()
+
+
+def _located(lowered) -> dict:
+    """{operation line: its location, references expanded} of the lowered
+    program's kernel calls — the names and call sites the compile cache keys on."""
+    text = lowered.as_text(debug_info=True)
+    defs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+
+    def expand(ref, depth=0):
+        body = defs.get(ref, ref)
+        return re.sub(r"#loc\d+", lambda m: expand(m.group(0), depth + 1), body) if depth < 64 else body
+
+    out = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and "stablehlo.custom_call" in line:
+            ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+            out[re.sub(r"loc\(#loc\d+\)\s*$", "", line).strip()[:80]] = expand(ref.group(1)) if ref else None
+    return out
+
+
+@pytest.mark.parametrize("lockstep_role", [None, "leader"])
+def test_engine_decode_chunk_holds_the_kernel_by_rule(one_chip, no_compile_cache, monkeypatch, lockstep_role):
+    """Solo and as a lockstep leader (which used to stand down from the
+    warm-up race and so decode through ``gather_kv``): the engine's decode
+    chunk for a v5e holds the ``attention`` custom call and no operation
+    under ``kv_gather`` — every rank resolves the rule alike."""
+    for var in ("GOFR_PALLAS", "GOFR_AUTOTUNE", "GOFR_AUTOTUNE_CACHE", "GOFR_PALLAS_INTERPRET"):
+        monkeypatch.delenv(var, raising=False)
+    compiled = _engine_decode_chunk(one_chip, lockstep_role=lockstep_role).compile().as_text()
+    kernels = [line for line in compiled.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all(re.search(r'op_name="[^"]*/attention[/"]', line) for line in kernels), kernels[:2]
+    assert not re.search(r'op_name="[^"]*/kv_gather[/"]', compiled), "an operation under kv_gather is back"
+
+
+def test_kernel_operations_are_named_by_the_program_alone(one_chip, no_compile_cache):
+    """The compile cache keys on operation names and call sites. The decode
+    chunk's kernel calls carry the same ones whether the program is the
+    first to trace the kernel, traces it a second time, or comes after
+    something else traced the kernel alone from another call site (the
+    warm-up race did; the wrapper's own ``jax.jit`` then kept that trace)."""
+    from gofr_tpu.ops import pallas
+    from gofr_tpu.ops.attention import paged_decode_attention
+
+    def kernel_alone():  # at the engine's shapes, as a race would trace it
+        def spec(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        with pallas.platform_hint("tpu"):
+            jax.jit(lambda *a: paged_decode_attention(*a, backend="pallas")).lower(
+                spec(4, 2, 128), spec(2, 12, 1, 128, 128), spec(2, 12, 1, 128, 128),
+                spec(dtype=jnp.int32), spec(4, 3, dtype=jnp.int32), spec(4, dtype=jnp.int32))
+
+    first = _located(_engine_decode_chunk(one_chip))
+    assert first and all(first.values()), first
+    assert _located(_engine_decode_chunk(one_chip)) == first
+    assert _located(_engine_decode_chunk(one_chip, traced_before=kernel_alone)) == first
