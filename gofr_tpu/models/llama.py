@@ -41,7 +41,12 @@ from gofr_tpu.ops.kvcache import (
     write_prompts,
     write_prompts_q,
 )
-from gofr_tpu.ops.attention import paged_decode_attention_q, paged_decode_attention_q4
+from gofr_tpu.ops.attention import (
+    append_rides_in_kernel,
+    paged_decode_append_attention,
+    paged_decode_attention_q,
+    paged_decode_attention_q4,
+)
 from gofr_tpu.ops.paged import (
     PagedKVCache,
     Q4PagedKVCache,
@@ -580,8 +585,12 @@ def _append_attend_paged(cache, layer, table, positions, q, k, v):
     """One decode token per slot, by pool kind: append its K/V at
     ``positions``, attend over ``positions + 1`` → (cache, attn)."""
     if isinstance(cache, PagedKVCache):
-        k_pool, v_pool = append_tokens_paged(cache.k, cache.v, layer, table, positions, k, v)
-        attn = paged_decode_attention(q, k_pool, v_pool, layer, table, positions + 1)
+        if append_rides_in_kernel(cache.k):  # one kernel call writes the row and attends
+            attn, k_pool, v_pool = paged_decode_append_attention(
+                q, k, v, cache.k, cache.v, layer, table, positions)
+        else:
+            k_pool, v_pool = append_tokens_paged(cache.k, cache.v, layer, table, positions, k, v)
+            attn = paged_decode_attention(q, k_pool, v_pool, layer, table, positions + 1)
         return PagedKVCache(k=k_pool, v=v_pool), attn
     q4c = isinstance(cache, Q4PagedKVCache)
     atp = append_tokens_paged_q4 if q4c else append_tokens_paged_q

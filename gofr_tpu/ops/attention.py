@@ -284,24 +284,28 @@ def _kv_shard_ctx(q: jnp.ndarray, pool: jnp.ndarray):
     return ctx
 
 
-def _shard_paged_call(impl, ctx, q, pools, layer, table, lengths):
-    """Run ``impl(q, *pools, layer, table, lengths)`` per-shard: q splits on
-    its head axis (dim 1) and every whole pool plane on its KV-head axis
+def _shard_paged_call(impl, ctx, heads, pools, layer, table, lengths, pools_out: int = 0):
+    """Run ``impl(*heads, *pools, layer, table, lengths)`` per-shard: every
+    ``heads`` operand ([N, H, D]: q, a step's new K/V) splits on its head
+    axis (dim 1) and every whole pool plane on its KV-head axis
     (paged.plane_partition_spec), layer/table/lengths replicated, output
-    head-sharded (no reduce — see module note above)."""
+    head-sharded (no reduce — see module note above). An ``impl`` that
+    writes returns its first ``pools_out`` planes after the output; they
+    come back sharded as they went in."""
     from jax.sharding import PartitionSpec as P
 
     from gofr_tpu.ops.paged import plane_partition_spec
 
     ax = ctx.axis
+    head_spec = P(None, ax, None)
     pool_specs = tuple(plane_partition_spec(p.ndim, ax) for p in pools)
     return jax.shard_map(
         impl,
         mesh=ctx.mesh,
-        in_specs=(P(None, ax, None),) + pool_specs + (P(), P(), P()),
-        out_specs=P(None, ax, None),
+        in_specs=(head_spec,) * len(heads) + pool_specs + (P(), P(), P()),
+        out_specs=(head_spec,) + pool_specs[:pools_out] if pools_out else head_spec,
         check_vma=False,
-    )(q, *pools, jnp.asarray(layer, jnp.int32), table, lengths)
+    )(*heads, *pools, jnp.asarray(layer, jnp.int32), table, lengths)
 
 
 def _require_kernel_page(pool: jnp.ndarray) -> None:
@@ -333,7 +337,7 @@ def paged_decode_attention_q(
     ctx = _kv_shard_ctx(q, kq_pool)
     if ctx is not None:
         impl = partial(_paged_decode_attention_q_local, scale=scale, backend=backend)
-        return _shard_paged_call(impl, ctx, q, (kq_pool, vq_pool, ks_pool, vs_pool),
+        return _shard_paged_call(impl, ctx, (q,), (kq_pool, vq_pool, ks_pool, vs_pool),
                                  layer, table, lengths)
     return _paged_decode_attention_q_local(
         q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
@@ -398,7 +402,7 @@ def paged_decode_attention_q4(
     ctx = _kv_shard_ctx(q, kq_pool)
     if ctx is not None:
         impl = partial(_paged_decode_attention_q4_local, scale=scale, backend=backend)
-        return _shard_paged_call(impl, ctx, q, (kq_pool, vq_pool, ks_pool, vs_pool),
+        return _shard_paged_call(impl, ctx, (q,), (kq_pool, vq_pool, ks_pool, vs_pool),
                                  layer, table, lengths)
     return _paged_decode_attention_q4_local(
         q, kq_pool, vq_pool, ks_pool, vs_pool, layer, table, lengths,
@@ -463,7 +467,7 @@ def paged_decode_attention(
     ctx = _kv_shard_ctx(q, k_pool)
     if ctx is not None:
         impl = partial(_paged_decode_attention_local, scale=scale, backend=backend)
-        return _shard_paged_call(impl, ctx, q, (k_pool, v_pool), layer, table, lengths)
+        return _shard_paged_call(impl, ctx, (q,), (k_pool, v_pool), layer, table, lengths)
     return _paged_decode_attention_local(
         q, k_pool, v_pool, layer, table, lengths, scale=scale, backend=backend,
     )
@@ -505,3 +509,48 @@ def _paged_decode_attention_local(
 
     k_view, v_view = gather_kv(k_pool, v_pool, layer, table)
     return decode_attention(q, k_view, v_view, lengths, scale=scale, backend="xla")
+
+
+def append_rides_in_kernel(k_pool: jnp.ndarray, backend: str = "auto") -> bool:
+    """Who writes a decode token's K/V into a dense paged pool: the
+    ``paged_decode`` kernel itself (``paged_decode_append_attention``: one
+    call a layer appends and attends) exactly where that kernel serves the
+    read (``resolve_backend``) and can address the plane's rows — head_dim a
+    multiple of the 128 lanes, pages of whole sublane tiles. Everywhere else
+    (head_dim 64, the CPU, ``backend="xla"``; the int8 / int4 pools never
+    ask) ``ops.paged.append_tokens_paged``'s scatter writes and the read
+    path follows. No switch: the engine reports it (``app_tpu_kernel_backend
+    {op="paged_append"}``), nothing chooses it."""
+    if resolve_backend(backend, op="paged_decode") != "pallas":
+        return False
+    from gofr_tpu.ops.pallas.paged_decode import append_in_kernel
+
+    return append_in_kernel(k_pool)
+
+
+@scoped("attention")
+def paged_decode_append_attention(
+    q: jnp.ndarray,          # [N, Hq, D]
+    k_new: jnp.ndarray,      # [N, Hkv, D]
+    v_new: jnp.ndarray,
+    k_pool: jnp.ndarray,     # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,
+    layer,
+    table: jnp.ndarray,      # [N, MaxP], OOB entries == P
+    positions: jnp.ndarray,  # [N]
+    *,
+    scale: float | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``append_tokens_paged`` at ``positions`` then ``paged_decode_attention``
+    over ``positions + 1``, as ONE kernel call that updates the planes where
+    they lie → (attn, k_pool, v_pool). For pools ``append_rides_in_kernel``
+    admits; the write's time is the ``attention`` scope's."""
+    from gofr_tpu.ops.pallas import interpret_mode
+    from gofr_tpu.ops.pallas.paged_decode import paged_decode_append_attention as fused
+
+    impl = partial(fused, scale=scale, interpret=interpret_mode())
+    ctx = _kv_shard_ctx(q, k_pool)
+    if ctx is not None:
+        return _shard_paged_call(impl, ctx, (q, k_new, v_new), (k_pool, v_pool),
+                                 layer, table, positions, pools_out=2)
+    return impl(q, k_new, v_new, k_pool, v_pool, layer, table, positions)

@@ -23,6 +23,15 @@ table entries (== P) clamp to P-1 and are only ever read behind that mask.
 docs/kernels.md has the design's A/B against a BlockSpec-only grid of
 (slot, logical_page).
 
+``paged_decode_append_attention`` is the same kernel serving a whole decode
+step of a layer: it also WRITES each lane's new K/V row into the planes,
+which go in and come out aliased. The row's page is the lane's last, so it
+is in VMEM when the row exists: the aligned sublane tile around the row is
+patched there (the page is attended with it, so nothing is read back from
+HBM after a write), staged and copied to the plane — O(tokens), no page
+rewritten whole, no scatter of its own (ops/attention.append_rides_in_kernel
+says where; PERF.md §6, PR 33).
+
 The int8 and int4 kernels below keep the older grid, (slot, kv_head,
 logical_page) with one [page, D] tile a step, dead pages included.
 
@@ -44,7 +53,7 @@ nibble unpack + dequant happen in-register — HBM reads per KV token halve
 again vs int8. The scale folds are byte-for-byte the int8 kernel's: ks on
 the scores after the QK matmul, vs inside the online-softmax recurrence.
 
-None of the three wrappers carries a ``jax.jit`` of its own: each is traced
+None of the wrappers carries a ``jax.jit`` of its own: each is traced
 inside the program that calls it, so its operations are named (and located)
 by that program alone, whatever else traced the kernel first in the process —
 the persistent compile cache keys on those names (tpu/device.py).
@@ -79,26 +88,13 @@ _OTHER_HEAD = 1 << 30
 _LANES = 128
 
 
-def _paged_decode_kernel(
-    ln_ref,    # SMEM [N] per-slot live length (scalar prefetch)
-    table_ref, # SMEM [N, MaxP] block table (scalar prefetch)
-    layer_ref, # SMEM [1] layer index (scalar prefetch)
-    q_ref,     # VMEM [Hq, d]
-    pos_ref,   # VMEM int32 [Hq, Hkv*page]: position in the page, _OTHER_HEAD off the head's own block
-    k_hbm,     # the whole plane [L, P, Hkv, page, d], left where it lies
-    v_hbm,
-    o_ref,     # VMEM [Hq, d]
-    k_buf,     # scratch [2, Hkv, page, d]: the page being attended and the one on its way
-    v_buf,
-    sem,       # DMA semaphores [2 planes, 2 buffers]
-    acc_ref,   # scratch f32 [Hq, d]
-    m_ref,     # scratch f32 [Hq, 128]
-    l_ref,     # scratch f32 [Hq, 128]
-    seq_ref,   # SMEM [1]: pages copied by the lanes before this one
-    *,
-    scale: float,
-    page: int,
-):
+def _tile_rows(dtype) -> int:
+    """Rows of one sublane tile of a plane in HBM: the least a copy can address
+    (8 rows of 32 bits; a bf16 tile packs 16)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool):
     """One grid step is one lane; its pages are a loop, not grid steps.
 
     The copies form one chain over the whole call: page ``j`` of a lane
@@ -106,7 +102,35 @@ def _paged_decode_kernel(
     the NEXT LANE's first when ``j`` is this lane's last — starts before
     page ``j`` is attended. Every lane copies at least one page (an empty
     lane its table's first entry, which it does not attend), so the chain
-    never breaks and the last lane leaves no copy in flight."""
+    never breaks and the last lane leaves no copy in flight.
+
+    With ``append`` the lane's new K/V row is written from here too. Its
+    page is the lane's last, so it is in VMEM when the row exists: the
+    aligned tile of rows around ``off`` is patched there (the page is then
+    attended WITH the row: nothing is read back from HBM after the write),
+    staged, and copied to the plane. The wait for that copy is put off by
+    two lanes — the lane after next waits before it stages its own — and
+    the last lane waits for what is left."""
+    # scalar prefetch (SMEM): ln [N] positions a lane attends (the new token's
+    # included), table [N, MaxP] clamped into the pool, layer [1]; with
+    # ``append`` wp [N] the page a lane's new row goes to (>= pool: it writes
+    # nothing) and off [N] its row in that page. VMEM blocks: q [Hq, d] of the
+    # lane; pos int32 [Hq, Hkv*page], the position in the page or _OTHER_HEAD
+    # off the head's own block; knew / vnew [N, Hkv, d], the step's K/V of
+    # every lane. The planes [L, P, Hkv, page, d] stay where they lie; when
+    # they are written the aliased outputs ARE the planes, read and written
+    # through one ref each.
+    if append:
+        (ln_ref, table_ref, layer_ref, wp_ref, off_ref, q_ref, pos_ref, knew_ref, vnew_ref,
+         _, _, o_ref, k_hbm, v_hbm, *scratch) = refs
+    else:
+        ln_ref, table_ref, layer_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref, *scratch = refs
+    (k_buf, v_buf,  # [2, Hkv, page, d]: the page being attended and the one on its way
+     sem,           # DMA semaphores [2 planes, 2 buffers]
+     acc_ref,       # f32 [Hq, d]
+     m_ref, l_ref,  # f32 [Hq, 128]
+     seq_ref,       # SMEM [1]: pages copied by the lanes before this one
+     *stage) = scratch
     bi = pl.program_id(0)
     lanes = pl.num_programs(0)
     length = ln_ref[bi]
@@ -130,6 +154,45 @@ def _paged_decode_kernel(
     mine = jnp.maximum(pl.cdiv(length, page), 1)  # pages this lane copies
     init_softmax_scratch(0, acc_ref, m_ref, l_ref)
 
+    if append:
+        k_stage, v_stage, wsem = stage  # [2, Hkv, rows, d] a plane; DMA semaphores [2 planes, 2 slots]
+        rows = k_stage.shape[2]
+        slot = bi % 2
+
+        def writes(lane):
+            return wp_ref[lane] < pool
+
+        def tile_base(lane):  # first row of the aligned tile that holds the lane's new row
+            return pl.multiple_of(off_ref[lane] // rows * rows, rows)
+
+        def tile_copies(lane, slot):
+            dst = (layer_ref[0], wp_ref[lane], slice(None), pl.ds(tile_base(lane), rows))
+            return (pltpu.make_async_copy(k_stage.at[slot], k_hbm.at[dst], wsem.at[0, slot]),
+                    pltpu.make_async_copy(v_stage.at[slot], v_hbm.at[dst], wsem.at[1, slot]))
+
+        def wait_tiles(lane, slot):
+            started, lane = lane >= 0, jnp.maximum(lane, 0)  # no lane before the first
+
+            @pl.when(started & writes(lane))
+            def _():
+                for copy in tile_copies(lane, slot):
+                    copy.wait()
+
+        wait_tiles(bi - 2, slot)  # this lane's staging slot is free again
+
+        def patch(buf):
+            base = tile_base(bi)
+            hit = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) == off_ref[bi] - base
+            for new_ref, page_buf, stage in ((knew_ref, k_buf, k_stage), (vnew_ref, v_buf, v_stage)):
+                new = new_ref[bi].astype(jnp.float32)  # [Hkv, d]; a row is cut out of 32-bit sublanes
+                for h in range(hkv):
+                    tile = page_buf[buf, h, pl.ds(base, rows), :]
+                    tile = jnp.where(hit, new[h:h + 1, :], tile.astype(jnp.float32)).astype(tile.dtype)
+                    stage[slot, h] = tile
+                    page_buf[buf, h, pl.ds(base, rows), :] = tile
+            for copy in tile_copies(bi, slot):
+                copy.start()
+
     def attend(j, carry):
         buf = (seq + j) % 2
         last = j + 1 == mine
@@ -141,6 +204,11 @@ def _paged_decode_kernel(
 
         for copy in page_copies(bi, j, buf):
             copy.wait()
+
+        if append:
+            @pl.when(last & writes(bi))
+            def _():
+                patch(buf)
 
         @pl.when(j * page < length)  # false only for an empty lane's one page
         def _():
@@ -165,6 +233,83 @@ def _paged_decode_kernel(
 
     softmax_finish(0, 1, acc_ref, l_ref, write)
 
+    if append:
+        @pl.when(bi == lanes - 1)
+        def _():
+            wait_tiles(bi - 1, 1 - slot)
+            wait_tiles(bi, slot)
+
+
+def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, interpret):
+    """The kernel's one call. ``new`` is None (read only → attn) or
+    ``(k_new, v_new, write_page, write_row)`` (→ attn, k_pool, v_pool, the
+    planes aliased)."""
+    n, hq, d = q.shape
+    _, pool, hkv, page, _ = k_pool.shape
+    _, maxp = table.shape
+    if hq % hkv != 0:
+        raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
+    group = hq // hkv
+    scale = scale if scale is not None else 1.0 / (d**0.5)
+    append = new is not None
+
+    col = jnp.arange(hkv * page, dtype=jnp.int32)[None, :]
+    own = jnp.arange(hq, dtype=jnp.int32)[:, None] // group == col // page
+    pos = jnp.where(own, col % page, _OTHER_HEAD)
+
+    def lane_map(bi, *_):
+        return (bi, 0, 0)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda bi, *_: (0,) * len(shape))
+
+    plane = pl.BlockSpec(memory_space=pl.ANY)
+    attn = jax.ShapeDtypeStruct((n, hq, d), q.dtype)
+    in_specs = [pl.BlockSpec((None, hq, d), lane_map), whole(hq, hkv * page)]
+    operands = [q, pos]
+    scratch = [
+        pltpu.VMEM((2, hkv, page, d), k_pool.dtype),
+        pltpu.VMEM((2, hkv, page, d), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((hq, d), jnp.float32),
+        pltpu.VMEM((hq, 128), jnp.float32),
+        pltpu.VMEM((hq, 128), jnp.float32),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    prefetch = [jnp.minimum(lengths.astype(jnp.int32), maxp * page),
+                jnp.minimum(table, pool - 1).astype(jnp.int32), _layer_operand(layer)]
+    if append:
+        k_new, v_new, write_page, write_row = new
+        rows = _tile_rows(k_pool.dtype)
+        prefetch += [write_page.astype(jnp.int32), write_row.astype(jnp.int32)]
+        in_specs += [whole(n, hkv, d), whole(n, hkv, d)]
+        operands += [k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype)]
+        scratch += [
+            pltpu.VMEM((2, hkv, rows, d), k_pool.dtype),
+            pltpu.VMEM((2, hkv, rows, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ]
+    planes = len(prefetch) + len(operands)  # the K plane's place among the inputs
+    kernel = functools.partial(_paged_decode_kernel, scale=scale, page=page, pool=pool, append=append)
+    return pl.pallas_call(
+        kernel,
+        name="attention",  # tracing.SCOPES: the kernel is named for its phase
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n,),
+            in_specs=in_specs + [plane, plane],
+            out_specs=([pl.BlockSpec((None, hq, d), lane_map), plane, plane] if append
+                       else pl.BlockSpec((None, hq, d), lane_map)),
+            scratch_shapes=scratch,
+        ),
+        out_shape=([attn, jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)] if append else attn),
+        input_output_aliases={planes: 1, planes + 1: 2} if append else {},
+        # the lanes run in order: the chain of copies crosses from one to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, *operands, k_pool, v_pool)
+
 
 def paged_decode_attention(
     q: jnp.ndarray,        # [N, Hq, D]
@@ -178,14 +323,7 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Single-step decode against the paged pool → [N, Hq, D]."""
-    n, hq, d = q.shape
-    _, pool, hkv, page, _ = k_pool.shape
-    _, maxp = table.shape
-    if hq % hkv != 0:
-        raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
-    group = hq // hkv
-    scale = scale if scale is not None else 1.0 / (d**0.5)
-
+    d = q.shape[-1]
     if d % _LANES:
         # A copy cannot cut a page whose rows are narrower than the lane
         # width out of the plane. Such a pool's layer is padded to it first:
@@ -200,46 +338,50 @@ def paged_decode_attention(
 
         return paged_decode_attention(
             widen(q), layer_of(k_pool), layer_of(v_pool), 0, table, lengths,
-            scale=scale, interpret=interpret)[..., :d]
+            scale=scale if scale is not None else 1.0 / (d**0.5), interpret=interpret)[..., :d]
+    return _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, None,
+                              scale=scale, interpret=interpret)
 
-    safe_table = jnp.minimum(table, pool - 1).astype(jnp.int32)
-    col = jnp.arange(hkv * page, dtype=jnp.int32)[None, :]
-    own = jnp.arange(hq, dtype=jnp.int32)[:, None] // group == col // page
-    pos = jnp.where(own, col % page, _OTHER_HEAD)
 
-    def lane_map(bi, ln_ref, table_ref, layer_ref):
-        return (bi, 0, 0)
+def append_in_kernel(k_pool: jnp.ndarray) -> bool:
+    """Whether ``paged_decode_append_attention`` can write this plane: rows
+    as wide as the lanes (a narrower pool is read through a padded COPY of
+    one layer, so nothing can be written through it) and pages made of whole
+    sublane tiles."""
+    return k_pool.shape[4] % _LANES == 0 and k_pool.shape[3] % _tile_rows(k_pool.dtype) == 0
 
-    kernel = functools.partial(_paged_decode_kernel, scale=scale, page=page)
-    return pl.pallas_call(
-        kernel,
-        name="attention",  # tracing.SCOPES: the kernel is named for its phase
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n,),
-            in_specs=[
-                pl.BlockSpec((None, hq, d), lane_map),
-                pl.BlockSpec((hq, hkv * page), lambda bi, ln, tb, ly: (0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((None, hq, d), lane_map),
-            scratch_shapes=[
-                pltpu.VMEM((2, hkv, page, d), k_pool.dtype),
-                pltpu.VMEM((2, hkv, page, d), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((hq, d), jnp.float32),
-                pltpu.VMEM((hq, 128), jnp.float32),
-                pltpu.VMEM((hq, 128), jnp.float32),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, hq, d), q.dtype),
-        # the lanes run in order: the chain of copies crosses from one to the next
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.minimum(lengths.astype(jnp.int32), maxp * page), safe_table,
-      _layer_operand(layer), q, pos, k_pool, v_pool)
+
+def paged_decode_append_attention(
+    q: jnp.ndarray,          # [N, Hq, D]
+    k_new: jnp.ndarray,      # [N, Hkv, D] the step's K/V per lane
+    v_new: jnp.ndarray,
+    k_pool: jnp.ndarray,     # [L, P, Hkv, page, D]
+    v_pool: jnp.ndarray,
+    layer,                   # scalar layer index
+    table: jnp.ndarray,      # [N, MaxP] int32, OOB entries == P
+    positions: jnp.ndarray,  # [N] where each lane's new row goes; it attends positions + 1
+    *,
+    scale: float | None = None,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A decode step's append AND attention in one call → (attn [N, Hq, D],
+    k_pool, v_pool): ``ops.paged.append_tokens_paged`` then
+    ``paged_decode_attention`` over ``positions + 1``, the planes updated
+    where they lie (aliased) and no row read back from HBM after its write.
+    A lane whose page is P (idle) or whose position lies past the table's
+    span writes nothing."""
+    if not append_in_kernel(k_pool):
+        raise ValueError(
+            f"the paged-decode kernel cannot write a plane {tuple(k_pool.shape)} of "
+            f"{k_pool.dtype}: it needs head_dim % {_LANES} == 0 and page_size % "
+            f"{_tile_rows(k_pool.dtype)} == 0 (append_tokens_paged writes any)")
+    from gofr_tpu.ops.paged import _locate_append  # the scatter's own rule for what is dropped
+
+    positions = positions.astype(jnp.int32)
+    write_page, write_row = _locate_append(table, positions, k_pool.shape[3], k_pool.shape[1])
+    return tuple(_paged_decode_call(
+        q, k_pool, v_pool, layer, table, positions + 1, (k_new, v_new, write_page, write_row),
+        scale=scale, interpret=interpret))
 
 
 def _paged_decode_q_kernel(

@@ -1668,14 +1668,14 @@ class GenerateEngine(_EngineBase):
         # backend (e.g. Pallas for a CPU test mesh under an attached TPU),
         # and jit would cache that mis-resolved program per shape
         with platform_hint(getattr(self.tpu, "platform", None)), self._trace_scope():
-            op, serves = self._decode_op(), self._decode_backend()
-            for b in ("pallas", "xla"):
-                # info-style gauge: 1 on the backend the rule resolves this
-                # engine's decode op to, 0 on the other
-                self.metrics.set_gauge(
-                    "app_tpu_kernel_backend", 1.0 if b == serves else 0.0,
-                    op=op, backend=b, kv_dtype=self.kv_quantize or "bf16")
-            self.logger.infof("decode op %s -> %s (rule)", op, serves)
+            # info-style gauge: 1 on the backend the rule resolves an op of
+            # this engine's decode program to, 0 on the other
+            for op, rec in self.autotune_report()["decisions"].items():
+                for b in {"paged_append": ("fused", "scatter")}.get(op, ("pallas", "xla")):
+                    self.metrics.set_gauge(
+                        "app_tpu_kernel_backend", 1.0 if b == rec["backend"] else 0.0,
+                        op=op, backend=b, kv_dtype=self.kv_quantize or "bf16")
+                self.logger.infof("decode op %s -> %s (rule)", op, rec["backend"])
             return self._warmup_traced(lbs, bbs)
 
     def _warmup_traced(self, lbs: list[int], bbs: list[int]) -> int:
@@ -1700,12 +1700,31 @@ class GenerateEngine(_EngineBase):
         with platform_hint(getattr(self.tpu, "platform", None)):
             return resolve_backend("auto", self._decode_op())
 
+    def _append_backend(self) -> str:
+        """Who writes a decode token's K/V into this engine's paged pool:
+        ``fused`` — the paged-decode kernel's own call appends and attends
+        (ops/attention.append_rides_in_kernel: the dense pool, where that
+        kernel serves and can address the plane's rows) — or ``scatter``
+        (ops/paged.append_tokens_paged*, then the read path)."""
+        from gofr_tpu.ops.attention import append_rides_in_kernel
+        from gofr_tpu.ops.paged import PagedKVCache
+        from gofr_tpu.ops.pallas import platform_hint
+
+        cache = self.cache  # models/llama._append_attend_paged asks the same of the pool it is given
+        with platform_hint(getattr(self.tpu, "platform", None)):
+            fused = isinstance(cache, PagedKVCache) and append_rides_in_kernel(cache.k)
+        return "fused" if fused else "scatter"
+
     def autotune_report(self) -> dict:
-        """Which backend serves this engine's decode op, in the shape
-        benchmarks/run.py, chip_smoke.py and /debug/engine read (the name is
-        theirs; nothing is tuned — ops/attention.resolve_backend decides)."""
-        return {"decisions": {self._decode_op(): {
-            "backend": self._decode_backend(), "source": "rule"}}}
+        """Which backend serves this engine's decode op — and, for a paged
+        pool, which path writes a decode token's K/V (``paged_append``) — in
+        the shape benchmarks/run.py, chip_smoke.py and /debug/engine read
+        (the name is theirs; nothing is tuned — the rules in ops/attention
+        decide)."""
+        decisions = {self._decode_op(): {"backend": self._decode_backend(), "source": "rule"}}
+        if self.kv_layout == "paged":
+            decisions["paged_append"] = {"backend": self._append_backend(), "source": "rule"}
+        return {"decisions": decisions}
 
     def spec_accept_totals(self) -> dict[str, tuple[float, float]]:
         """Lifetime per-adapter (accepted, proposed) speculative-decode

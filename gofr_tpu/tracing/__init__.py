@@ -526,6 +526,17 @@ def tracer_from_config(config, logger, service_name: str) -> Tracer:
 # same name through their ``name=``. A reader attributes a device operation to
 # the INNERMOST of these in its ``op_name`` path; an operation with none of
 # them is the compiler's own (loop plumbing, inserted copies).
+#
+# What two of them cover in a DECODE chunk depends on who appends
+# (ops/attention.append_rides_in_kernel; app_tpu_kernel_backend{op="paged_append"}):
+#
+#   paged_append   kv_append                          attention
+#   "scatter"      the row scatter, its index math    the read path alone
+#   "fused"        nothing (0.0 in a trace)           the kernel's one call a layer: the write
+#                                                     of the step's K/V rows AND the read
+#
+# Prefill, chunked prefill and speculative verify write through
+# ``write_prompts_paged*`` / ``_put_run`` under ``kv_append`` either way.
 SCOPES = ("embed", "qkv_rope", "kv_append", "kv_gather", "attention",
           "o_proj", "mlp", "lm_head", "sample")
 

@@ -40,7 +40,11 @@ def test_smoke_body_on_cpu_mesh():
     assert out["passes"]["four_chip"]["mesh"] == "tp:4"
     assert out["passes"]["four_chip"]["first_tokens_equal_one_chip"] == 8
     assert out["four_chip"] == "ok"
-    assert len(out["kernels"]) == 7 and all(k["compiled"] for k in out["kernels"].values())
+    assert len(out["kernels"]) == 8 and all(k["compiled"] for k in out["kernels"].values())
+    assert out["kernels"]["paged_decode_append_bf16"]["max_err"] <= 1e-4  # f32 here; the planes exact
+    for name, backend in (("paged", "paged_decode"), ("four_chip", "paged_decode")):
+        assert out["passes"][name]["decode_backend"] == {backend: "xla", "paged_append": "scatter"}, name
+    assert out["passes"]["slot"]["decode_backend"] == {"decode": "xla"}
     assert out["compile_cache"]["dir"] == jax.config.jax_compilation_cache_dir
     assert out["planner"] == ("native" if shutil.which("g++") else "python")
     json.dumps(out)  # the script prints it as one line, then the verdict as the last

@@ -174,7 +174,7 @@ def _tiny_engine(container=None, **kw):
     from gofr_tpu.models import LlamaConfig, llama
     from gofr_tpu.tpu.engine import GenerateEngine
 
-    cfg = LlamaConfig.tiny()
+    cfg = kw.pop("cfg", None) or LlamaConfig.tiny()
     params = llama.init(cfg, jax.random.key(0))
     kwargs = dict(slots=2, max_len=32, kv_layout="paged", page_size=8,
                   kv_quantize="int8", prefill_buckets=[16])
@@ -210,6 +210,46 @@ def test_engine_int8_paged_decode_token_exact_pallas_vs_xla(monkeypatch):
             eng.stop()
     assert tokens["pallas"] == tokens["xla"]
     jax.clear_caches()
+
+
+def test_engine_tokens_do_not_depend_on_who_appends(monkeypatch):
+    """Serving through the engine at heads of 128 (bf16 pool, pages of one
+    sublane tile, 2 layers) under the interpreter: the decode chunk whose
+    kernel call appends AND attends gives the greedy tokens of the chunk that
+    scatters and then reads — across a page boundary (the prompt of 14 decodes
+    over row 16), with a lane idle and then joined — and leaves the page pool
+    consistent. Which of the two an engine runs is the rule's to say
+    (``append_rides_in_kernel``); here the other side is forced at the model's
+    call site, the only place that asks."""
+    from gofr_tpu.models import LlamaConfig, llama
+    from gofr_tpu.testutil import assert_paged_pool_consistent
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256, num_layers=2,
+                      num_heads=2, num_kv_heads=1)
+    prompts = [[5 + i % 90 for i in range(14)], [11, 4, 8]]
+    tokens, wrote = {}, {}
+    for who in ("scatter", "fused"):
+        jax.clear_caches()  # who appends is a trace-time property
+        if who == "scatter":
+            monkeypatch.setattr(llama, "append_rides_in_kernel", lambda pool: False)
+        else:
+            monkeypatch.undo()
+            monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+        eng = _tiny_engine(cfg=cfg, kv_quantize="", page_size=16, max_len=48, prefill_buckets=[16])
+        try:
+            wrote[who] = eng.autotune_report()["decisions"]["paged_append"]["backend"]
+            eng.warmup()
+            eng.start()
+            first = eng.submit(prompts[0], max_new_tokens=10)
+            tokens[who] = [eng.generate(prompts[1], max_new_tokens=6, timeout=300)["tokens"],
+                           first.result(timeout=300)["tokens"]]
+            assert_paged_pool_consistent(eng, slots_empty=True)
+        finally:
+            eng.stop()
+    jax.clear_caches()
+    assert wrote["fused"] == "fused"  # the report reads the rule, not the patched call site
+    assert tokens["fused"] == tokens["scatter"] and all(len(t) in (6, 10) for t in tokens["fused"])
 
 
 # -- the rule -------------------------------------------------------------------
@@ -285,9 +325,9 @@ def test_engine_report_has_the_shape_the_benchmark_reads(monkeypatch):
     try:
         report = eng.autotune_report() or {}
         assert {op: rec.get("backend") for op, rec in report["decisions"].items()} == {
-            "paged_decode_q": "xla"}  # the mock container's devices are the CPU's
+            "paged_decode_q": "xla", "paged_append": "scatter"}  # the mock container's devices are the CPU's
         assert report.get("errors") is None
-        assert report["decisions"]["paged_decode_q"]["source"] == "rule"
+        assert {rec["source"] for rec in report["decisions"].values()} == {"rule"}
         eng.warmup()
         assert eng.autotune_report() == report
     finally:
@@ -295,9 +335,60 @@ def test_engine_report_has_the_shape_the_benchmark_reads(monkeypatch):
     gauge = c.metrics.get("app_tpu_kernel_backend")
     assert {(dict(ls)["op"], dict(ls)["kv_dtype"], dict(ls)["backend"]): v
             for ls, v in gauge._values.items()} == {
-        ("paged_decode_q", "int8", "xla"): 1.0, ("paged_decode_q", "int8", "pallas"): 0.0}
+        ("paged_decode_q", "int8", "xla"): 1.0, ("paged_decode_q", "int8", "pallas"): 0.0,
+        ("paged_append", "int8", "scatter"): 1.0, ("paged_append", "int8", "fused"): 0.0}
     bf16 = _tiny_engine(kv_quantize="")
     try:
-        assert bf16.autotune_report()["decisions"].keys() == {"paged_decode"}
+        assert bf16.autotune_report()["decisions"].keys() == {"paged_decode", "paged_append"}
     finally:
         bf16.stop()
+    slot = _tiny_engine(kv_layout="slot", kv_quantize="")
+    try:
+        assert slot.autotune_report()["decisions"].keys() == {"decode"}  # nothing paged to append to
+    finally:
+        slot.stop()
+
+
+# (platform the trace targets, kernels interpreted, head_dim, page_size, pool) -> who appends
+APPEND_CASES = [
+    ("tpu", False, 128, 16, "", "fused"),      # the benchmark's cells: the kernel serves and can address the rows
+    ("tpu", False, 64, 16, "", "scatter"),     # Llama-1B: the kernel reads a padded COPY of a layer
+    ("tpu", False, 128, 8, "", "scatter"),     # a page of half a bf16 sublane tile
+    ("tpu", False, 128, 16, "int8", "scatter"),
+    ("tpu", False, 128, 16, "int4", "scatter"),
+    ("cpu", False, 128, 16, "", "scatter"),    # the XLA read path serves: the scatter writes
+    ("cpu", True, 128, 16, "", "fused"),       # the CPU tests' interpreter
+    ("cpu", True, 64, 16, "", "scatter"),
+]
+
+
+@pytest.mark.parametrize("platform,interpret,head_dim,page,kvq,want", APPEND_CASES)
+def test_who_appends_follows_from_what_the_code_observes(monkeypatch, platform, interpret, head_dim,
+                                                          page, kvq, want):
+    """``paged_append`` is no choice of its own: it is ``fused`` exactly
+    where the rule gives the ``paged_decode`` kernel AND the plane's rows can
+    be addressed (head_dim % 128 == 0, whole sublane tiles a page) AND the
+    pool is the dense one; the engine's report, its gauge and the model's
+    call site (``models/llama._append_attend_paged``) read the same two
+    functions."""
+    from gofr_tpu.container import new_mock_container
+    from gofr_tpu.models import LlamaConfig
+    from gofr_tpu.ops.attention import append_rides_in_kernel
+
+    if interpret:
+        monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("GOFR_PALLAS_INTERPRET", raising=False)
+    cfg = LlamaConfig(vocab_size=64, hidden_size=2 * head_dim, intermediate_size=64,
+                      num_layers=1, num_heads=2, num_kv_heads=1)
+    c = new_mock_container()
+    eng = _tiny_engine(c, kv_quantize=kvq, page_size=page, max_len=2 * page, prefill_buckets=[page], cfg=cfg)
+    try:
+        monkeypatch.setattr(eng.tpu, "platform", platform, raising=False)
+        assert eng.autotune_report()["decisions"]["paged_append"] == {"backend": want, "source": "rule"}
+        if not kvq:
+            with pallas.platform_hint(platform):
+                assert append_rides_in_kernel(eng.cache.k) == (want == "fused")
+                assert not append_rides_in_kernel(eng.cache.k, backend="xla")
+    finally:
+        eng.stop()

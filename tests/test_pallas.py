@@ -187,3 +187,156 @@ def test_kv_write_env_dispatch(monkeypatch):
     got = append_tokens(k_layer, v_layer, positions, k_new, v_new)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=1e-6)
+
+
+# -- a decode step's append inside the paged-decode kernel (ops/pallas/paged_decode) --
+
+PAGE, MAXP = 16, 3
+# what a lane of the batch is there for -> (its position, whether its table row is allocated)
+APPEND_LANES = {
+    "length_0": (0, True),                   # nothing attended but the row it writes
+    "row_1": (1, True),
+    "last_row_of_a_page": (PAGE - 1, True),
+    "opens_a_fresh_page": (PAGE, True),      # pos % page == 0 past the first page
+    "second_tile_of_a_page": (PAGE + 9, True),
+    "last_row_of_the_span": (MAXP * PAGE - 1, True),
+    "past_the_span": (MAXP * PAGE, True),    # dropped, never clamped onto the last page
+    "idle": (5, False),                      # every entry P: writes nothing
+}
+
+
+def _append_case(dtype, hkv, group, lanes=tuple(APPEND_LANES), d=128, seed=0):
+    """One batch with a lane for each name in ``lanes`` → operands of a decode
+    step's append + attention at layer 1 of a 2-layer pool whose pages hold
+    noise everywhere (rows past a length included: a write must leave them)."""
+    n, pool = len(lanes), len(lanes) * MAXP + 1  # the last page is nobody's: clamped reads land there
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(ks[0], (n, hkv * group, d), dtype)
+    k_pool = jax.random.normal(ks[1], (2, pool, hkv, PAGE, d), dtype)
+    v_pool = jax.random.normal(ks[2], (2, pool, hkv, PAGE, d), dtype)
+    k_new = jax.random.normal(ks[3], (n, hkv, d), dtype)
+    v_new = jax.random.normal(ks[4], (n, hkv, d), dtype)
+    table = np.full((n, MAXP), pool, np.int32)
+    perm = np.random.RandomState(seed).permutation(pool - 1)
+    pos = np.zeros(n, np.int32)
+    for i, name in enumerate(lanes):
+        pos[i], allocated = APPEND_LANES[name]
+        if allocated:
+            need = min(pos[i] // PAGE + 1, MAXP)
+            table[i, :need] = perm[i * MAXP: i * MAXP + need]
+    return q, k_new, v_new, k_pool, v_pool, jnp.asarray(table), jnp.asarray(pos)
+
+
+def _assert_append_attend_matches(dtype, got, pools, operands, live):
+    """The fused call against ``append_tokens_paged`` then attention: planes
+    bit for bit; the live lanes' output bit for bit against the kernel read
+    of the scattered pool, and against XLA's read to 2e-5 in float32 / one
+    bf16 ulp in bf16."""
+    from gofr_tpu.ops.attention import paged_decode_attention
+    from gofr_tpu.ops.paged import append_tokens_paged
+
+    q, k_new, v_new, k_pool, v_pool, table, pos = operands
+    want_k, want_v = append_tokens_paged(k_pool, v_pool, 1, table, pos, k_new, v_new)
+    for plane, want in zip(pools, (want_k, want_v)):
+        assert plane.dtype == want.dtype
+        assert np.array_equal(np.asarray(plane, np.float32), np.asarray(want, np.float32))
+    live = np.asarray(live)
+    kernel = paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="pallas")
+    assert np.array_equal(np.asarray(got, np.float32)[live], np.asarray(kernel, np.float32)[live])
+    xla = np.asarray(paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="xla"), np.float32)
+    tol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7  # one bf16 ulp of a value in [1, 2), relative above
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live], xla[live], atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [2, 4])
+@pytest.mark.parametrize("hkv", [1, 2, 8])
+def test_paged_decode_append_matches_scatter_then_attend(monkeypatch, dtype, group, hkv):
+    """Every kind of lane in ONE call, for the head geometries the repo
+    builds (a tp shard has 1 or 2 KV heads, the benchmark's configurations
+    8; 2 and 4 query heads a KV head): a decode step's append and attention
+    as one kernel call equal the scatter followed by the read."""
+    from gofr_tpu.ops.attention import paged_decode_append_attention
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    operands = _append_case(dtype, hkv, group)
+    got, k_out, v_out = paged_decode_append_attention(*operands[:5], 1, *operands[5:])
+    live = [i for i, name in enumerate(APPEND_LANES) if name != "idle"]
+    _assert_append_attend_matches(dtype, got, (k_out, v_out), operands, live)
+
+
+@pytest.mark.parametrize("lane", sorted(APPEND_LANES))
+def test_paged_decode_append_lane_by_lane(monkeypatch, lane):
+    """Each kind of lane between two plain neighbours (the wait for a lane's
+    write is put off to the lane after next, and the last lane waits for what
+    is left: first, middle and last place each), bf16, G = 2: the planes
+    change in exactly the rows the scatter changes — none for the idle lane
+    and the one past its table's span."""
+    from gofr_tpu.ops.attention import paged_decode_append_attention
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    for place in range(3):
+        lanes = ["row_1", "last_row_of_a_page"]
+        lanes.insert(place, lane)
+        operands = _append_case(jnp.bfloat16, 2, 2, lanes=tuple(lanes), seed=place)
+        got, k_out, v_out = paged_decode_append_attention(*operands[:5], 1, *operands[5:])
+        live = [i for i, name in enumerate(lanes) if name != "idle"]
+        _assert_append_attend_matches(jnp.bfloat16, got, (k_out, v_out), operands, live)
+        changed = np.argwhere(np.asarray(k_out != operands[3]).any(axis=(2, 4)))  # (layer, page, row)
+        dropped = lane in ("idle", "past_the_span")
+        assert len(changed) == (2 if dropped else 3) and set(changed[:, 0]) == {1}, changed
+
+
+@pytest.mark.parametrize("shape,why", [
+    ((2, 7, 2, 16, 64), "head_dim 64: the kernel reads a padded copy of a layer"),
+    ((2, 7, 2, 8, 128), "a bf16 page of half a sublane tile"),
+])
+def test_paged_decode_append_refuses_a_plane_it_cannot_address(monkeypatch, shape, why):
+    """Asked BY NAME for a plane whose rows no copy can address, the fused
+    call raises where it is traced; ``append_rides_in_kernel`` (the rule the
+    model's call site asks) says no for the same planes, so a served program
+    never gets here."""
+    from gofr_tpu.ops.attention import append_rides_in_kernel, paged_decode_append_attention
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    pool = jnp.zeros(shape, jnp.bfloat16)
+    assert not append_rides_in_kernel(pool), why
+    new = jnp.zeros((3, shape[2], shape[4]), jnp.bfloat16)
+    with pytest.raises(ValueError, match="cannot write a plane"):
+        paged_decode_append_attention(jnp.zeros((3, 4, shape[4]), jnp.bfloat16), new, new, pool, pool, 1,
+                                      jnp.zeros((3, 2), jnp.int32), jnp.zeros((3,), jnp.int32))
+
+
+def test_paged_decode_append_waits_for_every_tile_copy_it_starts():
+    """The interpreter runs a copy where it is started and keeps no count of
+    the semaphores, so a wait that went missing passes every test above and
+    faults only on the chip (it did, in PR 33's last check). Counted in the
+    kernel's own jaxpr instead: the read-only kernel starts a page copy a plane
+    in two places (the call's first page, the page after) and waits in one;
+    the append adds ONE place that starts a lane's tile copies and THREE that
+    wait for them — the lane after next before it stages its own, and the
+    last lane for the lane before it and for itself."""
+    from gofr_tpu.ops.pallas import paged_decode as kernels
+
+    def copies(fn, *args):
+        counts = {}
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+                for value in eqn.params.values():
+                    for sub in value if isinstance(value, (list, tuple)) else [value]:
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return counts.get("dma_start", 0), counts.get("dma_wait", 0)
+
+    q, new = jnp.zeros((3, 4, 128), jnp.bfloat16), jnp.zeros((3, 2, 128), jnp.bfloat16)
+    plane = jnp.zeros((2, 7, 2, 16, 128), jnp.bfloat16)
+    table, pos = jnp.zeros((3, 2), jnp.int32), jnp.zeros((3,), jnp.int32)
+    planes = 2
+    assert copies(kernels.paged_decode_attention, q, plane, plane, 1, table, pos) == (2 * planes, 1 * planes)
+    assert copies(kernels.paged_decode_append_attention, q, new, new, plane, plane, 1, table, pos) == (
+        (2 + 1) * planes, (1 + 3) * planes)

@@ -11,6 +11,7 @@ legitimately shifts greedy ties vs a full-precision reference), holds
 through spec rounds, preemption-by-recompute, and host-tier swap-in.
 Runs on the conftest-forced 8-virtual-CPU-device mesh (jaxpin.pin_cpu)."""
 
+import re
 import time
 
 import jax
@@ -204,6 +205,59 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
     jax.clear_caches()
     assert seen and set(seen) == {(cfg.num_heads // 4, cfg.num_kv_heads // 4)}, seen
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("heads_a_shard", [1, 2])
+def test_kernel_append_on_the_sharded_pool_matches_the_unsharded_scatter(monkeypatch, heads_a_shard):
+    """A decode step's append AND attention as the kernel's one call under
+    ``shard_map``: each of the 4 shards writes and attends its own 1 or 2 KV
+    heads (Llama-1B's and Mistral's width at ``tp:4``); the planes come back
+    sharded as they went in and equal the unsharded scatter's bit for bit,
+    the output the unsharded append-then-attend; lane 2 idle, lane 3 past
+    its table's span, lane 0 opening a fresh page. No collective is added:
+    the compiled call holds none."""
+    import jax.numpy as jnp
+
+    from gofr_tpu.ops import attention
+    from gofr_tpu.ops.paged import KVShardCtx, append_tokens_paged, kv_shard_scope, pool_sharding
+    from gofr_tpu.ops.pallas import paged_decode as kernels
+    from gofr_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(MESH)
+    hkv, group, page, d, pool = 4 * heads_a_shard, 2, 16, 128, 9
+    ks = jax.random.split(jax.random.key(7), 5)
+    q = jax.random.normal(ks[0], (4, hkv * group, d))
+    k_pool = jax.random.normal(ks[1], (2, pool, hkv, page, d))
+    v_pool = jax.random.normal(ks[2], (2, pool, hkv, page, d))
+    k_new, v_new = jax.random.normal(ks[3], (4, hkv, d)), jax.random.normal(ks[4], (4, hkv, d))
+    table = jnp.asarray([[5, 2], [0, 7], [pool, pool], [3, 6]], jnp.int32)
+    pos = jnp.asarray([16, 7, 0, 32], jnp.int32)
+
+    want_k, want_v = append_tokens_paged(k_pool, v_pool, 1, table, pos, k_new, v_new)
+    want = attention.paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="xla")
+
+    seen = []  # KV heads of each kernel trace: a shard's own
+
+    def spy(q, k_new, v_new, k_pool, *args, _kernel=kernels.paged_decode_append_attention, **kw):
+        seen.append((q.shape[1], k_new.shape[1], k_pool.shape[2]))
+        return _kernel(q, k_new, v_new, k_pool, *args, **kw)
+
+    monkeypatch.setattr(kernels, "paged_decode_append_attention", spy)
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    sharding = pool_sharding(mesh)
+    step = jax.jit(lambda *a: attention.paged_decode_append_attention(*a[:5], 1, *a[5:]), donate_argnums=(3, 4))
+    args = (q, k_new, v_new, jax.device_put(k_pool, sharding), jax.device_put(v_pool, sharding), table, pos)
+    with kv_shard_scope(KVShardCtx(mesh=mesh, axis="tp", shards=4)):
+        compiled = step.lower(*args).compile().as_text()
+        got, k_out, v_out = step(*args)
+    assert seen and set(seen) == {(heads_a_shard * group, heads_a_shard, heads_a_shard)}, seen
+    assert not re.search(r"all-gather|all-reduce|collective-permute|all-to-all", compiled)
+    for plane, ref in ((k_out, want_k), (v_out, want_v)):
+        assert plane.sharding.is_equivalent_to(sharding, plane.ndim), plane.sharding
+        assert {sh.data.shape[2] for sh in plane.addressable_shards} == {heads_a_shard}
+        assert np.array_equal(np.asarray(plane), np.asarray(ref))
+    live = [0, 1, 3]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5)
 
 
 # -- spec rounds + preemption + prefix swap-in on the sharded pool -------------

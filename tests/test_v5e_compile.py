@@ -22,7 +22,7 @@ import pytest
 
 from gofr_tpu.models import LlamaConfig, llama
 
-pytestmark = pytest.mark.quick  # eight compiles of about three seconds; skips where no topology can be described
+pytestmark = pytest.mark.quick  # a dozen and a half compiles of three to ten seconds; skips where no topology can be described
 
 SLOTS, PAGE, PAGES_PER_SLOT = 32, 128, 9
 WIDTHS = {  # published widths AND depths: a pool small enough for fast memory is laid out otherwise
@@ -30,6 +30,8 @@ WIDTHS = {  # published widths AND depths: a pool small enough for fast memory i
                          num_layers=22, num_heads=32, num_kv_heads=4),
     "internlm2-1.8b-d128": dict(vocab_size=92544, hidden_size=2048, intermediate_size=8192,
                                 num_layers=24, num_heads=16, num_kv_heads=8),
+    "mistral-7b-l16-d128": dict(vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+                                num_layers=16, num_heads=32, num_kv_heads=8),
 }
 
 
@@ -134,6 +136,34 @@ def test_decode_with_the_paged_kernel_gathers_nothing_on_the_v5e(one_chip, no_co
         f"temporaries {temp} B (the XLA read path's were 76 MB at InternLM2's width)")
 
 
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_decode_appends_inside_the_kernel_where_it_can_address_the_rows(one_chip, no_compile_cache, width):
+    """At head_dim 128 (both benchmark widths) the decode step holds NO
+    scatter: its one ``attention`` custom call a layer takes both pool planes
+    and returns them aliased, so the layer scan's carry is written where it
+    lies, by the kernel. At head_dim 64 the kernel reads a padded copy of one
+    layer, nothing can be written through that, and the two scatters (typed
+    like the plane they alias) are still there. Nothing chose either: the
+    rule (``ops/attention.append_rides_in_kernel``) read the plane's shape."""
+    compiled, cache = _compile(one_chip, width, "decode")
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and re.search(r'op_name="[^"]*/attention[/"]', kernels[0]), kernels
+    plane = re.escape("bf16[%s]" % ",".join(str(x) for x in cache.k.shape))
+    scatters = [line.strip()[:120] for line in text.splitlines() if re.search(r" scatter\(", line)]
+    if cache.k.shape[4] % 128:
+        assert len(scatters) == 2 and all(re.search(plane, line) for line in scatters), scatters
+        assert "output_to_operand_aliasing" not in kernels[0]
+        return
+    assert not scatters, f"a scatter is back in the decode step: {scatters}"
+    assert not re.search(r'op_name="[^"]*/kv_append[/"]', text), "an operation under kv_append is back"
+    result, operands = kernels[0].split(" custom-call(", 1)
+    assert len(re.findall(plane, result)) == 2, f"the call does not return both planes: {result[:300]}"
+    aliased = re.search(r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\), \{2\}: \((\d+), \{\}\)\}", operands)
+    assert aliased and int(aliased.group(2)) == int(aliased.group(1)) + 1, (
+        f"the planes are not aliased through the call: {operands[-400:]}")
+
+
 # -- the engine's own decode chunk, read path chosen by the rule alone -----------
 
 
@@ -200,6 +230,8 @@ def test_engine_decode_chunk_holds_the_kernel_by_rule(one_chip, no_compile_cache
     kernels = [line for line in compiled.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all(re.search(r'op_name="[^"]*/attention[/"]', line) for line in kernels), kernels[:2]
     assert not re.search(r'op_name="[^"]*/kv_gather[/"]', compiled), "an operation under kv_gather is back"
+    assert not re.search(r'op_name="[^"]*/kv_append[/"]', compiled), "an operation under kv_append is back"
+    assert " scatter(" not in compiled, "the decode chunk scatters (heads of 128: the kernel appends)"
 
 
 def test_kernel_operations_are_named_by_the_program_alone(one_chip, no_compile_cache):
