@@ -1,6 +1,8 @@
 """Flagship TPU-serving example (reference has no model layer — this is the
-new capability, SURVEY.md §2.9): a Llama generate endpoint behind the
-continuous-batching engine, plus token streaming over websocket."""
+new capability, SURVEY.md §2.9): a generate endpoint behind the
+continuous-batching engine — a demo-sized Llama, or whatever decoder family
+the given configuration object belongs to — plus token streaming over
+websocket."""
 
 import os as _os
 import sys as _sys
@@ -9,15 +11,17 @@ _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.
 
 from gofr_tpu import App
 from gofr_tpu.config import EnvConfig
-from gofr_tpu.models import LlamaConfig, ModelSpec
+from gofr_tpu.models import LlamaConfig, ModelSpec, family_of
 
 
-def build_app(config=None, *, model_config: LlamaConfig | None = None, **engine_kw) -> App:
-    """``model_config`` replaces the demo-sized LlamaConfig — and the demo's
-    byte tokenizer with it, so prompts, results and streams are token ids
-    (a deployment names its own tokenizer in the ModelSpec). ``engine_kw``
-    go through to ``serve_model`` (kv_layout, slots, max_len, page_size,
-    ...) on top of the demo-sized defaults."""
+def build_app(config=None, *, model_config=None, **engine_kw) -> App:
+    """``model_config`` (a served family's config object: ``LlamaConfig``,
+    ``Cohere2MoeConfig``, ...) replaces the demo-sized LlamaConfig — and the
+    demo's byte tokenizer with it, so prompts, results and streams are token
+    ids (a deployment names its own tokenizer in the ModelSpec). The family
+    is the one the object belongs to. ``engine_kw`` go through to
+    ``serve_model`` (kv_layout, slots, max_len, page_size, ...) on top of
+    the demo-sized defaults."""
     import os
 
     folder = os.path.join(os.path.dirname(os.path.abspath(__file__)), "configs")
@@ -32,7 +36,7 @@ def build_app(config=None, *, model_config: LlamaConfig | None = None, **engine_
         cfg, tokenizer = LlamaConfig.tiny(vocab_size=300), ByteTokenizer()
     else:
         cfg, tokenizer = model_config, None
-    spec = ModelSpec("llama", cfg, task="generate", dtype=cfg.dtype, tokenizer=tokenizer)
+    spec = ModelSpec(family_of(cfg), cfg, task="generate", dtype=cfg.dtype, tokenizer=tokenizer)
     # EOS is disabled here because random weights emit any token — a real
     # checkpoint would keep the tokenizer's eos_token_id (build_engine wires
     # it automatically).

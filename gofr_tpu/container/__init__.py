@@ -104,6 +104,14 @@ class Container:
         # nested phases subtracted), flushed from the engines on every scrape
         m.new_counter("app_tpu_loop_phase_seconds_total", "device-loop host self time by phase (s)")
         m.new_counter("app_tpu_loop_phase_total", "device-loop phase entries by phase")
+        m.new_counter("app_tpu_moe_assignments_total",
+                      "token-to-expert assignments computed here, by held expert (expert)")
+        m.new_counter("app_tpu_moe_assignments_absent_total",
+                      "assignments to experts this rank does not hold (left out)")
+        m.new_counter("app_tpu_moe_experts_hit_total",
+                      "held experts with at least one assignment, summed over layer-steps")
+        m.new_counter("app_tpu_moe_layer_steps_total",
+                      "expert layers run (layers x program steps): the denominator")
         m.new_histogram("app_tpu_batch_occupancy", "occupied fraction of each device batch",
                         buckets=[0.1, 0.25, 0.5, 0.75, 0.9, 1.0])
         m.new_histogram("app_tpu_step_seconds", "device step wall time (s)")
@@ -348,6 +356,9 @@ class Container:
             phases = getattr(e, "_phases", None)
             if phases is not None:
                 phases.flush(self.metrics)
+            flush = getattr(e, "flush_step_counters", None)
+            if callable(flush):
+                flush(self.metrics)
         # spec-decode acceptance, divided at scrape time from raw
         # per-adapter (accepted, proposed) numerators summed across engines
         # — never an average of per-engine ratios

@@ -178,14 +178,18 @@ class CostModel:
     byte size. Pure arithmetic — every method is safe under any lock."""
 
     __slots__ = ("n_params", "weight_bytes", "kv_bytes_per_pos",
-                 "page_bytes", "page_size", "kv_dtype", "kv_shards")
+                 "page_bytes", "page_size", "kv_dtype", "kv_shards", "experts")
 
     def __init__(self, *, n_params: float, weight_bytes: float,
                  kv_bytes_per_pos: float, page_bytes: float = 0.0,
                  page_size: int = 0, kv_dtype: str = "bf16",
-                 kv_shards: int = 1):
+                 kv_shards: int = 1, experts: dict | None = None):
         self.n_params = float(n_params)
         self.weight_bytes = float(weight_bytes)
+        # a mixture-of-experts family's ``token_params(cfg)``: a token does
+        # not touch every parameter, a step does not read every weight
+        # (token_params / step_weight_bytes below). None = a dense family.
+        self.experts = experts
         # on a tp-sharded pool the engine passes PER-DEVICE byte figures
         # (1/kv_shards of the logical planes): every roofline this model
         # prices is a per-device bound, and the fleet rollup sums parts
@@ -195,19 +199,42 @@ class CostModel:
         self.kv_dtype = kv_dtype or "bf16"
         self.kv_shards = max(1, int(kv_shards))
 
+    def token_params(self) -> float:
+        """Parameters one token is multiplied with: all of them in a dense
+        family; in an expert family what every token meets (attention, shared
+        experts, router, head) plus the held experts it is routed to — in
+        expectation ``k * held / router_width`` a layer."""
+        ex = self.experts
+        if ex is None:
+            return self.n_params
+        routed = ex["k"] * ex["held"] / ex["router_width"]
+        return ex["always"] + ex["layers"] * routed * ex["expert"]
+
+    def step_weight_bytes(self, tokens: float) -> float:
+        """Weight bytes one program step over ``tokens`` tokens reads: all of
+        them in a dense family; in an expert family what every token meets
+        plus the held experts HIT — the expected number of distinct ones,
+        ``held * (1 - (1 - k / router_width) ** tokens)`` a layer."""
+        ex = self.experts
+        if ex is None:
+            return self.weight_bytes
+        item = self.weight_bytes / max(self.n_params, 1.0)
+        hit = ex["held"] * (1.0 - (1.0 - ex["k"] / ex["router_width"]) ** max(tokens, 0.0))
+        return item * (ex["always"] + ex["layers"] * hit * ex["expert"])
+
     def prefill(self, tokens: int) -> tuple[float, float]:
         """Batched prefill of ``tokens`` real prompt tokens (padding
         excluded): one weight pass + every position's KV write."""
-        flops = 2.0 * self.n_params * tokens
-        bytes_ = self.weight_bytes + tokens * self.kv_bytes_per_pos
+        flops = 2.0 * self.token_params() * tokens
+        bytes_ = self.step_weight_bytes(tokens) + tokens * self.kv_bytes_per_pos
         return flops, bytes_
 
     def chunk(self, chunk: int, offset: int) -> tuple[float, float]:
         """One prefill chunk at ``offset``: the chunk's weight pass and
         KV writes, plus the attention re-read of everything cached so
         far (chunked prefill's extra bandwidth cost vs one-shot)."""
-        flops = 2.0 * self.n_params * chunk
-        bytes_ = (self.weight_bytes
+        flops = 2.0 * self.token_params() * chunk
+        bytes_ = (self.step_weight_bytes(chunk)
                   + (offset + chunk) * self.kv_bytes_per_pos   # attn read
                   + chunk * self.kv_bytes_per_pos)             # writes
         return flops, bytes_
@@ -219,8 +246,8 @@ class CostModel:
         — pages-touched * page_size on paged, positions on slot, a
         dispatch-time floor since history grows within the chunk); each
         emitted token writes its KV row."""
-        flops = 2.0 * self.n_params * lanes * k
-        bytes_ = (k * self.weight_bytes
+        flops = 2.0 * self.token_params() * lanes * k
+        bytes_ = (k * self.step_weight_bytes(lanes)
                   + k * hist_positions * self.kv_bytes_per_pos
                   + lanes * k * self.kv_bytes_per_pos)
         return flops, bytes_

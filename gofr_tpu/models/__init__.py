@@ -9,6 +9,7 @@ from gofr_tpu.models import bert, gpt2, llama, mixtral, vit
 from gofr_tpu.models.base import (
     ModelSpec,
     cast_floats,
+    family_of,
     get_family,
     param_bytes,
     param_count,
@@ -25,6 +26,7 @@ register_family("llama", llama)
 register_family("mixtral", mixtral)
 register_family("bert", bert)
 register_family("vit", vit)
+register_family("cohere2_moe", "gofr_tpu.models.cohere2_moe")  # imported when first asked for
 
 __all__ = [
     "ModelSpec",
@@ -39,6 +41,7 @@ __all__ = [
     "bert",
     "vit",
     "cast_floats",
+    "family_of",
     "get_family",
     "param_bytes",
     "param_count",

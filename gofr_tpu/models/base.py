@@ -75,14 +75,42 @@ class ModelSpec:
 
 
 _FAMILIES: dict[str, Any] = {}
+# Families whose module is imported when they are first asked for (a name →
+# its module's path): a process that serves another family never pays for them.
+_LAZY_FAMILIES: dict[str, str] = {}
 
 
 def register_family(name: str, module: Any) -> None:
-    _FAMILIES[name] = module
+    """``module`` is the family's module, or the dotted path of one to import
+    on first use."""
+    if isinstance(module, str):
+        _LAZY_FAMILIES[name] = module
+    else:
+        _FAMILIES[name] = module
 
 
 def get_family(name: str):
+    if name not in _FAMILIES and name in _LAZY_FAMILIES:
+        import importlib
+
+        _FAMILIES[name] = importlib.import_module(_LAZY_FAMILIES[name])
     try:
         return _FAMILIES[name]
     except KeyError:
-        raise KeyError(f"unknown model family {name!r}; registered: {sorted(_FAMILIES)}") from None
+        raise KeyError(
+            f"unknown model family {name!r}; registered: "
+            f"{sorted(set(_FAMILIES) | set(_LAZY_FAMILIES))}") from None
+
+
+def family_of(config: Any) -> str:
+    """The registered family a config object belongs to: the one whose module
+    defines the config's class (``LlamaConfig`` lives in ``models/llama.py``,
+    family ``llama``)."""
+    module = type(config).__module__
+    for name, fam in _FAMILIES.items():
+        if getattr(fam, "__name__", None) == module:
+            return name
+    for name, path in _LAZY_FAMILIES.items():
+        if path == module:
+            return name
+    raise KeyError(f"no registered model family defines {type(config).__name__} ({module})")

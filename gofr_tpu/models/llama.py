@@ -581,17 +581,22 @@ def _paged_views(cfg: LlamaConfig, cache, layer, table):
             dequantize_view(*gkv(cache.v, cache.vs, layer, table), cfg.dtype))
 
 
-def _append_attend_paged(cache, layer, table, positions, q, k, v):
+def _append_attend_paged(cache, layer, table, positions, q, k, v, window=None):
     """One decode token per slot, by pool kind: append its K/V at
-    ``positions``, attend over ``positions + 1`` → (cache, attn)."""
+    ``positions``, attend over ``positions + 1`` → (cache, attn). ``window``
+    (a family with sliding-window layers; the dense pool only) bounds the
+    attention to the last ``window`` positions; None hands the ops nothing."""
+    sliding = {} if window is None else {"window": window}
     if isinstance(cache, PagedKVCache):
         if append_rides_in_kernel(cache.k):  # one kernel call writes the row and attends
             attn, k_pool, v_pool = paged_decode_append_attention(
-                q, k, v, cache.k, cache.v, layer, table, positions)
+                q, k, v, cache.k, cache.v, layer, table, positions, **sliding)
         else:
             k_pool, v_pool = append_tokens_paged(cache.k, cache.v, layer, table, positions, k, v)
-            attn = paged_decode_attention(q, k_pool, v_pool, layer, table, positions + 1)
+            attn = paged_decode_attention(q, k_pool, v_pool, layer, table, positions + 1, **sliding)
         return PagedKVCache(k=k_pool, v=v_pool), attn
+    if window is not None:
+        raise ValueError("a sliding window is served on the dense paged pool only")
     q4c = isinstance(cache, Q4PagedKVCache)
     atp = append_tokens_paged_q4 if q4c else append_tokens_paged_q
     pda = paged_decode_attention_q4 if q4c else paged_decode_attention_q

@@ -76,6 +76,7 @@ def mha_attention(
     bias: jnp.ndarray | None = None,
     scale: float | None = None,
     backend: str = "auto",
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     """Full (prefill) attention.
 
@@ -84,10 +85,15 @@ def mha_attention(
     ``q_offset`` shifts query positions (per-batch int array or scalar) so a
     chunked prefill at cache offset t attends causally as positions t..t+Sq.
     ``kv_lengths`` [B] masks padded key positions. ``bias`` is an additive
-    [B, 1|Hq, Sq, Skv] mask/ALiBi-style term.
+    [B, 1|Hq, Sq, Skv] mask/ALiBi-style term. ``window`` (a scalar, traced
+    or not; needs ``causal``) is a sliding window: key j is visible to the
+    query at position i iff ``i - window < j <= i``; None = full attention,
+    the trace as it was.
     """
     backend = resolve_backend(backend)
-    if backend == "pallas" and bias is None:  # kernel has no bias path
+    if window is not None and not causal:
+        raise ValueError("a sliding window is defined on causal attention only")
+    if backend == "pallas" and bias is None and window is None:  # kernel has no bias or window path
         if not isinstance(q_offset, jnp.ndarray):
             q_offset = jnp.asarray(q_offset, jnp.int32)
         if kv_lengths is None:
@@ -106,10 +112,15 @@ def mha_attention(
         if isinstance(q_offset, jnp.ndarray) and q_offset.ndim == 1:
             q_pos = jnp.arange(sq)[None, :] + q_offset[:, None]  # [B, Sq]
             causal_mask = q_pos[:, :, None] >= jnp.arange(skv)[None, None, :]  # [B, Sq, Skv]
+            if window is not None:
+                causal_mask &= q_pos[:, :, None] - window < jnp.arange(skv)[None, None, :]
             causal_mask = causal_mask[:, None, None]  # [B, 1, 1, Sq, Skv]
         else:
             q_pos = jnp.arange(sq)[:, None] + q_offset
-            causal_mask = (q_pos >= jnp.arange(skv)[None, :])[None, None, None]
+            causal_mask = q_pos >= jnp.arange(skv)[None, :]
+            if window is not None:
+                causal_mask &= q_pos - window < jnp.arange(skv)[None, :]
+            causal_mask = causal_mask[None, None, None]
         mask = causal_mask
     if kv_lengths is not None:
         len_mask = jnp.arange(skv)[None, :] < kv_lengths[:, None]  # [B, Skv]
@@ -193,11 +204,13 @@ def decode_attention(
     *,
     scale: float | None = None,
     backend: str = "auto",
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     """Single-step decode: q [B, Hq, D] against a head-major cache
-    [B, Hkv, Smax, D], attending to positions < lengths[b]. Returns
-    [B, Hq, D]."""
-    if resolve_backend(backend, op="decode") == "pallas":
+    [B, Hkv, Smax, D], attending to positions < lengths[b] — with a
+    ``window``, to the last ``window`` of them (the query sits at
+    ``lengths - 1``; XLA path only). Returns [B, Hq, D]."""
+    if window is None and resolve_backend(backend, op="decode") == "pallas":
         smax = k_cache.shape[2]
         if slot_decode_kernel_ok(smax):
             from gofr_tpu.ops.pallas import interpret_mode
@@ -221,6 +234,8 @@ def decode_attention(
     qg = q.reshape(b, hkv, hq // hkv, d)  # head h groups under kv head h // G
     scores = jnp.einsum("bkgd,bktd->bkgt", qg, k_cache).astype(jnp.float32) * scale
     mask = jnp.arange(smax)[None, :] < lengths[:, None]  # [B, Smax]
+    if window is not None:
+        mask &= jnp.arange(smax)[None, :] >= lengths[:, None] - window
     scores = jnp.where(mask[:, None, None], scores, NEG_INF)
     probs = _softmax(scores)
     out = jnp.einsum("bkgt,bktd->bkgd", probs.astype(v_cache.dtype), v_cache)
@@ -284,14 +299,15 @@ def _kv_shard_ctx(q: jnp.ndarray, pool: jnp.ndarray):
     return ctx
 
 
-def _shard_paged_call(impl, ctx, heads, pools, layer, table, lengths, pools_out: int = 0):
-    """Run ``impl(*heads, *pools, layer, table, lengths)`` per-shard: every
+def _shard_paged_call(impl, ctx, heads, pools, layer, table, lengths, pools_out: int = 0,
+                      window=None):
+    """Run ``impl(*heads, *pools, layer, table, lengths[, window])`` per-shard: every
     ``heads`` operand ([N, H, D]: q, a step's new K/V) splits on its head
     axis (dim 1) and every whole pool plane on its KV-head axis
     (paged.plane_partition_spec), layer/table/lengths replicated, output
     head-sharded (no reduce — see module note above). An ``impl`` that
     writes returns its first ``pools_out`` planes after the output; they
-    come back sharded as they went in."""
+    come back sharded as they went in. A ``window`` rides replicated, last."""
     from jax.sharding import PartitionSpec as P
 
     from gofr_tpu.ops.paged import plane_partition_spec
@@ -299,13 +315,16 @@ def _shard_paged_call(impl, ctx, heads, pools, layer, table, lengths, pools_out:
     ax = ctx.axis
     head_spec = P(None, ax, None)
     pool_specs = tuple(plane_partition_spec(p.ndim, ax) for p in pools)
+    scalars = (jnp.asarray(layer, jnp.int32), table, lengths)
+    if window is not None:
+        scalars += (jnp.asarray(window, jnp.int32),)
     return jax.shard_map(
         impl,
         mesh=ctx.mesh,
-        in_specs=(head_spec,) * len(heads) + pool_specs + (P(), P(), P()),
+        in_specs=(head_spec,) * len(heads) + pool_specs + (P(),) * len(scalars),
         out_specs=(head_spec,) + pool_specs[:pools_out] if pools_out else head_spec,
         check_vma=False,
-    )(*heads, *pools, jnp.asarray(layer, jnp.int32), table, lengths)
+    )(*heads, *pools, *scalars)
 
 
 def _require_kernel_page(pool: jnp.ndarray) -> None:
@@ -463,13 +482,15 @@ def paged_decode_attention(
     *,
     scale: float | None = None,
     backend: str = "auto",
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     ctx = _kv_shard_ctx(q, k_pool)
     if ctx is not None:
         impl = partial(_paged_decode_attention_local, scale=scale, backend=backend)
-        return _shard_paged_call(impl, ctx, (q,), (k_pool, v_pool), layer, table, lengths)
+        return _shard_paged_call(impl, ctx, (q,), (k_pool, v_pool), layer, table, lengths,
+                                 window=window)
     return _paged_decode_attention_local(
-        q, k_pool, v_pool, layer, table, lengths, scale=scale, backend=backend,
+        q, k_pool, v_pool, layer, table, lengths, window, scale=scale, backend=backend,
     )
 
 
@@ -480,6 +501,7 @@ def _paged_decode_attention_local(
     layer,
     table: jnp.ndarray,
     lengths: jnp.ndarray,
+    window: jnp.ndarray | int | None = None,
     *,
     scale: float | None = None,
     backend: str = "auto",
@@ -495,6 +517,9 @@ def _paged_decode_attention_local(
     block tables (ops.pallas.paged_decode); 'xla' materializes each slot's
     logical view with one gather (ops.paged.gather_kv) and reuses the dense
     decode path — correct everywhere, but pays an extra HBM round trip.
+    ``window`` (a scalar, an operand of the kernel): the lane's query at
+    ``lengths - 1`` sees the last ``window`` positions only; None = all of
+    them, and the kernel's trace as it is without the argument.
     """
     if resolve_backend(backend, op="paged_decode") == "pallas":
         from gofr_tpu.ops.pallas import interpret_mode
@@ -503,12 +528,12 @@ def _paged_decode_attention_local(
         _require_kernel_page(k_pool)
         return pallas_paged(
             q, k_pool, v_pool, layer, table, lengths,
-            scale=scale, interpret=interpret_mode(),
+            scale=scale, interpret=interpret_mode(), window=window,
         )
     from gofr_tpu.ops.paged import gather_kv
 
     k_view, v_view = gather_kv(k_pool, v_pool, layer, table)
-    return decode_attention(q, k_view, v_view, lengths, scale=scale, backend="xla")
+    return decode_attention(q, k_view, v_view, lengths, scale=scale, backend="xla", window=window)
 
 
 def append_rides_in_kernel(k_pool: jnp.ndarray, backend: str = "auto") -> bool:
@@ -540,11 +565,13 @@ def paged_decode_append_attention(
     positions: jnp.ndarray,  # [N]
     *,
     scale: float | None = None,
+    window: jnp.ndarray | int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``append_tokens_paged`` at ``positions`` then ``paged_decode_attention``
-    over ``positions + 1``, as ONE kernel call that updates the planes where
-    they lie → (attn, k_pool, v_pool). For pools ``append_rides_in_kernel``
-    admits; the write's time is the ``attention`` scope's."""
+    over ``positions + 1`` (the last ``window`` of them, if given), as ONE
+    kernel call that updates the planes where they lie → (attn, k_pool,
+    v_pool). For pools ``append_rides_in_kernel`` admits; the write's time
+    is the ``attention`` scope's."""
     from gofr_tpu.ops.pallas import interpret_mode
     from gofr_tpu.ops.pallas.paged_decode import paged_decode_append_attention as fused
 
@@ -552,5 +579,5 @@ def paged_decode_append_attention(
     ctx = _kv_shard_ctx(q, k_pool)
     if ctx is not None:
         return _shard_paged_call(impl, ctx, (q, k_new, v_new), (k_pool, v_pool),
-                                 layer, table, positions, pools_out=2)
-    return impl(q, k_new, v_new, k_pool, v_pool, layer, table, positions)
+                                 layer, table, positions, pools_out=2, window=window)
+    return impl(q, k_new, v_new, k_pool, v_pool, layer, table, positions, window)

@@ -20,11 +20,15 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
 
 
 def layer_norm(
-    x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray, eps: float = 1e-12
+    x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray | None, eps: float = 1e-12
 ) -> jnp.ndarray:
+    """Mean-subtracted LayerNorm; ``bias=None`` is the weight-only form
+    (Cohere's: no bias anywhere in the block)."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
-    normed = (xf - mean) / jnp.sqrt(var + eps)
-    return (normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
+    normed = (xf - mean) / jnp.sqrt(var + eps) * weight.astype(jnp.float32)
+    if bias is not None:
+        normed = normed + bias.astype(jnp.float32)
+    return normed.astype(dtype)

@@ -94,7 +94,8 @@ def _tile_rows(dtype) -> int:
     return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool):
+def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool,
+                         windowed: bool = False):
     """One grid step is one lane; its pages are a loop, not grid steps.
 
     The copies form one chain over the whole call: page ``j`` of a lane
@@ -110,7 +111,16 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
     attended WITH the row: nothing is read back from HBM after the write),
     staged, and copied to the plane. The wait for that copy is put off by
     two lanes — the lane after next waits before it stages its own — and
-    the last lane waits for what is left."""
+    the last lane waits for what is left.
+
+    ``windowed``: a last scalar-prefetch operand win [1] bounds what a lane
+    sees to its last ``win`` positions, ``[length - win, length)``. Its page
+    loop then STARTS at the page that holds position ``length - win`` (pages
+    wholly behind the window cost no copy and no arithmetic, like pages past
+    the length) and the positions of that first page that lie behind the
+    window are masked. The chain of copies runs over the pages that are
+    visited. A window that never binds (``win >= length``) visits what the
+    unwindowed kernel visits."""
     # scalar prefetch (SMEM): ln [N] positions a lane attends (the new token's
     # included), table [N, MaxP] clamped into the pool, layer [1]; with
     # ``append`` wp [N] the page a lane's new row goes to (>= pool: it writes
@@ -120,6 +130,9 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
     # every lane. The planes [L, P, Hkv, page, d] stay where they lie; when
     # they are written the aliased outputs ARE the planes, read and written
     # through one ref each.
+    if windowed:  # the window rides last among the scalars
+        n_scalars = 6 if append else 4
+        win_ref, refs = refs[n_scalars - 1], refs[:n_scalars - 1] + refs[n_scalars:]
     if append:
         (ln_ref, table_ref, layer_ref, wp_ref, off_ref, q_ref, pos_ref, knew_ref, vnew_ref,
          _, _, o_ref, k_hbm, v_hbm, *scratch) = refs
@@ -145,13 +158,24 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
         for copy in page_copies(lane, j, buf):
             copy.start()
 
+    if windowed:
+        def seen_from(lane):  # the first position a lane's window holds
+            return jnp.maximum(ln_ref[lane] - win_ref[0], 0)
+
+        def first_page(lane):
+            return seen_from(lane) // page
+    else:
+        def first_page(lane):
+            return 0
+
     @pl.when(bi == 0)
     def _():
         seq_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_page(0), 0)
 
     seq = seq_ref[0]
-    mine = jnp.maximum(pl.cdiv(length, page), 1)  # pages this lane copies
+    first = first_page(bi)
+    mine = jnp.maximum(pl.cdiv(length, page), 1)  # one past the last page this lane copies
     init_softmax_scratch(0, acc_ref, m_ref, l_ref)
 
     if append:
@@ -194,13 +218,15 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
                 copy.start()
 
     def attend(j, carry):
-        buf = (seq + j) % 2
+        buf = (seq + j - first) % 2 if windowed else (seq + j) % 2
         last = j + 1 == mine
         next_lane = jnp.where(last, bi + 1, bi)
 
         @pl.when(next_lane < lanes)
         def _():
-            start(next_lane, jnp.where(last, 0, j + 1), 1 - buf)
+            # the next lane's first page (its index clamped: only read under the `when`)
+            ahead = first_page(jnp.minimum(bi + 1, lanes - 1)) if windowed else 0
+            start(next_lane, jnp.where(last, ahead, j + 1), 1 - buf)
 
         for copy in page_copies(bi, j, buf):
             copy.wait()
@@ -220,13 +246,15 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
                 q_ref[...], k_buf[buf].reshape(hkv * page, d),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             ) * scale  # [Hq, Hkv*page]
-            s = jnp.where(pos_ref[...] + j * page < length, s, NEG_INF)
+            where = pos_ref[...] + j * page
+            seen = (where < length) & (where >= seen_from(bi)) if windowed else where < length
+            s = jnp.where(seen, s, NEG_INF)
             softmax_block_update(s, v_buf[buf].reshape(hkv * page, d), acc_ref, m_ref, l_ref)
 
         return carry
 
-    jax.lax.fori_loop(0, mine, attend, 0)
-    seq_ref[0] = seq + mine
+    jax.lax.fori_loop(first, mine, attend, 0)
+    seq_ref[0] = seq + mine - first if windowed else seq + mine
 
     def write(out):
         o_ref[...] = out.astype(o_ref.dtype)
@@ -240,10 +268,12 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
             wait_tiles(bi, slot)
 
 
-def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, interpret):
+def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, interpret,
+                       window=None):
     """The kernel's one call. ``new`` is None (read only → attn) or
     ``(k_new, v_new, write_page, write_row)`` (→ attn, k_pool, v_pool, the
-    planes aliased)."""
+    planes aliased). ``window`` (a scalar, traced or not) becomes one more
+    scalar-prefetch operand; None leaves the call as it is without one."""
     n, hq, d = q.shape
     _, pool, hkv, page, _ = k_pool.shape
     _, maxp = table.shape
@@ -289,8 +319,11 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, 
             pltpu.VMEM((2, hkv, rows, d), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ]
+    if window is not None:
+        prefetch += [jnp.maximum(jnp.asarray(window, jnp.int32), 1).reshape(1)]
     planes = len(prefetch) + len(operands)  # the K plane's place among the inputs
-    kernel = functools.partial(_paged_decode_kernel, scale=scale, page=page, pool=pool, append=append)
+    kernel = functools.partial(_paged_decode_kernel, scale=scale, page=page, pool=pool, append=append,
+                               **({} if window is None else {"windowed": True}))
     return pl.pallas_call(
         kernel,
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
@@ -321,8 +354,10 @@ def paged_decode_attention(
     *,
     scale: float | None = None,
     interpret: bool = False,
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
-    """Single-step decode against the paged pool → [N, Hq, D]."""
+    """Single-step decode against the paged pool → [N, Hq, D]; with a
+    ``window``, over each lane's last ``window`` positions."""
     d = q.shape[-1]
     if d % _LANES:
         # A copy cannot cut a page whose rows are narrower than the lane
@@ -338,9 +373,10 @@ def paged_decode_attention(
 
         return paged_decode_attention(
             widen(q), layer_of(k_pool), layer_of(v_pool), 0, table, lengths,
-            scale=scale if scale is not None else 1.0 / (d**0.5), interpret=interpret)[..., :d]
+            scale=scale if scale is not None else 1.0 / (d**0.5), interpret=interpret,
+            **({} if window is None else {"window": window}))[..., :d]
     return _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, None,
-                              scale=scale, interpret=interpret)
+                              scale=scale, interpret=interpret, window=window)
 
 
 def append_in_kernel(k_pool: jnp.ndarray) -> bool:
@@ -360,6 +396,7 @@ def paged_decode_append_attention(
     layer,                   # scalar layer index
     table: jnp.ndarray,      # [N, MaxP] int32, OOB entries == P
     positions: jnp.ndarray,  # [N] where each lane's new row goes; it attends positions + 1
+    window: jnp.ndarray | int | None = None,  # the last ``window`` of them; None = all
     *,
     scale: float | None = None,
     interpret: bool = False,
@@ -381,7 +418,7 @@ def paged_decode_append_attention(
     write_page, write_row = _locate_append(table, positions, k_pool.shape[3], k_pool.shape[1])
     return tuple(_paged_decode_call(
         q, k_pool, v_pool, layer, table, positions + 1, (k_new, v_new, write_page, write_row),
-        scale=scale, interpret=interpret))
+        scale=scale, interpret=interpret, window=window))
 
 
 def _paged_decode_q_kernel(

@@ -1,4 +1,5 @@
-"""Rotary position embeddings (RoPE), Llama-style half-split layout.
+"""Rotary position embeddings (RoPE): Llama-style half-split layout, and the
+GPT-J interleaved-pair layout (``apply_rope_interleaved``).
 
 The cos/sin table is precomputed once per model (static shapes keep it out
 of the per-step compile) and gathered by position ids — decode steps index
@@ -48,3 +49,35 @@ def apply_rope(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return rotated.astype(x.dtype)
+
+
+def rope_inv_freq(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
+    """The ``head_dim // 2`` inverse frequencies ``theta ** (-2i / head_dim)``."""
+    half = head_dim // 2
+    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+
+@scoped("qkv_rope")
+def apply_rope_interleaved(
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    inv_freq: jnp.ndarray,
+    factor: jnp.ndarray | float = 1.0,
+) -> jnp.ndarray:
+    """Rotate ``x`` [..., seq, heads, head_dim] at ``positions`` [..., seq] in
+    the GPT-J convention: the pairs are NEIGHBOURS, (x0, x1), (x2, x3), ...,
+    pair ``i`` turning by ``positions * inv_freq[i] * factor``. The angles
+    are computed from the positions (no table: a 200k-position model would
+    carry 100 MB of it). ``factor`` scales every angle: 0 is the identity
+    rotation, exactly — which is how one scanned layer body serves a layer
+    with no positional embedding beside rotary ones."""
+    # every pair's angle on both of its lanes; the partner of lane 2i is 2i+1
+    # and the other way round: two lane rotations and a select on parity, no
+    # reshape of the lane axis into pairs
+    angles = positions[..., None].astype(jnp.float32) * jnp.repeat(inv_freq, 2) * factor
+    cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, head_dim]: broadcast over heads
+    sin = jnp.sin(angles)[..., None, :]
+    xf = x.astype(jnp.float32)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)

@@ -378,6 +378,14 @@ def process_decode(eng) -> bool:
         # the result just landed on the host: everything from here on is
         # fold time, not device time (perf plane separates the two)
         pstep.t_ready = time.monotonic()
+    if eng._step_counters and kind in ("plain", "prefill", "chunk"):
+        # a counting family's tail of the token array (tpu/programs.py):
+        # [S, K] a decode chunk, [S] a prefill
+        tail = host[-len(eng._step_counters):]
+        if kind == "plain":
+            eng._step_counts["decode"] += tail.sum(axis=1)
+        else:
+            eng._step_counts["prefill"] += tail
     if eng._poisoned:
         # stop() declared this thread wedged and already failed/cleared
         # everything; the slot/page state now belongs to the caller.

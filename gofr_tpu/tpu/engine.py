@@ -1130,6 +1130,9 @@ class GenerateEngine(_EngineBase):
             )
         if kv_layout == "paged" and not hasattr(family, "make_paged_cache"):
             raise ValueError(f"model family {family.__name__} has no paged-cache support")
+        if kv_layout == "slot" and not hasattr(family, "make_cache"):
+            raise ValueError(f"model family {family.__name__} has no slot-cache support "
+                             "(kv_layout='paged' serves it)")
         self.kv_layout = kv_layout
 
         # Engine role (disaggregated serving; tpu/handoff.py): "both"
@@ -1349,6 +1352,9 @@ class GenerateEngine(_EngineBase):
                     page_size=page_size if kv_layout == "paged" else 0,
                     kv_dtype=self.kv_quantize or "bf16",
                     kv_shards=shards,
+                    # an expert family says how a token meets its parameters
+                    experts=(family.token_params(cfg)
+                             if hasattr(family, "token_params") else None),
                 ),
                 str(dev_kind))
         except Exception as e:  # pragma: no cover - meter must not gate serving
@@ -1416,6 +1422,14 @@ class GenerateEngine(_EngineBase):
         # request's engine.prefill span carry it (docs/observability.md)
         self._dispatch_seq = 0
         self._phases = LoopPhases()  # host time of _loop by phase, for /metrics and the profiler
+        # what a counting family's steps report (models/cohere2_moe.step_counters:
+        # (counter, labels) a row), summed as the token readbacks bring them in
+        # (decode.process_decode) and flushed at scrape (flush_step_counters)
+        counters = getattr(family, "step_counters", None)
+        self._step_counters = tuple(counters(cfg)) if callable(counters) else ()
+        self._step_counts = {phase: np.zeros(len(self._step_counters), np.int64)
+                             for phase in ("prefill", "decode")}
+        self._step_counts_flushed = {phase: c.copy() for phase, c in self._step_counts.items()}
         self._prev_last = None  # device-resident [slots] last-sampled-token carry
         self._spec_carry = None  # device-resident ([slots] token, [slots] hlen)
 
@@ -1714,6 +1728,18 @@ class GenerateEngine(_EngineBase):
         with platform_hint(getattr(self.tpu, "platform", None)):
             fused = isinstance(cache, PagedKVCache) and append_rides_in_kernel(cache.k)
         return "fused" if fused else "scatter"
+
+    def flush_step_counters(self, metrics) -> None:
+        """Scrape-time export of a counting family's step counters (the
+        loop-phase counters' discipline): add what the readbacks brought in
+        since the last call, by the kind of program that counted it
+        (``phase`` = ``prefill`` — whole and chunked — or ``decode``)."""
+        for phase, live in self._step_counts.items():
+            counts = live.copy()
+            for (name, labels), now, done in zip(
+                    self._step_counters, counts, self._step_counts_flushed[phase]):
+                metrics.increment_counter(name, float(now - done), phase=phase, **labels)
+            self._step_counts_flushed[phase] = counts
 
     def autotune_report(self) -> dict:
         """Which backend serves this engine's decode op — and, for a paged

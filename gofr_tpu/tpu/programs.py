@@ -53,6 +53,14 @@ pool-array argument (dynamic, like ``params`` — uploads and live
 hot-swap never recompile). With adapters off, layouts and traces are
 byte-identical to the above.
 
+A family that counts what its steps do (``family.step_counters(cfg)``: the
+mixture-of-experts family's routing counts) returns one int32 vector more
+from ``prefill_paged`` / ``decode_step_paged``; the paged programs then hand
+it back INSIDE the token array the host already reads — ``[nb + S]`` from a
+prefill, ``[n + S, K]`` from a decode chunk (S counters a step, after the
+rows the fold reads) — so counting adds no readback and no synchronisation.
+Every other family's programs are what they were.
+
 Backend resolution is a TRACE-time property of these programs: the decode
 attention ops inside them resolve ``backend="auto"`` when a program first
 traces (warmup), by the one rule in ``ops/attention.resolve_backend``
@@ -227,6 +235,11 @@ def build_programs(
     def _akw(sel, ad):
         return {"adapters": (sel, ad[0], ad[1], ad[2])} if adapters else {}
 
+    def _with_counts(toks, counts):
+        """A counting family's step counters ride behind the tokens (module
+        docstring); every other family returns none and keeps its array."""
+        return jnp.concatenate([toks, *counts]) if counts else toks
+
     ts = (top_k, top_p)
     Wp = pages_per_slot
     # paged + spec adds one trailing slot-id column after the block-table
@@ -272,13 +285,13 @@ def build_programs(
             tokens, lengths, rows, _, temps, step, sel = unpack_prefill(
                 packed, W, adapters=adapters)
             key = jax.random.fold_in(base_key, step)
-            logits, kv = family.prefill_paged(
+            logits, kv, *counts = family.prefill_paged(
                 cfg, params, tokens, lengths, kv, rows[:, :Wp], **pf,
                 **_akw(sel, ad))
             toks = sample_token(logits, key, temperature=temps, top_k=ts[0], top_p=ts[1])
             if tuple_cache:
                 hist = _seed_hist(hist, rows[:, Wp], tokens, lengths, toks)
-            return toks, _join(kv, hist)
+            return _with_counts(toks, counts), _join(kv, hist)
 
         @partial(jax.jit, donate_argnums=(2,))
         def _chunk_prefill(params, base_key, cache, packed, ad=None):
@@ -286,7 +299,7 @@ def build_programs(
             tokens, lengths, rows, offsets, temps, step, sel = unpack_prefill(
                 packed, W, chunked=True, adapters=adapters)
             key = jax.random.fold_in(base_key, step)
-            logits, kv = family.prefill_paged(
+            logits, kv, *counts = family.prefill_paged(
                 cfg, params, tokens, lengths, kv, rows[:, :Wp], offsets,
                 **_akw(sel, ad)
             )
@@ -294,7 +307,7 @@ def build_programs(
             if tuple_cache:
                 hist = _seed_hist(hist, rows[:, Wp], tokens, lengths, toks,
                                   offsets)
-            return toks, _join(kv, hist)
+            return _with_counts(toks, counts), _join(kv, hist)
 
         chunk_prefill = _chunk_prefill
 
@@ -311,16 +324,17 @@ def build_programs(
 
             def body(carry, _):
                 toks, pos, kv, key = carry
-                logits, kv = family.decode_step_paged(
+                logits, kv, *counts = family.decode_step_paged(
                     cfg, params, toks, pos, kv, table, **_akw(sel, ad))
                 key, sub = jax.random.split(key)
                 nxt = sample_token(logits, sub, temperature=temps, top_k=ts[0], top_p=ts[1])
-                return (nxt, pos + 1, kv, key), nxt
+                return (nxt, pos + 1, kv, key), (nxt, *counts)
 
-            (toks, pos, kv, key), out = jax.lax.scan(
+            (toks, pos, kv, key), (out, *counts) = jax.lax.scan(
                 body, (tokens, positions, kv, key), None, length=steps
             )
-            return out.T, toks, _join(kv, hist)  # [slots, K], [slots] carry
+            out = jnp.concatenate([out, *counts], axis=1) if counts else out
+            return out.T, toks, _join(kv, hist)  # [slots (+ counters), K], [slots] carry
 
         if spec_tokens:
             g = spec_tokens

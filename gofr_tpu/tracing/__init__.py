@@ -540,6 +540,14 @@ def tracer_from_config(config, logger, service_name: str) -> Tracer:
 SCOPES = ("embed", "qkv_rope", "kv_append", "kv_gather", "attention",
           "o_proj", "mlp", "lm_head", "sample")
 
+# The phases INSIDE ``mlp`` of a family whose feed-forward is a mixture of
+# experts (models/cohere2_moe.py, ops/moe.moe_ffn_held): the router with its
+# top-k, the dispatch (sort, gather) and the combine; the grouped product over
+# the experts held; the shared experts. A second closed list: a reader that
+# knows only SCOPES files these operations under ``mlp``, the innermost name
+# it knows.
+MOE_SCOPES = ("moe_router", "moe_experts", "moe_shared")
+
 # What the engine's device loop does on the host, a closed list (the loop's
 # own comments say which lines belong to which).
 LOOP_PHASES = ("control", "admit", "dispatch_prefill", "dispatch_decode",
@@ -547,10 +555,12 @@ LOOP_PHASES = ("control", "admit", "dispatch_prefill", "dispatch_decode",
 
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for a name of :data:`SCOPES`. Metadata only:
-    it changes no operation of the compiled program."""
-    if name not in SCOPES:
-        raise ValueError(f"unknown program scope {name!r}; the list is tracing.SCOPES")
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES` or
+    :data:`MOE_SCOPES`. Metadata only: it changes no operation of the
+    compiled program."""
+    if name not in SCOPES and name not in MOE_SCOPES:
+        raise ValueError(
+            f"unknown program scope {name!r}; the lists are tracing.SCOPES and tracing.MOE_SCOPES")
     import jax
 
     return jax.named_scope(name)

@@ -256,3 +256,98 @@ def test_kernel_operations_are_named_by_the_program_alone(one_chip, no_compile_c
     assert first and all(first.values()), first
     assert _located(_engine_decode_chunk(one_chip)) == first
     assert _located(_engine_decode_chunk(one_chip, traced_before=kernel_alone)) == first
+
+
+# -- PR 34: a second family shares the kernel and the paged helpers -----------------------
+
+
+def _kernel_operands(text: str) -> list[int]:
+    """Operand counts of the compiled program's kernel calls."""
+    counts = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            operands = line.split(" custom-call(", 1)[1].split("), custom_call_target", 1)[0]
+            counts.append(len(re.findall(r"%[\w.\-]+", operands)))
+    return counts
+
+
+@pytest.mark.parametrize("width,operands", [("mistral-7b-l16-d128", 11), ("llama-1b-d64", 7)])
+def test_llama_programs_carry_no_window_and_no_expert_scope(one_chip, no_compile_cache, width, operands):
+    """The accepted configurations keep their programs: the llama family's
+    kernel call takes what it took — lengths, table, layer (+ write page and
+    row), q, the head mask (+ the step's K and V), the two planes: 11 operands
+    fused, 7 read-only — and NO window operand, and no operation of its decode
+    or prefill program sits under a ``tracing.MOE_SCOPES`` name."""
+    from gofr_tpu import tracing
+
+    decode = _compile(one_chip, width, "decode")[0].as_text()
+    assert _kernel_operands(decode) == [operands], _kernel_operands(decode)
+    for program in (decode, _compile(one_chip, width, "prefill")[0].as_text()):
+        for name in tracing.MOE_SCOPES:
+            assert not re.search(r'op_name="[^"]*/%s[/"]' % name, program), name
+
+
+CELL_SLOTS, CELL_MAX_LEN, CELL_BUCKET = 128, 1536, 1024  # benchmarks/cells/command-a-plus-…: the shape it is timed at
+
+
+@pytest.fixture(scope="module")
+def cohere2_programs(one_chip):
+    """The new family's three served programs at the benchmark cell's engine
+    shape and the configuration's published widths, lowered as the engine
+    lowers them (tpu/programs.build_programs) for the described chip."""
+    from gofr_tpu.models import get_family
+    from gofr_tpu.models.cohere2_moe import Cohere2MoeConfig
+    from gofr_tpu.ops import pallas
+    from gofr_tpu.tpu.programs import build_programs
+
+    fam = get_family("cohere2_moe")
+    cfg = Cohere2MoeConfig(vocab_size=32768, num_layers=4, experts_held=16)
+    per_slot = -(-(CELL_MAX_LEN + 8) // PAGE)
+    pages = CELL_SLOTS * per_slot
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(described, jax.eval_shape(lambda: fam.init(cfg, jax.random.key(0))))
+    cache = jax.tree.map(described, jax.eval_shape(lambda: fam.make_paged_cache(cfg, pages, PAGE)))
+    key = described(jax.eval_shape(lambda: jax.random.key(0)))
+    programs = build_programs(fam, cfg, kv_layout="paged", spec_tokens=0, top_k=0, top_p=1.0,
+                              pages_per_slot=per_slot, page_size=PAGE)
+    jax.clear_caches()
+    with pallas.platform_hint("tpu"):
+        return {
+            "decode": programs.decode_chunk.lower(params, key, cache, 8, ints(5 + per_slot, CELL_SLOTS),
+                                                  ints(CELL_SLOTS)),
+            "prefill": programs.prefill_sample.lower(params, key, cache, ints(4, CELL_BUCKET + per_slot + 3)),
+            "chunk": programs.chunk_prefill.lower(params, key, cache, ints(1, CELL_BUCKET + per_slot + 4)),
+        }, cache
+
+
+@pytest.mark.parametrize("program,temp_gb", [("decode", 1.0), ("prefill", 2.0), ("chunk", 1.0)])
+def test_cohere2_moe_programs_fit_the_v5e_at_the_cells_shape(cohere2_programs, no_compile_cache, program, temp_gb):
+    """128 query heads, 16 held experts of 3 x 4096^2 a layer, 128 lanes x 13
+    pages: each program compiles for the v5e and fits beside 9.47 GB of
+    weights and a 3.49 GB pool; no layer's expert stack is copied out of the
+    parameters (the grouped product reads the whole stack: ops/moe.py), the
+    pool is carried, and the temporaries stay what they were measured to be."""
+    lowered, cache = cohere2_programs
+    compiled = lowered[program].compile()  # a program that does not fit raises here
+    ma = compiled.memory_analysis()
+    need = ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert need <= 15.75 * 2 ** 30, need
+    assert ma.temp_size_in_bytes < temp_gb * 1e9, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    assert not _ops_typed(compiled, ["copy"], cache.k.shape), "a pool plane is copied"
+    assert not _ops_typed(compiled, ["copy"], (4, 16, 4096, 4096)), "the experts of every layer are re-laid"
+    for name in ("moe_router", "moe_experts", "moe_shared"):
+        assert re.search(r'op_name="[^"]*/mlp/%s[/"]' % name, text), f"no operation under mlp/{name}"
+    if program == "decode":
+        # the kernel serves at a group of 16, appends in place, and takes the window: 12 operands
+        assert _kernel_operands(text) == [12], _kernel_operands(text)
+        assert " scatter(" not in text and not re.search(r'op_name="[^"]*/kv_gather[/"]', text)
+        assert "ragged-dot" not in text  # 128 tokens take the per-expert products
+    else:
+        assert "ragged-dot" in text  # 1,024 and 4,096 tokens take the grouped product
