@@ -25,6 +25,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+from gofr_tpu.ops.quant import qdot
 
 
 def truncated_normal(key, shape, stddev: float, dtype=jnp.float32):
@@ -49,6 +52,24 @@ def cast_floats(params: Any, dtype) -> Any:
     return jax.tree.map(
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, params
     )
+
+
+def qkv_heads(h: jnp.ndarray, lp: dict, head_size: int):
+    """h [..., E] → q [..., Hq, D], k, v [..., Hkv, D]: a layer's three
+    projections whose results are split into heads, the head counts read off
+    the weights' widths.
+
+    The flat products pass a barrier before the split. A reshape written
+    straight after the product is folded INTO it by the TPU compiler, which
+    then wants the weight as [heads, D, E]: it slices the layer's matrix out
+    of the stacked parameter, transposes the copy and only then multiplies —
+    every layer of every step (at 128 query heads it re-lays the whole stack).
+    Behind the barrier the product is a plain [.., E] x [E, heads*D] that reads
+    the scanned weight where it lies, as the MLP's products do (docs/kernels.md,
+    "Products that take a scanned weight"; tests/test_v5e_compile.py holds
+    it). The barrier changes no value."""
+    flat = lax.optimization_barrier(tuple(qdot(h, lp[w]) for w in ("wq", "wk", "wv")))
+    return tuple(y.reshape(*y.shape[:-1], -1, head_size) for y in flat)
 
 
 @dataclass

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from gofr_tpu.models.base import fan_in_init, truncated_normal
+from gofr_tpu.models.base import fan_in_init, qkv_heads, truncated_normal
 from gofr_tpu.models.llama import (
     _append_attend_paged,
     _paged_views,
@@ -207,13 +207,10 @@ def _embed(cfg: Cohere2MoeConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndar
 def _norm_qkv(cfg: Cohere2MoeConfig, lp: dict, x: jnp.ndarray, positions, factor):
     """x [B,S,E] → (n [B,S,E] float32 — the router reads it before its cast —
     and q [B,S,Hq,D], k, v [B,S,Hkv,D], q and k rotated)."""
-    b, s, _ = x.shape
     n32 = layer_norm(x.astype(jnp.float32), lp["norm"], None, cfg.norm_eps)
     n = n32.astype(cfg.dtype)
     inv_freq = rope_inv_freq(cfg.head_dim, cfg.rope_theta)
-    q = (n @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = (n @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = (n @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+    q, k, v = qkv_heads(n, lp, cfg.head_dim)
     return (n32, apply_rope_interleaved(q, positions, inv_freq, factor),
             apply_rope_interleaved(k, positions, inv_freq, factor), v)
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from gofr_tpu.models.base import fan_in_init, truncated_normal
+from gofr_tpu.models.base import fan_in_init, qkv_heads, truncated_normal
 from gofr_tpu.ops import apply_rope, mha_attention, rms_norm, rope_table
 from gofr_tpu.ops.attention import decode_attention, decode_attention_q, paged_decode_attention
 from gofr_tpu.ops.quant import qdot
@@ -203,12 +203,7 @@ def _embed(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
 @scoped("qkv_rope")
 def _qkv(cfg: LlamaConfig, lp: dict, x: jnp.ndarray):
     """x [B,S,E] → q [B,S,Hq,D], k/v [B,S,Hkv,D] (post-norm, pre-rope)."""
-    b, s, _ = x.shape
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = qdot(h, lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_size)
-    k = qdot(h, lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_size)
-    v = qdot(h, lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_size)
-    return q, k, v
+    return qkv_heads(rms_norm(x, lp["attn_norm"], cfg.norm_eps), lp, cfg.head_size)
 
 
 @scoped("o_proj")
@@ -303,10 +298,7 @@ def forward_pipelined(cfg: LlamaConfig, params: dict, tokens: jnp.ndarray,
 
         def body(x, lp):
             # local-head qkv: head counts come from the tp-sharded weights
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = (h @ lp["wq"]).reshape(b, s, -1, d)
-            k = (h @ lp["wk"]).reshape(b, s, -1, d)
-            v = (h @ lp["wv"]).reshape(b, s, -1, d)
+            q, k, v = qkv_heads(rms_norm(x, lp["attn_norm"], cfg.norm_eps), lp, d)
             q = apply_rope(q, positions, cos, sin)
             k = apply_rope(k, positions, cos, sin)
             a = mha_attention(q, k, v, causal=True, kv_lengths=lens)

@@ -38,6 +38,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from gofr_tpu.models import llama
+from gofr_tpu.models.base import qkv_heads
 from gofr_tpu.models.llama import LlamaConfig, _rope
 from gofr_tpu.ops.attention import decode_attention, mha_attention
 from gofr_tpu.ops.kvcache import SlotKVCache, append_tokens, write_prompts
@@ -123,9 +124,7 @@ class PPLlamaFamily:
                 def body(x, xs):
                     lp, k_layer, v_layer = xs  # k_layer [N, Hkv_local, Smax, D]
                     h = rms_norm(x[:, None], lp["attn_norm"], cfg.norm_eps)
-                    q = (h @ lp["wq"]).reshape(mbs, 1, -1, d)
-                    k = (h @ lp["wk"]).reshape(mbs, 1, -1, d)
-                    v = (h @ lp["wv"]).reshape(mbs, 1, -1, d)
+                    q, k, v = qkv_heads(h, lp, d)
                     q = apply_rope(q, pos1, cos, sin)[:, 0]
                     k = apply_rope(k, pos1, cos, sin)[:, 0]
                     v = v[:, 0]
@@ -206,9 +205,7 @@ class PPLlamaFamily:
                 def body(x, xs):
                     lp, k_layer, v_layer = xs
                     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-                    q = (h @ lp["wq"]).reshape(mbs, s, -1, d)
-                    k = (h @ lp["wk"]).reshape(mbs, s, -1, d)
-                    v = (h @ lp["wv"]).reshape(mbs, s, -1, d)
+                    q, k, v = qkv_heads(h, lp, d)
                     q = apply_rope(q, positions, cos, sin)
                     k = apply_rope(k, positions, cos, sin)
                     # OOB rows (bubbles / padding) scatter nowhere

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from gofr_tpu.models.base import fan_in_init, truncated_normal
+from gofr_tpu.models.base import fan_in_init, qkv_heads, truncated_normal
 from gofr_tpu.ops import apply_rope, mha_attention, rms_norm, rope_table
 from gofr_tpu.ops.attention import decode_attention
 from gofr_tpu.ops.kvcache import SlotKVCache, append_tokens, write_prompts
@@ -119,12 +119,7 @@ def _rope(cfg: MixtralConfig):
 
 
 def _qkv(cfg: MixtralConfig, lp: dict, x: jnp.ndarray):
-    b, s, _ = x.shape
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_size)
-    k = (h @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_size)
-    v = (h @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_size)
-    return q, k, v
+    return qkv_heads(rms_norm(x, lp["attn_norm"], cfg.norm_eps), lp, cfg.head_size)
 
 
 def _moe(cfg: MixtralConfig, lp: dict, x: jnp.ndarray,

@@ -10,6 +10,7 @@ implementation.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from gofr_tpu.tracing import scoped
 
@@ -71,13 +72,17 @@ def apply_rope_interleaved(
     carry 100 MB of it). ``factor`` scales every angle: 0 is the identity
     rotation, exactly — which is how one scanned layer body serves a layer
     with no positional embedding beside rotary ones."""
-    # every pair's angle on both of its lanes; the partner of lane 2i is 2i+1
-    # and the other way round: two lane rotations and a select on parity, no
-    # reshape of the lane axis into pairs
+    # every pair's angle on both of its lanes
     angles = positions[..., None].astype(jnp.float32) * jnp.repeat(inv_freq, 2) * factor
     cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, head_dim]: broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
-    xf = x.astype(jnp.float32)
-    even = jnp.arange(x.shape[-1]) % 2 == 0
-    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
-    return (xf * cos + partner * sin).astype(x.dtype)
+    # the partner of lane 2i is 2i+1 (negated) and the other way round, as ONE
+    # product with a signed permutation matrix: exact (one term a sum) and the
+    # matrix unit's to do. Two lane rotations and a select (jnp.roll by +-1)
+    # make the TPU compiler re-lay a prefill's whole q in float32 several
+    # times a layer (docs/kernels.md, "Products that take a scanned weight")
+    lane = jnp.arange(x.shape[-1])
+    swap = jnp.where(lane[:, None] == (lane ^ 1)[None, :], jnp.where(lane % 2 == 0, -1.0, 1.0), 0.0)
+    partner = jnp.matmul(x, swap.astype(x.dtype), precision=lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)

@@ -86,6 +86,49 @@ def test_family_is_found_from_the_config_and_imported_lazily(fam):
         Cohere2MoeConfig.tiny(experts_held=8, first_expert=12)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_interleaved_rotary_is_the_rotation_of_neighbouring_pairs(dtype):
+    """``apply_rope_interleaved`` finds a lane's partner by one product with a
+    signed permutation matrix: each pair (x[2i], x[2i+1]) turns by
+    ``position * inv_freq[i]`` exactly as the definition by pairs says, in
+    both dtypes (a partner is ONE term of the product's sum: exact), and a
+    factor of 0 is the identity, bit for bit."""
+    from gofr_tpu.ops.rope import apply_rope_interleaved, rope_inv_freq
+
+    x = jax.random.normal(jax.random.key(2), (2, 5, 3, 16), jnp.float32).astype(getattr(jnp, dtype))
+    positions = jnp.asarray([[0, 1, 7, 100, 4095], [3, 2, 1, 0, 50000]], jnp.int32)
+    inv_freq = rope_inv_freq(16, 50000.0)
+    got = apply_rope_interleaved(x, positions, inv_freq)
+    angle = positions[..., None, None].astype(jnp.float32) * inv_freq  # [B, S, 1, D/2]
+    pairs = x.astype(jnp.float32).reshape(2, 5, 3, 8, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    want = jnp.stack([a * jnp.cos(angle) - b * jnp.sin(angle), b * jnp.cos(angle) + a * jnp.sin(angle)],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(apply_rope_interleaved(x, positions, inv_freq, 0.0), np.float32),
+                                  np.asarray(x, np.float32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_barrier_in_qkv_heads_changes_no_logit(fam, monkeypatch, dtype):
+    """``_norm_qkv`` projects through ``models/base.qkv_heads`` (PR 35: the flat
+    products pass a barrier before the split into heads). Against the plain
+    split patched in at the call site, ``forward`` over both kinds of layer
+    gives the same logits bit for bit."""
+    cfg = Cohere2MoeConfig.tiny(dtype=getattr(jnp, dtype))
+    params = fam.init(cfg, jax.random.key(7))
+    toks = jnp.asarray([tokens(20), tokens(20, seed=1)], jnp.int32)
+    jax.clear_caches()
+    got = fam.forward(cfg, params, toks)
+    monkeypatch.setattr(fam, "qkv_heads", lambda h, lp, d: tuple(
+        (h @ lp[w]).reshape(*h.shape[:-1], -1, d) for w in ("wq", "wk", "wv")))
+    jax.clear_caches()
+    want = fam.forward(cfg, params, toks)
+    monkeypatch.undo()
+    jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("n", [5, 16, 40])  # inside the window, at its edge, beyond it
 def test_forward_equals_the_reference(fam, ref, model, n):
     cfg, params = model

@@ -252,6 +252,80 @@ def test_param_count_sanity():
     assert param_count(llama.init(LlamaConfig.tiny(), jax.random.key(0))) > 50_000
 
 
+# -- models/base.qkv_heads: the barrier between product and split changes no value ------
+
+
+def _plain_qkv_heads(h, lp, head_size):
+    """What every family wrote until PR 35: the split straight after the product."""
+    from gofr_tpu.ops.quant import qdot
+
+    return tuple(qdot(h, lp[w]).reshape(*h.shape[:-1], -1, head_size) for w in ("wq", "wk", "wv"))
+
+
+def _qkv_layer(dtype, quantized=False):
+    from gofr_tpu.ops.quant import quantize
+
+    keys = jax.random.split(jax.random.key(3), 4)
+    lp = {w: jax.random.normal(k, (64, width), jnp.float32).astype(dtype) * 0.1
+          for w, k, width in zip(("wq", "wk", "wv"), keys, (64, 32, 32))}
+    if quantized:
+        lp = {w: quantize(v) for w, v in lp.items()}
+    return jax.random.normal(keys[3], (3, 5, 64), jnp.float32).astype(dtype), lp
+
+
+@pytest.mark.parametrize("case", ["bfloat16", "float32", "qtensor"])
+def test_qkv_heads_equals_the_plain_split_bit_for_bit(case):
+    from gofr_tpu.models.base import qkv_heads
+
+    h, lp = _qkv_layer(jnp.float32 if case == "float32" else jnp.bfloat16, quantized=case == "qtensor")
+    got = jax.jit(qkv_heads, static_argnums=2)(h, lp, 16)
+    want = jax.jit(_plain_qkv_heads, static_argnums=2)(h, lp, 16)
+    for g, w, heads in zip(got, want, (4, 2, 2)):
+        assert g.shape == (3, 5, heads, 16) and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("case", ["grad_of_forward", "interpreted_paged_decode"])
+def test_programs_through_qkv_heads_equal_the_plain_split(monkeypatch, case):
+    """Whole programs, the helper against the plain split patched in where the
+    model calls it: a gradient passes through the barrier unchanged
+    (``forward`` is training-shaped), and prefill + three decode steps through
+    the paged-decode kernel under the Pallas interpreter (heads of 128, the
+    append fused) give the same logits, so the same greedy tokens."""
+    tokens = jax.random.randint(jax.random.key(1), (2, 14), 3, 120)
+
+    def run():
+        jax.clear_caches()  # which split is a trace-time property
+        if case == "grad_of_forward":
+            cfg = LlamaConfig.tiny()
+            params = llama.init(cfg, jax.random.key(0))
+            return jax.grad(lambda p: llama.forward(cfg, p, tokens).astype(jnp.float32).mean())(params)
+        cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256, num_layers=2,
+                          num_heads=2, num_kv_heads=1)
+        params = llama.init(cfg, jax.random.key(0))
+        table = jnp.arange(4, dtype=jnp.int32).reshape(2, 2)
+        lengths = jnp.array([14, 9])
+        logits, cache = llama.prefill_paged(cfg, params, tokens, lengths,
+                                            llama.make_paged_cache(cfg, 4, 16), table)
+        out = [logits]
+        for step in range(3):  # the row of 14 crosses into its second page
+            logits, cache = llama.decode_step_paged(cfg, params, jnp.argmax(out[-1], -1).astype(jnp.int32),
+                                                    lengths + step, cache, table)
+            out.append(logits)
+        return out
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    got = run()
+    traced = []
+    monkeypatch.setattr(llama, "qkv_heads", lambda *a: traced.append(1) or _plain_qkv_heads(*a))
+    want = run()
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert traced, "the plain split was not traced: both sides ran the helper"
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32)),
+                 got, want)
+
+
 class TestGPT2:
     def test_prefill_decode_matches_forward(self):
         """Greedy via prefill+decode_step must equal argmax of incremental

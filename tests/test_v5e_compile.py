@@ -13,6 +13,7 @@ show as ``copy`` operations typed like a whole pool plane.
 All in ONE file, topology described inside a fixture: only one process at a
 time may load the TPU's library, and it keeps it until it exits."""
 
+import functools
 import os
 import re
 
@@ -61,11 +62,13 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+@functools.cache
 def _compile(one_chip, width, program):
     """One paged program at ``width`` compiled for the described chip, its
     read path resolved as an engine on a TPU resolves it (the rule in
     ops/attention.resolve_backend under ``platform_hint("tpu")``) →
-    (compiled, cache shapes)."""
+    (compiled, cache shapes). Compiled once a (width, program): the tests
+    below read the same text for different things."""
     from gofr_tpu.ops import pallas
 
     cfg = LlamaConfig(**WIDTHS[width])
@@ -99,6 +102,42 @@ def _ops_typed(compiled, kinds, dims):
     typed = re.escape("bf16[%s]" % ",".join(str(x) for x in dims))
     return [line.strip()[:160] for line in compiled.as_text().splitlines()
             if re.search(r"= %s\S* (%s)\(" % (typed, "|".join(kinds)), line)]
+
+
+def _assert_qkv_reads_the_stacked_weights(compiled, layers, embed, q_width, kv_width):
+    """No operation of the compiled program is typed like one layer's ``wq`` /
+    ``wk`` / ``wv`` or like their stack (a ``copy``, or a fusion — whose root
+    is then a lone ``dynamic-slice``: the bad case of docs/kernels.md,
+    "products that take a scanned weight"), and three ``qkv_rope`` products
+    take a stacked parameter ``[L, E, heads*D]`` as an operand."""
+    for dims in [(1, embed, q_width), (1, embed, kv_width), (layers, embed, q_width), (layers, embed, kv_width)]:
+        lone = _ops_typed(compiled, ["copy", "fusion"], dims)
+        assert not lone, f"a layer's q/k/v weight (or the stack) is sliced out, copied or re-laid: {lone}"
+    text = compiled.as_text()
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = ([a-z0-9]+\[[\d,]*\])", text, re.M))
+    stacked = {"bf16[%d,%d,%d]" % (layers, embed, w) for w in (q_width, kv_width)}
+    reading = 0  # the qkv_rope products that take a stacked parameter (a rotary step may add products of its own)
+    for line in text.splitlines():
+        if " fusion(" in line and re.search(r'op_name="[^"]*/qkv_rope/dot_general"', line):
+            operands = re.findall(r"%[\w.\-]+", line.split(" fusion(", 1)[1].split("), kind=", 1)[0])
+            reading += bool(stacked & {types.get(name) for name in operands})
+    assert reading == 3, f"{reading} of the three q/k/v products read the stacked parameter"
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk_prefill"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_qkv_products_read_the_stacked_weights_on_the_v5e(one_chip, no_compile_cache, width, program):
+    """PR 35: written as ``qdot(h, wq).reshape(.., heads, D)`` the compiler
+    folded the split into the product, wanted the weight as [heads, D, E], and
+    every layer of every step sliced ``wq[l]`` out of the stack into fast
+    memory and transposed the copy before multiplying. Behind
+    ``models/base.qkv_heads``' barrier the three products take the stacked
+    parameter and the layer index, as the MLP's do."""
+    compiled, _ = _compile(one_chip, width, program)
+    w = WIDTHS[width]
+    head = w["hidden_size"] // w["num_heads"]
+    _assert_qkv_reads_the_stacked_weights(
+        compiled, w["num_layers"], w["hidden_size"], w["num_heads"] * head, w["num_kv_heads"] * head)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "chunk_prefill"])
@@ -318,23 +357,27 @@ def cohere2_programs(one_chip):
                               pages_per_slot=per_slot, page_size=PAGE)
     jax.clear_caches()
     with pallas.platform_hint("tpu"):
-        return {
+        lowered = {
             "decode": programs.decode_chunk.lower(params, key, cache, 8, ints(5 + per_slot, CELL_SLOTS),
                                                   ints(CELL_SLOTS)),
             "prefill": programs.prefill_sample.lower(params, key, cache, ints(4, CELL_BUCKET + per_slot + 3)),
             "chunk": programs.chunk_prefill.lower(params, key, cache, ints(1, CELL_BUCKET + per_slot + 4)),
-        }, cache
+        }
+    # compiled on first use, once a program (under the test's ``no_compile_cache``)
+    return functools.cache(lambda program: lowered[program].compile()), cache
 
 
-@pytest.mark.parametrize("program,temp_gb", [("decode", 1.0), ("prefill", 2.0), ("chunk", 1.0)])
+@pytest.mark.parametrize("program,temp_gb", [("decode", 0.1), ("prefill", 2.0), ("chunk", 1.0)])
 def test_cohere2_moe_programs_fit_the_v5e_at_the_cells_shape(cohere2_programs, no_compile_cache, program, temp_gb):
     """128 query heads, 16 held experts of 3 x 4096^2 a layer, 128 lanes x 13
     pages: each program compiles for the v5e and fits beside 9.47 GB of
     weights and a 3.49 GB pool; no layer's expert stack is copied out of the
     parameters (the grouped product reads the whole stack: ops/moe.py), the
-    pool is carried, and the temporaries stay what they were measured to be."""
-    lowered, cache = cohere2_programs
-    compiled = lowered[program].compile()  # a program that does not fit raises here
+    pool is carried, and the temporaries stay what they were measured to be
+    (the decode chunk's were 742 MB until PR 35: the re-laid ``wq`` stack and
+    a layer of it; 8 MB since)."""
+    compiled_program, cache = cohere2_programs
+    compiled = compiled_program(program)  # a program that does not fit raises here
     ma = compiled.memory_analysis()
     need = ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
     assert need <= 15.75 * 2 ** 30, need
@@ -351,3 +394,13 @@ def test_cohere2_moe_programs_fit_the_v5e_at_the_cells_shape(cohere2_programs, n
         assert "ragged-dot" not in text  # 128 tokens take the per-expert products
     else:
         assert "ragged-dot" in text  # 1,024 and 4,096 tokens take the grouped product
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk"])
+def test_cohere2_moe_qkv_products_read_the_stacked_weights(cohere2_programs, no_compile_cache, program):
+    """At 128 query heads a layer's ``wq`` is 134 MB, too large for fast
+    memory: the folded split (test_qkv_products_read_the_stacked_weights_on_the_v5e)
+    re-laid the WHOLE ``[4, 4096, 16384]`` stack once a decode chunk and copied
+    a layer of it HBM to HBM every layer-step. Neither is left in any program."""
+    compiled_program, _ = cohere2_programs
+    _assert_qkv_reads_the_stacked_weights(compiled_program(program), 4, 4096, 128 * 128, 8 * 128)
