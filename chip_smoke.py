@@ -402,22 +402,29 @@ def _kernel_pass(cfg, shape: Shape, *, interpret: bool) -> dict:
     # (what serves at head_dim 128: ops/attention.append_rides_in_kernel),
     # against the XLA pair — the scatter, then the gathered read. The
     # benchmark's head geometry (8 KV heads of 128, G = 2), this pass's table
-    # and write positions. Lane 0's write is dropped and what it then attends
-    # on its unallocated page means nothing: its output is left out.
+    # and write positions, every third lane from lane 2 on idle (its table
+    # row all sentinel, as the engine masks a lane that is not decoding: the
+    # kernel skips it and the chain of copies runs between the live lanes
+    # around it). Lane 0's write is dropped and what it then attends on its
+    # unallocated page means nothing: its output is left out, as are the idle
+    # lanes' (zeros from the kernel, a clamped page from XLA).
     q_d, kn_d, vn_d = normal(n, 16, 128), normal(n, 8, 128), normal(n, 8, 128)
     kp_d, vp_d = normal(2, pool, 8, page, 128), normal(2, pool, 8, page, 128)
+    idle = np.arange(n) % 3 == 2
+    fused_table = jnp.where(jnp.asarray(idle)[:, None], pool, append_table)
+    kept = np.flatnonzero(~idle)[1:]
 
     def fused_step():
         out, k_out, v_out = jitted(k_paged.paged_decode_append_attention)(
-            q_d, kn_d, vn_d, kp_d, vp_d, layer, append_table, paged_pos)
-        return out[1:], k_out, v_out
+            q_d, kn_d, vn_d, kp_d, vp_d, layer, fused_table, paged_pos)
+        return out[kept], k_out, v_out
 
     def xla_pair():
         k_out, v_out = jax.jit(paged.append_tokens_paged)(
-            kp_d, vp_d, layer, append_table, paged_pos, kn_d, vn_d)
+            kp_d, vp_d, layer, fused_table, paged_pos, kn_d, vn_d)
         out = xla(attn.paged_decode_attention, backend="xla")(
-            q_d, k_out, v_out, layer, append_table, paged_pos + 1)
-        return out[1:], k_out, v_out
+            q_d, k_out, v_out, layer, fused_table, paged_pos + 1)
+        return out[kept], k_out, v_out
 
     cases = {
         "flash_prefill": (

@@ -10,18 +10,20 @@ never a gather-materialized copy of the logical view (that copy is the XLA
 fallback, ops.paged.gather_kv).
 
 ``paged_decode_attention`` (bf16 pool; what the served decode program runs
-on a TPU, ops/attention.resolve_backend) — grid (slot,), one lane a step, the pool
-planes left in HBM. A lane's pages are a loop over ceil(len / page), each
-page ONE copy a plane of [Hkv, page, D] — a whole page of every KV head,
-which the pool's layout makes one contiguous run — into one of two VMEM
-buffers, the next page's copy (the next lane's first, at a lane's end) in
-flight while this one is attended. A page past a lane's length costs no
-copy, no arithmetic and no grid step. Every head is attended at once: one
-[Hq, D] x [D, Hkv*page] product whose blocks off a query head's own KV head
-are masked away together with the positions past the length. Unallocated
-table entries (== P) clamp to P-1 and are only ever read behind that mask.
-docs/kernels.md has the design's A/B against a BlockSpec-only grid of
-(slot, logical_page).
+on a TPU, ops/attention.resolve_backend) — grid (1,), the pool planes left in
+HBM. The kernel lists the LIVE lanes (a page in the table's first column, a
+length above 0) and loops over them; an idle lane costs no copy, no product
+and no grid step, and its output rows are zeros. A live lane's pages are a
+loop over ceil(len / page), each page ONE copy a plane of [Hkv, page, D] — a
+whole page of every KV head, which the pool's layout makes one contiguous
+run — into one of two VMEM buffers, the next page's copy (the next live
+lane's first, at a lane's end) in flight while this one is attended. A page
+past a lane's length costs no copy and no arithmetic. Every head is attended
+at once: one [Hq, D] x [D, Hkv*page] product whose blocks off a query head's
+own KV head are masked away together with the positions past the length.
+Unallocated table entries (== P) inside a length clamp to P-1. docs/kernels.md
+has the design's A/Bs: against a BlockSpec-only grid of (slot, logical_page),
+and of a grid step a lane against one loop over the live lanes.
 
 ``paged_decode_append_attention`` is the same kernel serving a whole decode
 step of a layer: it also WRITES each lane's new K/V row into the planes,
@@ -96,22 +98,27 @@ def _tile_rows(dtype) -> int:
 
 def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool,
                          windowed: bool = False):
-    """One grid step is one lane; its pages are a loop, not grid steps.
+    """One grid step for the whole call: the live lanes are a loop, and so are
+    a lane's pages. An idle lane is not visited at all.
 
-    The copies form one chain over the whole call: page ``j`` of a lane
-    lands in buffer ``(seq + j) % 2``, and the copy of the page after it —
-    the NEXT LANE's first when ``j`` is this lane's last — starts before
-    page ``j`` is attended. Every lane copies at least one page (an empty
-    lane its table's first entry, which it does not attend), so the chain
-    never breaks and the last lane leaves no copy in flight.
+    A lane is live when its table's first entry is a page of the pool and its
+    length is not 0; an idle lane (the engine hands one a row of ``pool``) costs
+    a look at two scalars. The kernel lists the live lanes in lane order in
+    SMEM first and loops over that list. The copies form one chain over the
+    live lanes: page ``j`` of a lane lands in buffer
+    ``(seq + j - first) % 2``, ``seq`` counting the pages visited before it,
+    and the copy of the page after it — the NEXT LIVE LANE's first when ``j``
+    is this lane's last — starts before page ``j`` is attended. The last live
+    lane leaves no copy in flight. The output is one block, zero-filled once:
+    an idle lane's rows stay zero.
 
     With ``append`` the lane's new K/V row is written from here too. Its
     page is the lane's last, so it is in VMEM when the row exists: the
     aligned tile of rows around ``off`` is patched there (the page is then
     attended WITH the row: nothing is read back from HBM after the write),
     staged, and copied to the plane. The wait for that copy is put off by
-    two lanes — the lane after next waits before it stages its own — and
-    the last lane waits for what is left.
+    two live lanes — the live lane after next waits before it stages its
+    own — and what is left is waited for after the loop.
 
     ``windowed``: a last scalar-prefetch operand win [1] bounds what a lane
     sees to its last ``win`` positions, ``[length - win, length)``. Its page
@@ -122,14 +129,14 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
     visited. A window that never binds (``win >= length``) visits what the
     unwindowed kernel visits."""
     # scalar prefetch (SMEM): ln [N] positions a lane attends (the new token's
-    # included), table [N, MaxP] clamped into the pool, layer [1]; with
-    # ``append`` wp [N] the page a lane's new row goes to (>= pool: it writes
-    # nothing) and off [N] its row in that page. VMEM blocks: q [Hq, d] of the
-    # lane; pos int32 [Hq, Hkv*page], the position in the page or _OTHER_HEAD
-    # off the head's own block; knew / vnew [N, Hkv, d], the step's K/V of
-    # every lane. The planes [L, P, Hkv, page, d] stay where they lie; when
-    # they are written the aliased outputs ARE the planes, read and written
-    # through one ref each.
+    # included), table [N, MaxP] (entries == pool: no page), layer [1]; with
+    # ``append`` wp [N] the page a lane's new row goes to
+    # (>= pool: it writes nothing) and off [N] its row in that page. VMEM
+    # blocks: q [N, Hq, d]; pos int32 [Hq, Hkv*page], the position in the
+    # page or _OTHER_HEAD off the head's own block; knew / vnew [N, Hkv, d],
+    # the step's K/V of every lane; the output [N, Hq, d]. The planes
+    # [L, P, Hkv, page, d] stay where they lie; when they are written the
+    # aliased outputs ARE the planes, read and written through one ref each.
     if windowed:  # the window rides last among the scalars
         n_scalars = 6 if append else 4
         win_ref, refs = refs[n_scalars - 1], refs[:n_scalars - 1] + refs[n_scalars:]
@@ -142,15 +149,27 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
      sem,           # DMA semaphores [2 planes, 2 buffers]
      acc_ref,       # f32 [Hq, d]
      m_ref, l_ref,  # f32 [Hq, 128]
-     seq_ref,       # SMEM [1]: pages copied by the lanes before this one
+     live_ref,      # SMEM [N]: the live lanes in lane order
      *stage) = scratch
-    bi = pl.program_id(0)
-    lanes = pl.num_programs(0)
-    length = ln_ref[bi]
+    lanes, span = table_ref.shape[0], table_ref.shape[1] * page
     hkv, _, d = k_buf.shape[1:]
 
+    def length_of(lane):
+        return jnp.minimum(ln_ref[lane], span)
+
+    def listed(lane, count):
+        live = (table_ref[lane, 0] < pool) & (ln_ref[lane] > 0)
+
+        @pl.when(live)
+        def _():
+            live_ref[count] = lane
+
+        return count + live.astype(jnp.int32)
+
+    count = jax.lax.fori_loop(0, lanes, listed, 0)  # live_ref past count is never read: it holds nothing
+
     def page_copies(lane, j, buf):
-        src = (layer_ref[0], table_ref[lane, j])
+        src = (layer_ref[0], jnp.minimum(table_ref[lane, j], pool - 1))
         return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[buf], sem.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[src], v_buf.at[buf], sem.at[1, buf]))
 
@@ -160,7 +179,7 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
 
     if windowed:
         def seen_from(lane):  # the first position a lane's window holds
-            return jnp.maximum(ln_ref[lane] - win_ref[0], 0)
+            return jnp.maximum(length_of(lane) - win_ref[0], 0)
 
         def first_page(lane):
             return seen_from(lane) // page
@@ -168,20 +187,15 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
         def first_page(lane):
             return 0
 
-    @pl.when(bi == 0)
-    def _():
-        seq_ref[0] = 0
-        start(0, first_page(0), 0)
+    o_ref[...] = jnp.zeros_like(o_ref)
 
-    seq = seq_ref[0]
-    first = first_page(bi)
-    mine = jnp.maximum(pl.cdiv(length, page), 1)  # one past the last page this lane copies
-    init_softmax_scratch(0, acc_ref, m_ref, l_ref)
+    @pl.when(count > 0)
+    def _():
+        start(live_ref[0], first_page(live_ref[0]), 0)
 
     if append:
         k_stage, v_stage, wsem = stage  # [2, Hkv, rows, d] a plane; DMA semaphores [2 planes, 2 slots]
         rows = k_stage.shape[2]
-        slot = bi % 2
 
         def writes(lane):
             return wp_ref[lane] < pool
@@ -194,78 +208,83 @@ def _paged_decode_kernel(*refs, scale: float, page: int, pool: int, append: bool
             return (pltpu.make_async_copy(k_stage.at[slot], k_hbm.at[dst], wsem.at[0, slot]),
                     pltpu.make_async_copy(v_stage.at[slot], v_hbm.at[dst], wsem.at[1, slot]))
 
-        def wait_tiles(lane, slot):
-            started, lane = lane >= 0, jnp.maximum(lane, 0)  # no lane before the first
-
-            @pl.when(started & writes(lane))
+        def wait_tiles(i):  # the i-th live lane's tile copies, where it started them
+            @pl.when(i >= 0)
             def _():
-                for copy in tile_copies(lane, slot):
-                    copy.wait()
+                lane = live_ref[i]
 
-        wait_tiles(bi - 2, slot)  # this lane's staging slot is free again
+                @pl.when(writes(lane))
+                def _():
+                    for copy in tile_copies(lane, i % 2):
+                        copy.wait()
 
-        def patch(buf):
-            base = tile_base(bi)
-            hit = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) == off_ref[bi] - base
+        def patch(lane, slot, buf):
+            base = tile_base(lane)
+            hit = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0) == off_ref[lane] - base
             for new_ref, page_buf, stage in ((knew_ref, k_buf, k_stage), (vnew_ref, v_buf, v_stage)):
-                new = new_ref[bi].astype(jnp.float32)  # [Hkv, d]; a row is cut out of 32-bit sublanes
+                new = new_ref[lane].astype(jnp.float32)  # [Hkv, d]; a row is cut out of 32-bit sublanes
                 for h in range(hkv):
                     tile = page_buf[buf, h, pl.ds(base, rows), :]
                     tile = jnp.where(hit, new[h:h + 1, :], tile.astype(jnp.float32)).astype(tile.dtype)
                     stage[slot, h] = tile
                     page_buf[buf, h, pl.ds(base, rows), :] = tile
-            for copy in tile_copies(bi, slot):
+            for copy in tile_copies(lane, slot):
                 copy.start()
 
-    def attend(j, carry):
-        buf = (seq + j - first) % 2 if windowed else (seq + j) % 2
-        last = j + 1 == mine
-        next_lane = jnp.where(last, bi + 1, bi)
-
-        @pl.when(next_lane < lanes)
-        def _():
-            # the next lane's first page (its index clamped: only read under the `when`)
-            ahead = first_page(jnp.minimum(bi + 1, lanes - 1)) if windowed else 0
-            start(next_lane, jnp.where(last, ahead, j + 1), 1 - buf)
-
-        for copy in page_copies(bi, j, buf):
-            copy.wait()
-
+    def one_lane(i, seq):
+        lane = live_ref[i]
+        length = length_of(lane)
+        first = first_page(lane)
+        mine = pl.cdiv(length, page)  # one past the last page this lane copies
+        init_softmax_scratch(0, acc_ref, m_ref, l_ref)
         if append:
-            @pl.when(last & writes(bi))
-            def _():
-                patch(buf)
+            wait_tiles(i - 2)  # this lane's staging slot is free again
 
-        @pl.when(j * page < length)  # false only for an empty lane's one page
-        def _():
+        def attend(j, carry):
+            buf = (seq + j - first) % 2
+            last = j + 1 == mine
+
+            @pl.when(jnp.logical_not(last) | (i + 1 < count))
+            def _():
+                # the next live lane's first page at this lane's last
+                ahead = live_ref[jnp.minimum(i + 1, count - 1)]
+                start(jnp.where(last, ahead, lane), jnp.where(last, first_page(ahead), j + 1), 1 - buf)
+
+            for copy in page_copies(lane, j, buf):
+                copy.wait()
+
+            if append:
+                @pl.when(last & writes(lane))
+                def _():
+                    patch(lane, i % 2, buf)
+
             # every head at once: one [Hq, d] x [d, Hkv*page] product whose
             # off-head blocks are masked away with the dead positions (a
             # product per head pays the same MXU weight loads: A/B in
             # docs/kernels.md)
             s = jax.lax.dot_general(
-                q_ref[...], k_buf[buf].reshape(hkv * page, d),
+                q_ref[lane], k_buf[buf].reshape(hkv * page, d),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             ) * scale  # [Hq, Hkv*page]
             where = pos_ref[...] + j * page
-            seen = (where < length) & (where >= seen_from(bi)) if windowed else where < length
+            seen = (where < length) & (where >= seen_from(lane)) if windowed else where < length
             s = jnp.where(seen, s, NEG_INF)
             softmax_block_update(s, v_buf[buf].reshape(hkv * page, d), acc_ref, m_ref, l_ref)
+            return carry
 
-        return carry
+        jax.lax.fori_loop(first, mine, attend, 0)
 
-    jax.lax.fori_loop(first, mine, attend, 0)
-    seq_ref[0] = seq + mine - first if windowed else seq + mine
+        def write(out):
+            o_ref[lane] = out.astype(o_ref.dtype)
 
-    def write(out):
-        o_ref[...] = out.astype(o_ref.dtype)
+        softmax_finish(0, 1, acc_ref, l_ref, write)
+        return seq + mine - first
 
-    softmax_finish(0, 1, acc_ref, l_ref, write)
+    jax.lax.fori_loop(0, count, one_lane, 0)
 
     if append:
-        @pl.when(bi == lanes - 1)
-        def _():
-            wait_tiles(bi - 1, 1 - slot)
-            wait_tiles(bi, slot)
+        wait_tiles(count - 2)
+        wait_tiles(count - 1)
 
 
 def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, interpret,
@@ -276,7 +295,6 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, 
     scalar-prefetch operand; None leaves the call as it is without one."""
     n, hq, d = q.shape
     _, pool, hkv, page, _ = k_pool.shape
-    _, maxp = table.shape
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not divisible by kv heads {hkv}")
     group = hq // hkv
@@ -287,15 +305,12 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, 
     own = jnp.arange(hq, dtype=jnp.int32)[:, None] // group == col // page
     pos = jnp.where(own, col % page, _OTHER_HEAD)
 
-    def lane_map(bi, *_):
-        return (bi, 0, 0)
-
-    def whole(*shape):
-        return pl.BlockSpec(shape, lambda bi, *_: (0,) * len(shape))
+    def whole(*shape):  # one grid step: a block is fetched once and needs no second buffer
+        return pl.BlockSpec(shape, lambda *_: (0,) * len(shape), pipeline_mode=pl.Buffered(1))
 
     plane = pl.BlockSpec(memory_space=pl.ANY)
     attn = jax.ShapeDtypeStruct((n, hq, d), q.dtype)
-    in_specs = [pl.BlockSpec((None, hq, d), lane_map), whole(hq, hkv * page)]
+    in_specs = [whole(n, hq, d), whole(hq, hkv * page)]
     operands = [q, pos]
     scratch = [
         pltpu.VMEM((2, hkv, page, d), k_pool.dtype),
@@ -304,10 +319,9 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, 
         pltpu.VMEM((hq, d), jnp.float32),
         pltpu.VMEM((hq, 128), jnp.float32),
         pltpu.VMEM((hq, 128), jnp.float32),
-        pltpu.SMEM((1,), jnp.int32),
+        pltpu.SMEM((n,), jnp.int32),
     ]
-    prefetch = [jnp.minimum(lengths.astype(jnp.int32), maxp * page),
-                jnp.minimum(table, pool - 1).astype(jnp.int32), _layer_operand(layer)]
+    prefetch = [lengths.astype(jnp.int32), table.astype(jnp.int32), _layer_operand(layer)]
     if append:
         k_new, v_new, write_page, write_row = new
         rows = _tile_rows(k_pool.dtype)
@@ -329,16 +343,14 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, lengths, new, *, scale, 
         name="attention",  # tracing.SCOPES: the kernel is named for its phase
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(n,),
+            grid=(1,),
             in_specs=in_specs + [plane, plane],
-            out_specs=([pl.BlockSpec((None, hq, d), lane_map), plane, plane] if append
-                       else pl.BlockSpec((None, hq, d), lane_map)),
+            out_specs=[whole(n, hq, d), plane, plane] if append else whole(n, hq, d),
             scratch_shapes=scratch,
         ),
         out_shape=([attn, jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                     jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)] if append else attn),
         input_output_aliases={planes: 1, planes + 1: 2} if append else {},
-        # the lanes run in order: the chain of copies crosses from one to the next
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, *operands, k_pool, v_pool)

@@ -227,7 +227,7 @@ def _append_case(dtype, hkv, group, lanes=tuple(APPEND_LANES), d=128, seed=0):
     return q, k_new, v_new, k_pool, v_pool, jnp.asarray(table), jnp.asarray(pos)
 
 
-def _assert_append_attend_matches(dtype, got, pools, operands, live):
+def _assert_append_attend_matches(dtype, got, pools, operands, live, window=None):
     """The fused call against ``append_tokens_paged`` then attention: planes
     bit for bit; the live lanes' output bit for bit against the kernel read
     of the scattered pool, and against XLA's read to 2e-5 in float32 / one
@@ -240,12 +240,18 @@ def _assert_append_attend_matches(dtype, got, pools, operands, live):
     for plane, want in zip(pools, (want_k, want_v)):
         assert plane.dtype == want.dtype
         assert np.array_equal(np.asarray(plane, np.float32), np.asarray(want, np.float32))
-    live = np.asarray(live)
-    kernel = paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="pallas")
+    live = np.asarray(live, int)
+    kernel = paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="pallas", window=window)
     assert np.array_equal(np.asarray(got, np.float32)[live], np.asarray(kernel, np.float32)[live])
-    xla = np.asarray(paged_decode_attention(q, want_k, want_v, 1, table, pos + 1, backend="xla"), np.float32)
-    tol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7  # one bf16 ulp of a value in [1, 2), relative above
-    np.testing.assert_allclose(np.asarray(got, np.float32)[live], xla[live], atol=tol, rtol=tol)
+    # a lane reads at most its table's span, and a window counts back from there
+    span = jnp.minimum(pos + 1, table.shape[1] * k_pool.shape[3])
+    xla = np.asarray(paged_decode_attention(q, want_k, want_v, 1, table, span, backend="xla", window=window),
+                     np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live], xla[live], atol=_tol(dtype), rtol=_tol(dtype))
+
+
+def _tol(dtype):
+    return 2e-5 if dtype == jnp.float32 else 2.0 ** -7  # one bf16 ulp of a value in [1, 2), relative above
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -287,6 +293,76 @@ def test_paged_decode_append_lane_by_lane(monkeypatch, lane):
         assert len(changed) == (2 if dropped else 3) and set(changed[:, 0]) == {1}, changed
 
 
+# Where the idle lanes of one call sit among the live ones (names from APPEND_LANES).
+IDLE_LAYOUTS = {
+    "idle_first": ("idle", "idle", "row_1", "last_row_of_a_page", "opens_a_fresh_page"),
+    "idle_last": ("length_0", "second_tile_of_a_page", "past_the_span", "idle", "idle"),
+    "idle_between_live": ("row_1", "idle", "last_row_of_the_span", "idle", "idle", "opens_a_fresh_page",
+                          "idle", "length_0"),
+    "one_live_of_40": ("idle",) * 23 + ("second_tile_of_a_page",) + ("idle",) * 16,
+    "every_lane_idle": ("idle",) * 4,
+    "no_lane_idle": tuple(name for name in APPEND_LANES if name != "idle"),
+}
+
+
+def _tpu_interpreter():
+    """The TPU interpreter: a copy lands only when it is waited for
+    (``dma_execution_mode="on_wait"``), so a copy the kernel never waits
+    for shows as a page never attended or a row never written."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams(dma_execution_mode="on_wait")
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["unwindowed", "window_21"])
+@pytest.mark.parametrize("layout", sorted(IDLE_LAYOUTS))
+def test_paged_decode_append_skips_idle_lanes(monkeypatch, layout, window):
+    """The fused call over one layout of idle and live lanes, with and without
+    a window that cuts inside a page: the live lanes equal the scatter
+    followed by the read; an idle lane's output is exactly zero; the planes
+    change in the rows the live lanes write and nowhere else, so an idle
+    lane's pages are bit for bit what they were."""
+    from gofr_tpu.ops.pallas import paged_decode as kernels
+
+    monkeypatch.setenv("GOFR_PALLAS_INTERPRET", "1")
+    lanes = IDLE_LAYOUTS[layout]
+    operands = _append_case(jnp.bfloat16, 2, 2, lanes=lanes)
+    got, k_out, v_out = kernels.paged_decode_append_attention(
+        *operands[:5], 1, *operands[5:], window, interpret=_tpu_interpreter())
+    live = [i for i, name in enumerate(lanes) if name != "idle"]
+    idle = [i for i, name in enumerate(lanes) if name == "idle"]
+    _assert_append_attend_matches(jnp.bfloat16, got, (k_out, v_out), operands, live, window)
+    assert not np.asarray(got, np.float32)[idle].any()
+    writers = sum(name not in ("idle", "past_the_span") for name in lanes)
+    for plane, before in ((k_out, operands[3]), (v_out, operands[4])):
+        changed = np.argwhere(np.asarray(plane != before).any(axis=(2, 4)))  # (layer, page, row)
+        assert len(changed) == writers and set(changed[:, 0]) <= {1}, changed
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["unwindowed", "window_21"])
+@pytest.mark.parametrize("layout", sorted(IDLE_LAYOUTS))
+def test_paged_decode_read_skips_idle_lanes(layout, window):
+    """The read-only kernel over the same layouts against ``ops.paged.gather_kv``
+    and XLA's attention, lengths ``position + 1`` — except the ``length_0``
+    lanes, read here at length 0: pages in the table and nothing to attend
+    is idle too. Live lanes agree to one bf16 ulp; idle lanes are zeros."""
+    from gofr_tpu.ops.attention import paged_decode_attention
+    from gofr_tpu.ops.pallas import paged_decode as kernels
+
+    lanes = IDLE_LAYOUTS[layout]
+    q, _, _, k_pool, v_pool, table, pos = _append_case(jnp.bfloat16, 2, 2, lanes=lanes)
+    lengths = jnp.where(jnp.asarray([name == "length_0" for name in lanes]), 0, pos + 1)
+    got = np.asarray(kernels.paged_decode_attention(
+        q, k_pool, v_pool, 1, table, lengths, window=window, interpret=_tpu_interpreter()), np.float32)
+    span = jnp.minimum(lengths, MAXP * PAGE)  # as in _assert_append_attend_matches
+    want = np.asarray(paged_decode_attention(q, k_pool, v_pool, 1, table, span, backend="xla",
+                                             window=window), np.float32)
+    idle = np.asarray([name in ("idle", "length_0") for name in lanes])
+    tol = _tol(jnp.bfloat16)
+    np.testing.assert_allclose(got[~idle], want[~idle], atol=tol, rtol=tol)
+    assert not got[idle].any()
+
+
 @pytest.mark.parametrize("shape,why", [
     ((2, 7, 2, 16, 64), "head_dim 64: the kernel reads a padded copy of a layer"),
     ((2, 7, 2, 8, 128), "a bf16 page of half a sublane tile"),
@@ -308,15 +384,20 @@ def test_paged_decode_append_refuses_a_plane_it_cannot_address(monkeypatch, shap
 
 
 def test_paged_decode_append_waits_for_every_tile_copy_it_starts():
-    """The interpreter runs a copy where it is started and keeps no count of
-    the semaphores, so a wait that went missing passes every test above and
-    faults only on the chip (it did, in PR 33's last check). Counted in the
-    kernel's own jaxpr instead: the read-only kernel starts a page copy a plane
-    in two places (the call's first page, the page after) and waits in one;
-    the append adds ONE place that starts a lane's tile copies and THREE that
-    wait for them — the lane after next before it stages its own, and the
-    last lane for the lane before it and for itself."""
+    """The plain interpreter runs a copy where it is started and keeps no
+    count of the semaphores, so a wait that went missing passes the tests
+    that use it and faults only on the chip (it did once).
+    Counted in the kernel's own jaxpr instead: the read-only kernel starts a
+    page copy a plane in two places (the call's first page, the page after)
+    and waits in one; the append adds ONE place that starts a live lane's
+    tile copies and THREE that wait for them — the live lane after next
+    before it stages its own, and after the loop for the last two. Then run,
+    on idle lanes between live ones, by the TPU interpreter, which makes a
+    copy land only when it is waited for: every row written and every page
+    attended means every copy the chain over the live lanes starts is
+    waited for."""
     from gofr_tpu.ops.pallas import paged_decode as kernels
+    from gofr_tpu.ops.paged import append_tokens_paged
 
     def copies(fn, *args):
         counts = {}
@@ -340,3 +421,12 @@ def test_paged_decode_append_waits_for_every_tile_copy_it_starts():
     assert copies(kernels.paged_decode_attention, q, plane, plane, 1, table, pos) == (2 * planes, 1 * planes)
     assert copies(kernels.paged_decode_append_attention, q, new, new, plane, plane, 1, table, pos) == (
         (2 + 1) * planes, (1 + 3) * planes)
+
+    operands = _append_case(jnp.bfloat16, 2, 2, lanes=IDLE_LAYOUTS["idle_between_live"])
+    got, k_out, v_out = kernels.paged_decode_append_attention(
+        *operands[:5], 1, *operands[5:], interpret=_tpu_interpreter())
+    q, k_new, v_new, k_pool, v_pool, table, pos = operands
+    for plane, want in zip((k_out, v_out), append_tokens_paged(k_pool, v_pool, 1, table, pos, k_new, v_new)):
+        assert np.array_equal(np.asarray(plane, np.float32), np.asarray(want, np.float32))
+    read = kernels.paged_decode_attention(q, k_out, v_out, 1, table, pos + 1, interpret=True)
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(read, np.float32))

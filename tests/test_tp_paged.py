@@ -168,7 +168,9 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
     """The bf16 paged-decode kernel under ``shard_map``: each shard runs the
     same kernel over ONE of the 4 KV heads (the block's head count comes from
     the local plane), lengths ragged across a page boundary, lane 2 idle. The
-    step's logits match the unsharded XLA read path's."""
+    live lanes' logits match the unsharded XLA read path's; the idle lane's are
+    finite (the kernel skips it and hands back zeros, where the XLA path reads
+    a clamped page that nobody owns)."""
     import jax.numpy as jnp
 
     from gofr_tpu.models import llama
@@ -204,7 +206,8 @@ def test_paged_kernel_on_the_sharded_pool_matches_xla(setup, monkeypatch):
             cfg, params, toks, pos, cache(sharding=pool_sharding(mesh)), table)
     jax.clear_caches()
     assert seen and set(seen) == {(cfg.num_heads // 4, cfg.num_kv_heads // 4)}, seen
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2], rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(got)[2]).all()
 
 
 @pytest.mark.parametrize("heads_a_shard", [1, 2])

@@ -183,7 +183,9 @@ def test_decode_appends_inside_the_kernel_where_it_can_address_the_rows(one_chip
     lies, by the kernel. At head_dim 64 the kernel reads a padded copy of one
     layer, nothing can be written through that, and the two scatters (typed
     like the plane they alias) are still there. Nothing chose either: the
-    rule (``ops/attention.append_rides_in_kernel``) read the plane's shape."""
+    rule (``ops/attention.append_rides_in_kernel``) read the plane's shape.
+    Nor is there a sort: the kernel finds its live lanes itself, where an
+    order handed in by the wrapper was sorted inside the layer loop."""
     compiled, cache = _compile(one_chip, width, "decode")
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -195,6 +197,7 @@ def test_decode_appends_inside_the_kernel_where_it_can_address_the_rows(one_chip
         assert "output_to_operand_aliasing" not in kernels[0]
         return
     assert not scatters, f"a scatter is back in the decode step: {scatters}"
+    assert " sort(" not in text, "the decode step sorts: the kernel lists its live lanes itself"
     assert not re.search(r'op_name="[^"]*/kv_append[/"]', text), "an operation under kv_append is back"
     result, operands = kernels[0].split(" custom-call(", 1)
     assert len(re.findall(plane, result)) == 2, f"the call does not return both planes: {result[:300]}"
